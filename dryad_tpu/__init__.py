@@ -235,12 +235,18 @@ def predict(
 
 
 def _accelerator_present() -> bool:
-    try:
-        import jax
+    """True when jax initialised with a non-CPU device.  No jax installed,
+    or a jax that initialised with CPU devices only, picks the CPU
+    trainer; a device initialisation that RAISES (a chip held by another
+    process, a broken libtpu) propagates — it must never turn into a
+    silent run on the numpy reference."""
+    import importlib.util
 
-        return any(d.platform != "cpu" for d in jax.devices())
-    except Exception:
+    if importlib.util.find_spec("jax") is None:
         return False
+    import jax
+
+    return any(d.platform != "cpu" for d in jax.devices())
 
 
 def _engine_present() -> bool:

@@ -176,7 +176,7 @@ def cmd_train(args) -> int:
             callbacks.append(logger)
 
         if args.supervise:
-            # resilient long runs: classify tunnel/device faults, degrade
+            # resilient long runs: classify device faults, degrade
             # chunking, auto-resume from checkpoints (dryad_tpu/resilience);
             # the stale-checkpoint --resume guard already ran up top
             from dryad_tpu.resilience import RetryPolicy, supervise_train
@@ -619,7 +619,7 @@ def cmd_fleet(args) -> int:
     supervision (crash/hang detection, budgeted respawn, journal) behind
     the health-routed fleet router (dryad_tpu/fleet)."""
     from dryad_tpu.fleet import (CapacityController, FleetSupervisor,
-                                 make_fleet_router, serve_argv)
+                                 make_fleet_router, serve_argv, serve_env)
     from dryad_tpu.fleet.router import main_loop
     from dryad_tpu.obs.drift import parse_psi_budget
     from dryad_tpu.obs.slo import parse_budgets
@@ -682,6 +682,7 @@ def cmd_fleet(args) -> int:
               else RetryPolicy(retry_budget=args.retry_budget))
     supervisor = FleetSupervisor(
         make_argv, n_start, policy=policy, journal=args.journal,
+        make_env=lambda index: serve_env(index, args.backend),
         probe_interval_s=args.probe_interval,
         startup_timeout_s=args.startup_timeout)
     # a process MANAGER must not die leaving its children running: the
@@ -796,7 +797,7 @@ def main(argv=None) -> int:
     t.add_argument("--checkpoint-every", type=int, default=10)
     t.add_argument("--resume", action="store_true")
     t.add_argument("--supervise", action="store_true",
-                   help="resilient run: classify tunnel/device faults, "
+                   help="resilient run: classify device faults, "
                         "degrade chunking, auto-resume from checkpoints "
                         "(requires --checkpoint-dir)")
     t.add_argument("--journal",
@@ -995,7 +996,12 @@ def main(argv=None) -> int:
                          "breach or admission saturation) before a "
                          "scale-up is admitted")
     fl.add_argument("--backend", default="auto",
-                    choices=["auto", "tpu", "cpu"])
+                    choices=["auto", "tpu", "cpu"],
+                    help="every replica's serve backend; a device backend "
+                         "gives replica i chip i of this host and nothing "
+                         "else (one process per chip) — a replica without "
+                         "a chip fails its start-up, it never serves from "
+                         "the CPU instead")
     fl.add_argument("--host", default="127.0.0.1")
     fl.add_argument("--port", type=int, default=8000,
                     help="router port (also serves the aggregated /metrics "

@@ -3,7 +3,7 @@
 The obs jax-freedom invariant is about what ``import dryad_tpu.obs``
 ultimately PULLS IN, not about what strings appear in obs files — a
 refactor that makes ``obs/registry.py`` import a helper from, say,
-``dryad_tpu/engine/jax_compat.py`` would pass every text grep while
+``dryad_tpu/engine/distributed.py`` would pass every text grep while
 quietly making the "jax-free by lint" package import jax at module load.
 This module resolves imports statically (``ast.Import``/``ImportFrom``,
 relative levels included), follows edges through dryad_tpu-internal

@@ -60,6 +60,11 @@ _COLLECTIVES = frozenset({
     "reduce_scatter", "pmin", "pmax", "pgather", "axis_index",
 })
 
+# a vma-checked shard_map spells the all-reduce whose result is device-
+# invariant ``psum_invariant``; the unchecked feature arm spells the same
+# collective ``psum`` — one census key for both
+_CANONICAL = {"psum_invariant": "psum"}
+
 # mesh width every arm traces against (matches tests/conftest.py's 8 fake
 # CPU devices; the CLI exports the same XLA_FLAGS before importing jax)
 N_SHARDS = 8
@@ -138,7 +143,7 @@ def census_jaxpr(jaxpr, row_threshold: int,
     for eqn in j.eqns:
         name = eqn.primitive.name
         if name in _COLLECTIVES:
-            out.collectives[name] += 1
+            out.collectives[_CANONICAL.get(name, name)] += 1
         elif name == "sort" and _max_rows(eqn) >= row_threshold:
             if in_shard_map:
                 out.local_row_sorts += 1
@@ -150,8 +155,9 @@ def census_jaxpr(jaxpr, row_threshold: int,
             else:
                 out.table_gathers += 1
         elif name == "pallas_call":
-            kname = getattr(eqn.params.get("name_and_src_info"), "name",
-                            None) or "pallas"
+            # the call's explicit name, else the kernel body's function
+            kname = (eqn.params.get("name")
+                     or eqn.params["jaxpr"].debug_info.func_name)
             sig = "(" + ",".join(_aval_sig(v) for v in eqn.invars) + ")"
             out.pallas_kernels.setdefault(kname, set()).add(sig)
             continue  # do not descend into kernel bodies
@@ -251,31 +257,12 @@ def _mesh():
     return make_mesh(jax.devices()[:N_SHARDS])
 
 
-def _abstract_train_args(p, N, F, K):
-    import jax
-    import jax.numpy as jnp
-
-    from dryad_tpu.booster import CAT_WORDS
-    from dryad_tpu.engine.train import _empty_out_device
-
-    out = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-        _empty_out_device(K, p.max_nodes, CAT_WORDS))
-    sds = jax.ShapeDtypeStruct
-    return (out,
-            sds((N, K), jnp.float32),    # score
-            sds((N, F), jnp.uint8),      # Xb
-            sds((N,), jnp.float32),      # y
-            sds((N,), jnp.bool_),        # bag
-            sds((F,), jnp.bool_),        # fmask
-            sds((F,), jnp.bool_))        # is_cat_feat
-
-
 def _train_arm(params: dict, *, N=2048, F=8, platform="tpu", K=1,
                renewal=False):
     from dryad_tpu.config import make_params
-    from dryad_tpu.engine.train import _comm_stats, _shared_roots_ok
-    from dryad_tpu.engine.train import audit_iteration_fn
+    from dryad_tpu.engine.train import (_comm_stats, _shared_roots_ok,
+                                        audit_iteration_args,
+                                        audit_iteration_fn)
 
     p = make_params(params).validate()
     mesh = _mesh()
@@ -296,7 +283,7 @@ def _train_arm(params: dict, *, N=2048, F=8, platform="tpu", K=1,
         "expected_psums": comm["psum_calls_per_iter"],
         "comm": comm,
     }
-    return fn, _abstract_train_args(p, N, F, K), meta
+    return fn, audit_iteration_args(p, N, F, K), meta
 
 
 def _arm_levelwise_wired():

@@ -15,3 +15,33 @@ atomic-scatter model:
   backends; the histogram allreduce rides ``jax.lax.psum`` over ICI/DCN in
   place of the reference's NCCL (SURVEY.md §2 #13-14).
 """
+
+import os
+
+import jax
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def place_compile_cache() -> str:
+    """Give jax's persistent compilation cache a directory; return it.
+
+    The wide chunk programs compile for minutes, and every train, predict,
+    serve, probe and profile process imports this package before its first
+    device program, so this is the one place the cache is placed.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and nothing
+    is set in code.  Otherwise the cache goes to ``<checkout>/.jax_cache``,
+    derived from this package's own location: the directory is part of
+    what makes a later process find the entries, so it is never built from
+    a temporary name, a pid or the time.
+    """
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+place_compile_cache()

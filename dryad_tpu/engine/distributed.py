@@ -42,7 +42,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dryad_tpu.config import Params, hist_reduce_resolved
-from dryad_tpu.engine.jax_compat import shard_map, shard_map_norep
 
 AXIS = "data"
 
@@ -210,10 +209,11 @@ def grow_sharded(params: Params, total_bins: int, has_cat: bool,
     }
     extra = () if bundled_mask is None else (bundled_mask,)
     extra += () if root_hist is None else (root_hist,)
-    # the feature arm's combine all_gather has no replication rule in the
-    # 0.4.x checker (its outputs ARE device-identical — the combine runs
-    # on gathered records); the rep check comes off for that arm only,
-    # with the parity tests standing in (jax_compat.shard_map_norep doc).
+    # the feature arm's combine all_gather is typed device-VARYING by the
+    # vma checker although its outputs ARE device-identical (the combine
+    # runs on gathered records), so the replicated out_specs the tree
+    # arrays need are rejected; the check comes off for that arm only,
+    # with the N-shard ≡ 1-shard ≡ fused parity tests standing in.
     # Only the LEVEL-SYNCHRONOUS growers run the feature program — the
     # sequential grower ignores hist_reduce — so the checker stays ON for
     # every fused program (mirrors _comm_stats' level_synchronous rule).
@@ -227,11 +227,11 @@ def grow_sharded(params: Params, total_bins: int, has_cat: bool,
     mode = (hist_reduce_resolved(params, Xb.shape[1], int(total_bins),
                                  mesh.devices.size)
             if level_sync else "fused")
-    sm = shard_map_norep if mode == "feature" else shard_map
-    return sm(
+    return jax.shard_map(
         run, mesh=mesh,
         in_specs=(row2, row, row, row, rep, rep) + (rep,) * len(extra),
         out_specs=(tree_specs, row),
+        check_vma=mode != "feature",
     )(Xb, g, h, bag_mask, feat_mask, is_cat_feat, *extra)
 
 
@@ -252,6 +252,6 @@ def roots_sharded(mesh: Mesh, Xb, g_all, h_all, bag, total_bins,
 
     row = P(AXIS)
     row2 = P(AXIS, None)
-    return shard_map(
+    return jax.shard_map(
         run, mesh=mesh, in_specs=(row2, row2, row2, row), out_specs=P(),
     )(Xb, g_all, h_all, bag)

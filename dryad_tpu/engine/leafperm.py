@@ -64,8 +64,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dryad_tpu.engine import jax_compat
-
 _TILE_ROWS = 512     # must match pallas_hist._TILE_ROWS (shared layouts)
 # Destination-row granule: Mosaic can only slice an HBM uint8 memref at
 # sublane-tile multiples ("failed to prove divisible by the tiling" for
@@ -156,6 +154,11 @@ def permute_records(rec: jnp.ndarray, pos: jnp.ndarray, dstl: jnp.ndarray,
     zero-fills and masks this; caught in review)."""
     n_rows, WB = rec.shape
     T = _TILE_ROWS
+    if n_rows % T:
+        # a ragged tail would be dropped, and on hardware the rows past
+        # it are stale HBM, not the interpreter's zeros
+        raise ValueError(f"permute_records: {n_rows} rows is not a "
+                         f"multiple of the {T}-row tile")
     n_tiles = n_rows // T
     # memory-safety clamp (tile_plan's "safety squeeze" precedent): a
     # violated caller bound must misplace rows DETERMINISTICALLY inside
@@ -169,9 +172,9 @@ def permute_records(rec: jnp.ndarray, pos: jnp.ndarray, dstl: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((1, 2, T), lambda i, dl, dr: (i, 0, 0)),
             pl.BlockSpec((1, T, WB), lambda i, dl, dr: (i, 0, 0)),
-            pl.BlockSpec(memory_space=jax_compat.tpu_any_space()),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=jax_compat.tpu_any_space()),
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         scratch_shapes=[
             pltpu.VMEM((T // _ALIGN, _ALIGN, WB), jnp.uint8),
             pltpu.VMEM((T // _ALIGN, _ALIGN, WB), jnp.uint8),
@@ -184,12 +187,13 @@ def permute_records(rec: jnp.ndarray, pos: jnp.ndarray, dstl: jnp.ndarray,
     if axis_name is not None:
         # the aliased zero init must carry the same varying-manual-axes
         # as the (shard-local) output it becomes
-        zeros = jax_compat.pcast_varying(zeros, axis_name)
+        zeros = jax.lax.pcast(zeros, axis_name, to="varying")
     out = pl.pallas_call(
         functools.partial(_perm_kernel, T=T, WB=WB),
         grid_spec=grid_spec,
-        out_shape=jax_compat.shape_dtype_struct((G, _ALIGN, WB),
-                                                jnp.uint8, axis_name),
+        out_shape=jax.ShapeDtypeStruct(
+            (G, _ALIGN, WB), jnp.uint8,
+            vma=None if axis_name is None else frozenset({axis_name})),
         # operand index counts the 2 prefetched scalars first: 2=pos,
         # 3=rec, 4=zeros -> alias the zero buffer to the output
         input_output_aliases={4: 0},
@@ -367,6 +371,9 @@ def hist_from_layout(rec: jnp.ndarray, seg_first: jnp.ndarray,
 
     T = _TILE_ROWS
     P = int(num_cols)
+    if rec.shape[0] % T:
+        raise ValueError(f"hist_from_layout: {rec.shape[0]} rows is not a "
+                         f"multiple of the {T}-row tile")
     n_tiles_in = rec.shape[0] // T
     # dense plan: positions of each segment's tiles in the packed prefix
     base = jnp.concatenate([jnp.zeros((1,), jnp.int32),
@@ -489,8 +496,8 @@ def natural_root_layout(rec_nat: jnp.ndarray, num_runs: int,
     tile_run = jnp.zeros((n_buf_tiles,), jnp.int32)
     run_slot = jnp.full((num_runs,), sent, jnp.int32).at[0].set(first_slot)
     if axis_name is not None:
-        tile_run = jax_compat.pcast_varying(tile_run, axis_name)
-        run_slot = jax_compat.pcast_varying(run_slot, axis_name)
+        tile_run = jax.lax.pcast(tile_run, axis_name, to="varying")
+        run_slot = jax.lax.pcast(run_slot, axis_name, to="varying")
     return rec_lay, tile_run, run_slot
 
 

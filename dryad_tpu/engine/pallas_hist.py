@@ -53,8 +53,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dryad_tpu.engine import jax_compat
-
 # weight rows: g_hi g_mid g_lo h_hi h_mid h_lo count (+ pad to the MXU tile)
 _WROWS = 8
 _MXU_M = 128          # weight rows padded to a full MXU tile (see module doc)
@@ -176,7 +174,7 @@ def _hist_kernel(tile_leaf_ref, tile_first_ref, tile_skip_ref, x_ref, w_ref,
         Fc, T = x.shape
         Bp = padded_bins
         shift = Fc.bit_length() - 1                # Fc is a power of two
-        x_rep = jax_compat.tile_repeat(x, Bp, axis=0)   # (Fc*Bp, T) tiled
+        x_rep = pltpu.repeat(x, Bp, axis=0)       # (Fc*Bp, T) TILED copies
         iota_b = jax.lax.broadcasted_iota(jnp.int32, (Fc * Bp, T), 0) >> shift
         onehot = (x_rep == iota_b).astype(jnp.bfloat16)
         # zero-pad the 8 weight rows to the 128-row MXU tile in VMEM (HBM
@@ -242,8 +240,9 @@ def _hist_tiles(Xt, Wt, tile_leaf, tile_first, tile_skip, *, num_cols: int,
         out_specs=pl.BlockSpec((1, _WROWS, Fc * Bp),
                                lambda j, i, tl, tf, sk: (tl[i], 0, j)),
     )
-    out_shape = jax_compat.shape_dtype_struct(
-        (P, _WROWS, n_fb * Fc * Bp), jnp.float32, axis_name)
+    out_shape = jax.ShapeDtypeStruct(
+        (P, _WROWS, n_fb * Fc * Bp), jnp.float32,
+        vma=None if axis_name is None else frozenset({axis_name}))
     out = pl.pallas_call(
         functools.partial(_hist_kernel, padded_bins=Bp),
         grid_spec=grid_spec,
@@ -728,13 +727,13 @@ def _nat_kernel(x_ref, w_ref, o_ref, *, padded_bins: int):
     Fc, T = x.shape
     Bp = padded_bins
     shift = Fc.bit_length() - 1
-    x_rep = jax_compat.tile_repeat(x, Bp, axis=0)
+    x_rep = pltpu.repeat(x, Bp, axis=0)       # tiled whole copies
     iota_b = jax.lax.broadcasted_iota(jnp.int32, (Fc * Bp, T), 0) >> shift
     onehot = (x_rep == iota_b).astype(jnp.bfloat16)
 
     limbs = w_ref[0]                               # (8, T): 7 limbs + sel row
     sel = limbs[7:8, :].astype(jnp.int32)
-    w = jax_compat.tile_repeat(limbs, _NAT_SLOTS, axis=0)  # (128,T) r=limbs[r%8]
+    w = pltpu.repeat(limbs, _NAT_SLOTS, axis=0)    # (128,T) r=limbs[r%8]
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (_NAT_SLOTS * 8, T), 0)
     keep = ((row_iota >> 3) == sel) & ((row_iota & 7) != 7)
     w = jnp.where(keep, w, jnp.bfloat16(0))
@@ -794,8 +793,9 @@ def build_hist_nat(Xt_nat, g, h, sel, *, total_bins: int, num_features: int,
         out_specs=pl.BlockSpec((1, _NAT_SLOTS * 8, Fc * Bp),
                                lambda j, i: (j, 0, 0)),
     )
-    out_shape = jax_compat.shape_dtype_struct(
-        (n_fb, _NAT_SLOTS * 8, Fc * Bp), jnp.float32, axis_name)
+    out_shape = jax.ShapeDtypeStruct(
+        (n_fb, _NAT_SLOTS * 8, Fc * Bp), jnp.float32,
+        vma=None if axis_name is None else frozenset({axis_name}))
     out = pl.pallas_call(
         functools.partial(_nat_kernel, padded_bins=Bp),
         grid_spec=grid_spec,

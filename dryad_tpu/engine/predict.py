@@ -200,7 +200,7 @@ def tree_leaves(tree: dict, Xb: jnp.ndarray, depth_bound) -> jnp.ndarray:
 
 
 def _accumulate_body(trees: dict, Xb: jnp.ndarray, init: jnp.ndarray,
-                     depth_bound: int):
+                     depth_bound: int, axis_name: str | None = None):
     """Raw scores (N, K): scan boosting iterations, vmap the K class trees.
 
     ``trees`` arrays are shaped (n_iter, K, M, ...); per class the additions
@@ -208,11 +208,15 @@ def _accumulate_body(trees: dict, Xb: jnp.ndarray, init: jnp.ndarray,
     reference path.  Shared verbatim by the jitted single-device program
     and by each shard's block under ``shard_map`` (sharded_accumulate_fn):
     every op here is strictly per-row, which is what makes row sharding a
-    bitwise no-op rather than an approximation.
+    bitwise no-op rather than an approximation.  Under ``shard_map`` pass
+    ``axis_name``: the scan carry starts from the replicated ``init`` and
+    must be marked device-varying like the per-row sums that replace it.
     """
     N = Xb.shape[0]
     K = trees["value"].shape[1]    # present in both layouts
     score0 = jnp.broadcast_to(init.astype(jnp.float32), (N, K))
+    if axis_name is not None:
+        score0 = jax.lax.pcast(score0, axis_name, to="varying")
 
     def step(score, tree_k):
         leaves = jax.vmap(lambda tr: tree_leaves(tr, Xb, depth_bound))(tree_k)  # (K, N)
@@ -237,12 +241,11 @@ def sharded_accumulate_fn(mesh, depth_bound: int):
     from jax.sharding import PartitionSpec as P
 
     from dryad_tpu.engine.distributed import AXIS
-    from dryad_tpu.engine.jax_compat import shard_map
 
     def run(trees, Xb, init):
-        return _accumulate_body(trees, Xb, init, depth_bound)
+        return _accumulate_body(trees, Xb, init, depth_bound, axis_name=AXIS)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(), P(AXIS, None), P()),
         out_specs=P(AXIS, None),

@@ -21,7 +21,8 @@ and sockets.  Entry points::
     from dryad_tpu.fleet import FleetSupervisor, FleetRouter, serve_argv
     sup = FleetSupervisor(
         lambda i, pf: serve_argv(["m.dryad"], pf, backend="auto"),
-        n_replicas=2, journal="fleet.jsonl").start()
+        n_replicas=2, make_env=lambda i: serve_env(i, "auto"),
+        journal="fleet.jsonl").start()
     router = FleetRouter(sup, port=8000).start()
 
 or ``python -m dryad_tpu fleet --model m.dryad --replicas 2 --port 8000``.
@@ -29,7 +30,7 @@ or ``python -m dryad_tpu fleet --model m.dryad --replicas 2 --port 8000``.
 
 from dryad_tpu.fleet.autoscale import CapacityController
 from dryad_tpu.fleet.replica import (ReplicaProcess, ReplicaStartupError,
-                                     serve_argv)
+                                     serve_argv, serve_env)
 from dryad_tpu.fleet.router import (FleetRouter, make_fleet_router,
                                     relabel_exposition)
 from dryad_tpu.fleet.supervisor import FleetSupervisor, ReplicaSlot
@@ -37,5 +38,5 @@ from dryad_tpu.fleet.supervisor import FleetSupervisor, ReplicaSlot
 __all__ = [
     "CapacityController", "FleetRouter", "FleetSupervisor",
     "ReplicaProcess", "ReplicaSlot", "ReplicaStartupError",
-    "make_fleet_router", "relabel_exposition", "serve_argv",
+    "make_fleet_router", "relabel_exposition", "serve_argv", "serve_env",
 ]

@@ -11,7 +11,10 @@ what the process could do in-process.
 Two arms, reported together by ``scripts/bench_serve.py --fleet``:
 
 * **scaling** — the same closed loop against 1/2/4-replica fleets
-  (``fleet_rows_per_s_n1/n2/n4`` + per-arm spreads).  The CLAUDE.md
+  (``fleet_rows_per_s_n1/n2/n4`` + per-arm spreads).  Every replica gets
+  the one ``backend``; with a device backend replica i is shown chip i
+  only (``replica.serve_env``), so a fleet larger than the host's chip
+  count fails at start-up instead of filling up with CPU servers.  The CLAUDE.md
   discipline carries over: closed loop (clients wait for each answer, so
   concurrency is exact), min-free measurement is replaced by arms +
   spread fields because walls here are end-to-end HTTP, and the payload
@@ -33,7 +36,7 @@ import threading
 import time
 from typing import Optional, Sequence
 
-from dryad_tpu.fleet.replica import serve_argv
+from dryad_tpu.fleet.replica import serve_argv, serve_env
 from dryad_tpu.fleet.router import FleetRouter
 from dryad_tpu.fleet.supervisor import FleetSupervisor
 from dryad_tpu.obs.registry import (REQUEST_LATENCY, Registry,
@@ -143,6 +146,7 @@ def _start_fleet(model_path: str, n_replicas: int, *, backend: str,
     # must not mix with a previous arm's (or the process default's)
     reg = Registry()
     sup = FleetSupervisor(make_argv, n_replicas,
+                          make_env=lambda index: serve_env(index, backend),
                           policy=RetryPolicy(backoff_base_s=0.1),
                           registry=reg,
                           startup_timeout_s=startup_timeout_s)

@@ -72,6 +72,22 @@ def serve_argv(model_specs: Sequence[str], port_file: str, *,
     return argv
 
 
+def serve_env(index: int, backend: str) -> dict:
+    """What a production replica in slot ``index`` adds to the fleet
+    process's environment.  A chip belongs to one process at a time, and
+    a jax process takes every chip it can see, so a device-backend
+    replica is shown exactly one: chip ``index`` of this host (libtpu's
+    per-process visibility and bounds variables).  A slot whose chip does
+    not exist fails its start-up with the device error — the fleet never
+    turns that into a CPU replica.  The numpy backend touches no chip and
+    gets nothing; on a host without chips the variables are inert."""
+    if backend == "cpu":
+        return {}
+    return {"TPU_VISIBLE_CHIPS": str(int(index)),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
 class ReplicaProcess:
     """Spawn + address + probe one replica subprocess."""
 

@@ -133,6 +133,9 @@ class FleetSupervisor:
 
     ``make_argv(index, port_file)`` builds each replica's command line
     (``fleet.replica.serve_argv`` for production; tests pass a stub).
+    ``make_env(index)`` gives each slot's additions to this process's
+    environment (``fleet.replica.serve_env``: one chip per device-backend
+    replica), every generation.
     ``fault_env`` maps replica index -> a ``DRYAD_REPLICA_FAULTS`` spec
     string armed for that replica's FIRST generation only (drills).
     ``journal`` takes a path (owned/closed here) or an open RunJournal,
@@ -167,6 +170,7 @@ class FleetSupervisor:
                  unhealthy_after: int = 2,
                  recycle_after: int = 8,
                  startup_timeout_s: float = 60.0,
+                 make_env=None,
                  fault_env: Optional[dict] = None,
                  log_dir: Optional[str] = None):
         if n_replicas < 1:
@@ -181,6 +185,7 @@ class FleetSupervisor:
         self.unhealthy_after = int(unhealthy_after)
         self.recycle_after = int(recycle_after)
         self.startup_timeout_s = float(startup_timeout_s)
+        self.make_env = make_env
         self.fault_env = dict(fault_env or {})
         self.log_dir = log_dir
         self._slots = [ReplicaSlot(i) for i in range(int(n_replicas))]
@@ -325,9 +330,10 @@ class FleetSupervisor:
         process itself would otherwise re-arm EVERY generation and turn
         one drill into a budget-exhausting fleet outage; supervisor-owned
         replicas take drills only through ``fault_env``."""
-        if slot.generation == 0 and slot.index in self.fault_env:
-            return {REPLICA_FAULTS_ENV: self.fault_env[slot.index]}
-        return {REPLICA_FAULTS_ENV: ""}
+        env = dict(self.make_env(slot.index)) if self.make_env else {}
+        armed = slot.generation == 0 and slot.index in self.fault_env
+        env[REPLICA_FAULTS_ENV] = self.fault_env[slot.index] if armed else ""
+        return env
 
     def _spawn(self, slot: ReplicaSlot, first: bool = False) -> bool:
         """Spawn (or respawn) the slot's process; on startup failure keep

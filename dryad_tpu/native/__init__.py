@@ -7,10 +7,14 @@ no pybind11).  The pure-numpy implementations in ``data/sketch.py`` /
 ``cpu/predict.py`` remain the bit-exact *spec*; this module is the fast
 path and must match them bit for bit (tests/test_native.py diffs them).
 
-Loading is lazy and failure-tolerant: if the .so is absent we try one
-quiet ``make``; if the toolchain is missing, ``available()`` is False and
-every caller falls back to numpy.  ``DRYAD_NATIVE=0`` disables the native
-path outright.
+Loading is lazy and failure-tolerant.  ``libdryad_native.so`` is not
+committed (``.gitignore``), so the rule is stated on the binary itself,
+never on file times (which mean nothing in a fresh copy of the tree): a
+.so that is absent, cannot be opened or reports another ABI version is
+rebuilt from ``src/dryad_native.cpp`` into the checkout; if the
+toolchain is missing too, ``available()`` is False and every caller
+falls back to numpy.  ``status()`` says which of these happened.
+``DRYAD_NATIVE=0`` disables the native path outright.
 """
 
 from __future__ import annotations
@@ -27,8 +31,12 @@ _SO = os.path.join(_HERE, "libdryad_native.so")
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
-# must equal dryad_abi_version() in the .so; a stale binary that failed to
-# rebuild would otherwise be called through the wrong signature
+# "loaded" (an existing .so passed the ABI check), "built" (compiled by
+# this process), "unavailable" (no usable .so and no toolchain) or
+# "disabled" (DRYAD_NATIVE=0) — set by the first _load()
+_status = "unavailable"
+# must equal dryad_abi_version() in the .so; a binary from another
+# revision would otherwise be called through the wrong signature
 _ABI_VERSION = 2
 
 _i64 = ctypes.c_int64
@@ -42,8 +50,9 @@ _u32p = np.ctypeslib.ndpointer(np.uint32, flags="C_CONTIGUOUS")
 
 def _build() -> bool:
     try:
+        # -B: the rule is the ABI check, not make's file times
         res = subprocess.run(
-            ["make", "-C", _HERE],
+            ["make", "-B", "-C", _HERE],
             capture_output=True,
             timeout=120,
         )
@@ -52,29 +61,34 @@ def _build() -> bool:
         return False
 
 
+def _open() -> Optional[ctypes.CDLL]:
+    """The .so on disk, or None when it is absent, cannot be opened or
+    was built for another ABI version."""
+    if not os.path.exists(_SO):
+        return None
+    try:
+        lib = ctypes.CDLL(_SO)
+        lib.dryad_abi_version.restype = _i64
+        lib.dryad_abi_version.argtypes = []
+        return lib if lib.dryad_abi_version() == _ABI_VERSION else None
+    except (OSError, AttributeError):
+        return None
+
+
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _status
     if _tried:
         return _lib
     _tried = True
     if os.environ.get("DRYAD_NATIVE", "1") == "0":
+        _status = "disabled"
         return None
-    src = os.path.join(_HERE, "src", "dryad_native.cpp")
-    stale = (
-        os.path.exists(_SO)
-        and os.path.exists(src)
-        and os.path.getmtime(src) > os.path.getmtime(_SO)
-    )
-    if (not os.path.exists(_SO) or stale) and not _build() and not os.path.exists(_SO):
+    lib, status = _open(), "loaded"
+    if lib is None:
+        lib, status = (_open() if _build() else None), "built"
+    if lib is None:
         return None
     try:
-        lib = ctypes.CDLL(_SO)
-
-        lib.dryad_abi_version.restype = _i64
-        lib.dryad_abi_version.argtypes = []
-        if lib.dryad_abi_version() != _ABI_VERSION:
-            return None
-
         lib.sketch_numerical.restype = _i64
         lib.sketch_numerical.argtypes = [_f32p, _i64, _i64, _f32p]
         lib.bin_matrix.restype = None
@@ -87,11 +101,19 @@ def _load() -> Optional[ctypes.CDLL]:
             _u16p, _i64, _i64, _i32p, _i32p, _i32p, _i32p, _u8p, _u32p, _u8p,
             _f32p, _i64, _i64, _i64, _i64, _i64, _f32p,
         ]
-    except (OSError, AttributeError):
-        # stale/incompatible binary: fall back to numpy rather than crash
+    except AttributeError:
+        # a symbol is missing although the ABI number matched: fall back
+        # to numpy rather than crash
         return None
-    _lib = lib
+    _lib, _status = lib, status
     return _lib
+
+
+def status() -> str:
+    """How the native library came to be (or not) in this process:
+    ``"loaded"``, ``"built"``, ``"unavailable"`` or ``"disabled"``."""
+    _load()
+    return _status
 
 
 def available() -> bool:

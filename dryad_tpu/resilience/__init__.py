@@ -1,6 +1,6 @@
 """dryad_tpu.resilience — supervised training for long runs.
 
-The subsystem that makes the recorded tunnel/device fault classes
+The subsystem that makes the recorded device fault classes
 survivable without a human: fault classification + deterministic
 injection (faults.py), retry/degradation policy (policy.py), the
 supervising driver (supervisor.py), and the append-only run journal

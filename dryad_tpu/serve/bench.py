@@ -17,7 +17,7 @@ it across the bucketed AND sharded arms).
 Measurement discipline follows bench.py / CLAUDE.md: the closed loop
 runs ``arms`` times and the report carries the per-arm spread
 (max/min - 1) next to the headline rows/s — a spread over 5% means the
-capture is suspect (host contention, cold cache, tunnel noise) and the
+capture is suspect (host contention, cold cache) and the
 report says so (``suspect_capture``) instead of letting a noisy point
 masquerade as a trend.  ``run_bench_compare`` measures the overlapped
 dispatch pipeline against the strictly serial loop on otherwise

@@ -7,11 +7,12 @@ queue's overlapped dispatch pipeline behind one thread-safe ``predict``
 call, with a ``stats()`` snapshot for observability.  ``python -m
 dryad_tpu serve`` wraps this in an HTTP front end (serve/http.py).
 
-Backend resolution ('auto') prefers the device path when an accelerator
-is attached and falls back gracefully to the canonical numpy predict
-when no device can be initialized — the serving semantics (bucketing,
-batching, metrics, bitwise parity with ``Booster.predict``) are
-identical on both paths.
+Backend resolution ('auto') takes the device path when jax initialised
+with an accelerator and the canonical numpy predict when it initialised
+with CPU devices only; a device initialisation that raises is never
+turned into a CPU server.  The serving semantics (bucketing, batching,
+metrics, bitwise parity with ``Booster.predict``) are identical on both
+paths, and ``stats()["devices"]`` names the devices the jit path uses.
 
 Sharded predict: on the device path with a multi-device mesh, buckets
 whose rows × outputs clear ``sharded_threshold`` run under ``shard_map``
@@ -29,8 +30,8 @@ the old strictly serial loop, kept as the bench comparison arm).
 
 from __future__ import annotations
 
+import os
 import time
-import warnings
 from typing import Optional
 
 import numpy as np
@@ -49,20 +50,18 @@ def _resolve_backend(backend: str) -> str:
 
     'tpu' runs the jit path on whatever platform jax initializes (the
     test mesh is 8 virtual CPU devices); 'auto' takes the jit path only
-    when a real accelerator is attached.  Device-init failure degrades to
-    the numpy path with a warning instead of killing the server.
+    when a real accelerator is attached.  A device initialisation that
+    RAISES (a chip held by another process, a broken libtpu) propagates
+    for both: a replica that cannot reach its device must fail its
+    start-up, not serve from the host under a device backend's name.
     """
     if backend == "cpu":
         return "cpu"
     if backend not in ("auto", "tpu"):
         raise ValueError(f"unknown backend {backend!r}")
-    try:
-        import jax
+    import jax
 
-        devices = jax.devices()
-    except Exception as e:  # noqa: BLE001 — any device-init failure degrades
-        warnings.warn(f"device init failed ({e!r}); serving on CPU")
-        return "cpu"
+    devices = jax.devices()
     if backend == "tpu":
         return "jax"
     return "jax" if any(d.platform != "cpu" for d in devices) else "cpu"
@@ -161,6 +160,25 @@ class PredictServer:
         from dryad_tpu.engine.distributed import make_mesh
 
         return make_mesh(devices)
+
+    def _devices(self) -> Optional[dict]:
+        """Where the jit path runs: platform, device_kind, the ids of the
+        mesh's devices (or the one default device) and the chips this
+        process was shown — None on the numpy backend, which touches no
+        jax device.  A process pinned to one chip numbers it 0 whichever
+        chip it is, so ``visible_chips`` (libtpu's ``TPU_VISIBLE_CHIPS``
+        as the fleet set it, None when unpinned) is what tells a fleet's
+        replicas apart."""
+        if self.backend != "jax":
+            return None
+        import jax
+
+        devs = (list(self.mesh.devices.flat) if self.mesh is not None
+                else jax.devices()[:1])
+        return {"platform": devs[0].platform,
+                "device_kind": devs[0].device_kind,
+                "ids": [int(d.id) for d in devs],
+                "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS")}
 
     # ---- lifecycle ---------------------------------------------------------
     def start(self) -> "PredictServer":
@@ -429,6 +447,7 @@ class PredictServer:
     def stats(self) -> dict:
         snap = self.metrics.snapshot()
         snap["backend"] = self.backend
+        snap["devices"] = self._devices()
         snap["active_version"] = self.registry.active_version
         snap["versions"] = self.registry.versions()
         snap["aliases"] = self.registry.aliases()

@@ -32,15 +32,18 @@ def _n(n):
     return max(1000, int(n * SCALE))
 
 
-def run(name, fn):
+def run(name, fn) -> bool:
+    """One config's result line; False when it failed (every config still
+    runs, and the exit code reports the failures)."""
     t0 = time.perf_counter()
     try:
         metrics = fn()
         metrics.update(status="ok", seconds=round(time.perf_counter() - t0, 1))
-    except Exception as e:  # noqa: BLE001 — acceptance report must not die
+    except Exception as e:  # noqa: BLE001 — report every config, then fail
         metrics = {"status": f"FAIL: {type(e).__name__}: {e}",
                    "seconds": round(time.perf_counter() - t0, 1)}
     print(json.dumps({"config": name, **metrics}), flush=True)
+    return metrics["status"] == "ok"
 
 
 def higgs_100k():
@@ -103,8 +106,16 @@ def criteo():
 
 
 if __name__ == "__main__":
-    run("higgs_100k_depth6_100trees", higgs_100k)
-    run("covertype_581k_softmax", covertype)
-    run("epsilon_400kx2000_regression", epsilon)
-    run("mslr_lambdarank_ndcg", mslr)
-    run("criteo_sparse_categorical", criteo)
+    import jax
+
+    if jax.devices()[0].platform == "cpu":
+        raise SystemExit("acceptance.py measures the attached device; jax "
+                         "initialised with platform 'cpu' only")
+    results = [
+        run("higgs_100k_depth6_100trees", higgs_100k),
+        run("covertype_581k_softmax", covertype),
+        run("epsilon_400kx2000_regression", epsilon),
+        run("mslr_lambdarank_ndcg", mslr),
+        run("criteo_sparse_categorical", criteo),
+    ]
+    raise SystemExit(0 if all(results) else 1)

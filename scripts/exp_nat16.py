@@ -10,7 +10,6 @@ bin tiles are a pure function of Xb (buildable once per tree).
 Measures the kernel vs the segmented path at 10M rows, P=8, and checks
 values against the XLA oracle.
 """
-# dryadlint: disable-file=no-block-until-ready -- r3-era one-shot tile materialization outside the timed region; results recorded (STATUS r3)
 
 import functools
 import sys

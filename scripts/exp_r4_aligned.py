@@ -12,7 +12,6 @@ a chunked one-hot reduce INSIDE the loop (exactness preserved).
 
 Usage: PYTHONPATH=... python scripts/exp_r4_aligned.py [rows] [P] [reps]
 """
-# dryadlint: disable-file=no-block-until-ready -- r4-era setup materialization outside the timed region; results recorded (STATUS r4)
 
 import sys
 import time

@@ -11,7 +11,6 @@ jump between prefixes locates the composition cost.
 
 Usage: PYTHONPATH=... python scripts/exp_r4_bisect.py [rows] [P] [reps]
 """
-# dryadlint: disable-file=no-block-until-ready -- r4-era setup materialization outside the timed region; results recorded (STATUS r4)
 
 import sys
 import time

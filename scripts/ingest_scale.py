@@ -14,13 +14,14 @@ Usage: python scripts/ingest_scale.py [rows] [--budget-gb 8]
 """
 
 import argparse
+import os
 import resource
 import sys
 import time
 
 import numpy as np
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def peak_rss_gb() -> float:

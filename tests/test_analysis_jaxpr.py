@@ -28,7 +28,6 @@ from dryad_tpu.analysis.jaxpr_audit import (
     trace_arm,
 )
 from dryad_tpu.engine.distributed import AXIS, make_mesh
-from dryad_tpu.engine.jax_compat import shard_map
 
 pytestmark = pytest.mark.distributed
 
@@ -151,7 +150,7 @@ def test_census_weights_scan_trip_counts():
 
         return jax.lax.fori_loop(0, 5, body, jnp.float32(0))
 
-    fn = shard_map(inner, mesh=mesh, in_specs=(P(AXIS),), out_specs=P())
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(P(AXIS),), out_specs=P())
     closed = jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((64,), jnp.float32))
     c = census_jaxpr(closed, row_threshold=8)
     assert c.collectives["psum"] == 5
@@ -169,7 +168,7 @@ def test_census_seeded_extra_psum_is_counted():
         return jax.lax.psum(x.sum(), AXIS) + jax.lax.psum(x.max(), AXIS)
 
     def trace(f):
-        fn = shard_map(f, mesh=mesh, in_specs=(P(AXIS),), out_specs=P())
+        fn = jax.shard_map(f, mesh=mesh, in_specs=(P(AXIS),), out_specs=P())
         return census_jaxpr(jax.make_jaxpr(fn)(
             jax.ShapeDtypeStruct((64,), jnp.float32)), 8)
 
@@ -184,7 +183,7 @@ def test_census_splits_global_vs_shard_local_sorts():
     def local_sorting(x):
         return jnp.sort(x)     # sorts the SHARD
 
-    fn = shard_map(local_sorting, mesh=mesh, in_specs=(P(AXIS),),
+    fn = jax.shard_map(local_sorting, mesh=mesh, in_specs=(P(AXIS),),
                    out_specs=P(AXIS))
 
     def global_sorting(x):

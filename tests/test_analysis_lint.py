@@ -74,25 +74,6 @@ def test_wired_grower_existing_slot_argsort_is_waived():
 
 
 # ---------------------------------------------------------------------------
-# no-block-until-ready
-
-def test_block_until_ready_seeded_in_serve():
-    src = SourceTree(ROOT).read("dryad_tpu/serve/metrics.py")
-    bad = src + "\ndef _wait(x):\n    return x.block_until_ready()\n"
-    rep = _violations("no-block-until-ready",
-                      {"dryad_tpu/serve/metrics.py": bad})
-    assert _rule_hits(rep, "no-block-until-ready")
-
-
-def test_block_until_ready_seeded_in_obs():
-    src = SourceTree(ROOT).read("dryad_tpu/obs/registry.py")
-    bad = src + "\ndef _wait(x):\n    x.block_until_ready()\n"
-    rep = _violations("no-block-until-ready",
-                      {"dryad_tpu/obs/registry.py": bad})
-    assert _rule_hits(rep, "no-block-until-ready")
-
-
-# ---------------------------------------------------------------------------
 # batcher-device-fetch
 
 @pytest.mark.parametrize("snippet", [
@@ -148,7 +129,7 @@ def test_fleet_transitive_jax_import_seeded():
     # an innocent-looking module-level import of an engine helper pulls
     # jax into `import dryad_tpu.fleet` — the chain must be reported
     src = SourceTree(ROOT).read("dryad_tpu/fleet/replica.py")
-    bad = "from dryad_tpu.engine.jax_compat import shard_map\n" + src
+    bad = "from dryad_tpu.engine.distributed import make_mesh\n" + src
     rep = _violations("fleet-jax-free",
                       {"dryad_tpu/fleet/replica.py": bad})
     hits = _rule_hits(rep, "fleet-jax-free")
@@ -162,20 +143,11 @@ def test_fleet_device_fetch_shape_banned():
     assert _rule_hits(rep, "fleet-jax-free")
 
 
-def test_block_until_ready_seeded_in_fleet():
-    # the real-fetch discipline covers fleet throttles like serve's
-    src = SourceTree(ROOT).read("dryad_tpu/fleet/supervisor.py")
-    bad = src + "\ndef _wait(x):\n    return x.block_until_ready()\n"
-    rep = _violations("no-block-until-ready",
-                      {"dryad_tpu/fleet/supervisor.py": bad})
-    assert _rule_hits(rep, "no-block-until-ready")
-
-
 def test_obs_transitive_jax_import_seeded():
-    # registry.py -> engine.jax_compat -> jax: no obs file mentions jax,
+    # registry.py -> engine.distributed -> jax: no obs file mentions jax,
     # only the import-graph walk can see it (the r11 upgrade over grep)
     src = SourceTree(ROOT).read("dryad_tpu/obs/registry.py")
-    bad = ("from dryad_tpu.engine.jax_compat import shard_map  # innocent\n"
+    bad = ("from dryad_tpu.engine.distributed import make_mesh  # innocent\n"
            + src)
     rep = _violations("obs-jax-free", {"dryad_tpu/obs/registry.py": bad})
     hits = _rule_hits(rep, "obs-jax-free")
@@ -337,13 +309,13 @@ def test_dead_perturbation_whole_unit_advance_is_clean():
 # waiver machinery
 
 def test_waiver_suppresses_and_is_counted():
-    src = SourceTree(ROOT).read("dryad_tpu/serve/metrics.py")
-    bad = (src + "\ndef _wait(x):\n"
-           "    # dryadlint: disable=no-block-until-ready -- fixture reason\n"
-           "    return x.block_until_ready()\n")
-    rep = _violations("no-block-until-ready",
-                      {"dryad_tpu/serve/metrics.py": bad})
-    assert not _rule_hits(rep, "no-block-until-ready")
+    src = SourceTree(ROOT).read("dryad_tpu/serve/batcher.py")
+    bad = (src + "\ndef _peek(x):\n"
+           "    # dryadlint: disable=batcher-device-fetch -- fixture reason\n"
+           "    return x.addressable_data(0)\n")
+    rep = _violations("batcher-device-fetch",
+                      {"dryad_tpu/serve/batcher.py": bad})
+    assert not _rule_hits(rep, "batcher-device-fetch")
     assert any(w.reason == "fixture reason" for _, w in rep.waived)
 
 
@@ -354,12 +326,12 @@ def test_waiver_without_reason_is_an_error():
 
 
 def test_file_level_waiver_covers_whole_file():
-    src = SourceTree(ROOT).read("dryad_tpu/serve/metrics.py")
-    bad = ("# dryadlint: disable-file=no-block-until-ready -- fixture\n"
-           + src + "\ndef _wait(x):\n    return x.block_until_ready()\n")
-    rep = _violations("no-block-until-ready",
-                      {"dryad_tpu/serve/metrics.py": bad})
-    assert not _rule_hits(rep, "no-block-until-ready")
+    src = SourceTree(ROOT).read("dryad_tpu/serve/batcher.py")
+    bad = ("# dryadlint: disable-file=batcher-device-fetch -- fixture\n"
+           + src + "\ndef _peek(x):\n    return x.addressable_data(0)\n")
+    rep = _violations("batcher-device-fetch",
+                      {"dryad_tpu/serve/batcher.py": bad})
+    assert not _rule_hits(rep, "batcher-device-fetch")
     assert rep.waived
 
 

@@ -1,5 +1,5 @@
 """Resilient training subsystem (dryad_tpu/resilience): fault
-classification against the recorded tunnel signatures, deterministic
+classification against the recorded fault signatures, deterministic
 injection, ch_max threading/precedence, the supervised mixed-fault soak
 (bitwise vs the uninterrupted run), and every fail-closed path."""
 
@@ -55,7 +55,7 @@ def test_classify_recorded_signatures():
 
 def test_classify_fails_closed_on_everything_else():
     # user/config errors must NEVER be retried, whatever their message
-    assert classify_fault(ValueError("UNAVAILABLE: looks tunnely")) == F.UNKNOWN
+    assert classify_fault(ValueError("UNAVAILABLE: looks like a device fault")) == F.UNKNOWN
     assert classify_fault(RuntimeError("some novel explosion")) == F.UNKNOWN
     assert classify_fault(KeyboardInterrupt()) == F.UNKNOWN
     # prose "aborted" is not the grpc ABORTED status — a deterministic bug
@@ -184,7 +184,7 @@ def test_chunk_cap_ladder_degrade_and_rewiden():
     with pytest.raises(ValueError, match="at least one step"):
         ChunkCapPolicy(RetryPolicy(ch_max_ladder=()))
     # re-widening never returns to a known-fatal length: a persistent
-    # tunnel phase must not oscillate safe -> fatal -> safe and burn the
+    # faulty phase must not oscillate safe -> fatal -> safe and burn the
     # retry budget (the recorded r5 mode: 6-8 fatal, <= 2 always clean)
     osc = ChunkCapPolicy(RetryPolicy(rewiden_after_clean_chunks=1))
     osc.note_dispatch(6)
@@ -194,7 +194,7 @@ def test_chunk_cap_ladder_degrade_and_rewiden():
     assert osc.cap() == 2                  # no ladder step in (2, 4): hold
     # cadence tightening is monotone non-increasing with a floor well
     # above per-iteration checkpointing (a materialize fetch per iteration
-    # is the tunnel-killing pattern)
+    # is the fetch-bound pattern the cadence exists to avoid)
     pol = RetryPolicy()
     assert pol.next_checkpoint_every(50) == 25
     assert pol.next_checkpoint_every(6) == 5

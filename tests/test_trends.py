@@ -71,10 +71,12 @@ def test_load_history_backfill_tolerant(tmp_path):
 
 
 def test_load_history_real_committed_files():
+    # the r1-r5 artifacts recorded another installation and left the tree
+    # (PR 21); whatever history is committed must parse, and the ci.sh
+    # gate must stay green on an empty one
     hist = load_history(ROOT)
-    assert len(hist) >= 5
-    assert hist[-1]["round"] == max(p["round"] for p in hist)
     assert all("value" in p["metrics"] for p in hist)
+    assert compare(hist)["ok"]
 
 
 # ---- comparison -------------------------------------------------------------
