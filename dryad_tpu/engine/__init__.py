@@ -20,6 +20,8 @@ import os
 
 import jax
 
+from dryad_tpu.obs import spans as _spans
+
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
@@ -45,3 +47,10 @@ def place_compile_cache() -> str:
 
 
 place_compile_cache()
+
+# every obs span (train.fetch.*, train.chunk_dispatch, the serve spans)
+# is also a TraceAnnotation: under ``dryad.train(profile_dir=...)`` it
+# shows by its path on the profile's host plane, on the device
+# operations' time axis.  obs stays jax-free; this package is where jax
+# and the spans meet.
+_spans.set_annotator(jax.profiler.TraceAnnotation)

@@ -148,6 +148,7 @@ def feature_shard_offset(axis_name, num_features: int) -> jnp.ndarray:
     return jax.lax.axis_index(axis_name).astype(jnp.int32) * Fs
 
 
+@jax.named_scope("dryad.split_scan")
 def combine_best_splits(rec, axis_name, *, allow, min_split_gain: float,
                         has_cat: bool):
     """All-gather per-shard LocalSplit records and run the replicated

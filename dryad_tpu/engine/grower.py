@@ -128,6 +128,7 @@ def _monotone_array(p: Params, F: int):
     return jnp.asarray(mono, jnp.int32)
 
 
+@jax.named_scope("dryad.split_scan")
 def child_bounds(mono, sf, GL, HL, GR, HR, lam, lo_p, hi_p):
     """Monotone output bounds for the two children of a split (LightGBM
     "basic" mode): the midpoint of the clamped child outputs separates the
@@ -145,12 +146,14 @@ def child_bounds(mono, sf, GL, HL, GR, HR, lam, lo_p, hi_p):
     return lo_l, hi_l, lo_r, hi_r
 
 
+@jax.named_scope("dryad.split_scan")
 def root_stats(hist0: jnp.ndarray):
     """Canonical leaf totals = feature-0 histogram sums (cpu/trainer.py
     contract) — shared by both growers so the derivation can never diverge."""
     return hist0[0, 0].sum(), hist0[1, 0].sum(), hist0[2, 0].sum()
 
 
+@jax.named_scope("dryad.split_scan")
 def finalize_leaf_values(p: Params, M: int, slot_node, slot_G, slot_H,
                          value: jnp.ndarray, slot_lo=None, slot_hi=None) -> jnp.ndarray:
     """Newton leaf values with shrinkage, fp32, scattered to leaf nodes.
@@ -166,6 +169,7 @@ def finalize_leaf_values(p: Params, M: int, slot_node, slot_G, slot_H,
     return value.at[idx].set(vals, mode="drop")
 
 
+@jax.named_scope("dryad.split_scan")
 def pack_cat_bitset(cat_mask_nodes: jnp.ndarray, M: int) -> jnp.ndarray:
     """(M, B) bool membership masks -> (M, CAT_WORDS) uint32 node bitsets,
     bit layout b -> word b>>5, bit b&31 (matches cpu/histogram.py)."""
@@ -249,43 +253,45 @@ def grow_tree(
     # ALL rows partitioned (see hist_of); derived from bag_mask so the init
     # inherits the varying-manual-axes of the shard under shard_map (a plain
     # constant would make the grow-loop cond branches' vma types diverge)
-    row_slot = jnp.where(bag_mask, 0, 0).astype(jnp.int32)
+    with jax.named_scope("dryad.route"):
+        row_slot = jnp.where(bag_mask, 0, 0).astype(jnp.int32)
     hist0 = root_hist if root_hist is not None else hist_of(row_slot == 0)
-    G0, H0, C0 = root_stats(hist0)
-    ninf, pinf = jnp.float32(-jnp.inf), jnp.float32(jnp.inf)
-    root = best(hist0, G0, H0, C0, jnp.int32(0), ninf, pinf)
+    with jax.named_scope("dryad.split_scan"):
+        G0, H0, C0 = root_stats(hist0)
+        ninf, pinf = jnp.float32(-jnp.inf), jnp.float32(jnp.inf)
+        root = best(hist0, G0, H0, C0, jnp.int32(0), ninf, pinf)
 
-    st = {
-        "row_slot": row_slot,
-        "slot_node": jnp.full((L,), -1, jnp.int32).at[0].set(0),
-        "slot_gain": jnp.full((L,), NEG_INF, jnp.float32).at[0].set(root.gain),
-        "slot_G": jnp.zeros((L,), jnp.float32).at[0].set(G0),
-        "slot_H": jnp.zeros((L,), jnp.float32).at[0].set(H0),
-        "slot_C": jnp.zeros((L,), jnp.float32).at[0].set(C0),
-        "slot_depth": jnp.zeros((L,), jnp.int32),
-        "slot_lo": jnp.full((L,), ninf, jnp.float32),
-        "slot_hi": jnp.full((L,), pinf, jnp.float32),
-        "sp_feature": jnp.full((L,), -1, jnp.int32).at[0].set(root.feature),
-        "sp_thresh": jnp.zeros((L,), jnp.int32).at[0].set(root.threshold),
-        "sp_GL": jnp.zeros((L,), jnp.float32).at[0].set(root.g_left),
-        "sp_HL": jnp.zeros((L,), jnp.float32).at[0].set(root.h_left),
-        "sp_CL": jnp.zeros((L,), jnp.float32).at[0].set(root.c_left),
-        "sp_catmask": jnp.zeros((L, root.cat_mask.shape[0]), bool).at[0].set(root.cat_mask),
-        "sp_dleft": jnp.ones((L,), bool).at[0].set(root.default_left),
-        "hists": jnp.zeros((L, 3, F, B), jnp.float32).at[0].set(hist0),
-        "feature": jnp.full((M,), -1, jnp.int32),
-        "threshold": jnp.zeros((M,), jnp.int32),
-        "left": jnp.zeros((M,), jnp.int32),
-        "right": jnp.zeros((M,), jnp.int32),
-        "value": jnp.zeros((M,), jnp.float32),
-        "gain": jnp.zeros((M,), jnp.float32),
-        "cover": jnp.zeros((M,), jnp.float32).at[0].set(C0),
-        "is_cat": jnp.zeros((M,), bool),
-        "cat_mask_nodes": jnp.zeros((M, root.cat_mask.shape[0]), bool),
-        "node_dleft": jnp.ones((M,), bool),
-        "num_nodes": jnp.int32(1),
-        "max_depth": jnp.int32(0),
-    }
+        st = {
+            "row_slot": row_slot,
+            "slot_node": jnp.full((L,), -1, jnp.int32).at[0].set(0),
+            "slot_gain": jnp.full((L,), NEG_INF, jnp.float32).at[0].set(root.gain),
+            "slot_G": jnp.zeros((L,), jnp.float32).at[0].set(G0),
+            "slot_H": jnp.zeros((L,), jnp.float32).at[0].set(H0),
+            "slot_C": jnp.zeros((L,), jnp.float32).at[0].set(C0),
+            "slot_depth": jnp.zeros((L,), jnp.int32),
+            "slot_lo": jnp.full((L,), ninf, jnp.float32),
+            "slot_hi": jnp.full((L,), pinf, jnp.float32),
+            "sp_feature": jnp.full((L,), -1, jnp.int32).at[0].set(root.feature),
+            "sp_thresh": jnp.zeros((L,), jnp.int32).at[0].set(root.threshold),
+            "sp_GL": jnp.zeros((L,), jnp.float32).at[0].set(root.g_left),
+            "sp_HL": jnp.zeros((L,), jnp.float32).at[0].set(root.h_left),
+            "sp_CL": jnp.zeros((L,), jnp.float32).at[0].set(root.c_left),
+            "sp_catmask": jnp.zeros((L, root.cat_mask.shape[0]), bool).at[0].set(root.cat_mask),
+            "sp_dleft": jnp.ones((L,), bool).at[0].set(root.default_left),
+            "hists": jnp.zeros((L, 3, F, B), jnp.float32).at[0].set(hist0),
+            "feature": jnp.full((M,), -1, jnp.int32),
+            "threshold": jnp.zeros((M,), jnp.int32),
+            "left": jnp.zeros((M,), jnp.int32),
+            "right": jnp.zeros((M,), jnp.int32),
+            "value": jnp.zeros((M,), jnp.float32),
+            "gain": jnp.zeros((M,), jnp.float32),
+            "cover": jnp.zeros((M,), jnp.float32).at[0].set(C0),
+            "is_cat": jnp.zeros((M,), bool),
+            "cat_mask_nodes": jnp.zeros((M, root.cat_mask.shape[0]), bool),
+            "node_dleft": jnp.ones((M,), bool),
+            "num_nodes": jnp.int32(1),
+            "max_depth": jnp.int32(0),
+        }
 
     # ---- grow loop ----------------------------------------------------------
     def pick_slot(s_gain, s_depth):
@@ -298,108 +304,115 @@ def grow_tree(
         return jnp.argmax(s_gain).astype(jnp.int32)
 
     def do_split(k, s, st):
-        parent = st["slot_node"][s]
-        sf = st["sp_feature"][s]
-        thr = st["sp_thresh"][s]
-        catm = st["sp_catmask"][s]
-        cat_split = is_cat_feat[sf] if has_cat else jnp.bool_(False)
+        with jax.named_scope("dryad.split_scan"):
+            parent = st["slot_node"][s]
+            sf = st["sp_feature"][s]
+            thr = st["sp_thresh"][s]
+            catm = st["sp_catmask"][s]
+            cat_split = is_cat_feat[sf] if has_cat else jnp.bool_(False)
 
-        bins_f = jnp.take(Xb, sf, axis=1).astype(jnp.int32)
-        num_left = bins_f <= thr
-        dl = st["sp_dleft"][s]
-        if learn_missing:
-            num_left &= dl | (bins_f > 0)
-        if has_cat:
-            go_left = jnp.where(cat_split, catm[jnp.minimum(bins_f, catm.shape[0] - 1)],
-                                num_left)
-        else:
-            go_left = num_left
-        in_slot = st["row_slot"] == s
+        with jax.named_scope("dryad.route"):
+            bins_f = jnp.take(Xb, sf, axis=1).astype(jnp.int32)
+            num_left = bins_f <= thr
+            dl = st["sp_dleft"][s]
+            if learn_missing:
+                num_left &= dl | (bins_f > 0)
+            if has_cat:
+                go_left = jnp.where(cat_split, catm[jnp.minimum(bins_f, catm.shape[0] - 1)],
+                                    num_left)
+            else:
+                go_left = num_left
+            in_slot = st["row_slot"] == s
 
-        GL, HL, CL = st["sp_GL"][s], st["sp_HL"][s], st["sp_CL"][s]
-        Gp, Hp, Cp = st["slot_G"][s], st["slot_H"][s], st["slot_C"][s]
-        GR, HR, CR = Gp - GL, Hp - HL, Cp - CL
+        with jax.named_scope("dryad.split_scan"):
+            GL, HL, CL = st["sp_GL"][s], st["sp_HL"][s], st["sp_CL"][s]
+            Gp, Hp, Cp = st["slot_G"][s], st["slot_H"][s], st["slot_C"][s]
+            GR, HR, CR = Gp - GL, Hp - HL, Cp - CL
 
-        left_id = st["num_nodes"]
-        right_id = left_id + 1
-        new_r = jnp.int32(k + 1)
+            left_id = st["num_nodes"]
+            right_id = left_id + 1
+            new_r = jnp.int32(k + 1)
 
-        gain_arr = st["gain"].at[parent].set(st["slot_gain"][s])
-        cover_arr = st["cover"].at[left_id].set(CL).at[right_id].set(CR)
-        feature = st["feature"].at[parent].set(sf)
-        threshold = st["threshold"].at[parent].set(jnp.where(cat_split, 0, thr))
-        left = st["left"].at[parent].set(left_id)
-        right = st["right"].at[parent].set(right_id)
-        is_cat_arr = st["is_cat"].at[parent].set(cat_split)
-        cat_nodes = st["cat_mask_nodes"].at[parent].set(
-            jnp.where(cat_split, catm, jnp.zeros_like(catm))
-        )
-        node_dleft = st["node_dleft"].at[parent].set(dl | cat_split)
+            gain_arr = st["gain"].at[parent].set(st["slot_gain"][s])
+            cover_arr = st["cover"].at[left_id].set(CL).at[right_id].set(CR)
+            feature = st["feature"].at[parent].set(sf)
+            threshold = st["threshold"].at[parent].set(jnp.where(cat_split, 0, thr))
+            left = st["left"].at[parent].set(left_id)
+            right = st["right"].at[parent].set(right_id)
+            is_cat_arr = st["is_cat"].at[parent].set(cat_split)
+            cat_nodes = st["cat_mask_nodes"].at[parent].set(
+                jnp.where(cat_split, catm, jnp.zeros_like(catm))
+            )
+            node_dleft = st["node_dleft"].at[parent].set(dl | cat_split)
 
         # row partition/apply: left child keeps slot s, right child takes k+1
-        row_slot = jnp.where(in_slot & ~go_left, new_r, st["row_slot"])
+        with jax.named_scope("dryad.route"):
+            row_slot = jnp.where(in_slot & ~go_left, new_r, st["row_slot"])
 
         # smaller child's histogram direct; larger by subtraction
-        left_smaller = CL <= CR
-        if p.hist_subtraction:
-            small_slot = jnp.where(left_smaller, s, new_r)
-            shist = hist_of(row_slot == small_slot)
-            ohist = st["hists"][s] - shist
-            hist_l = jnp.where(left_smaller, shist, ohist)
-            hist_r = jnp.where(left_smaller, ohist, shist)
-        else:
-            hist_l = hist_of(row_slot == s)
-            hist_r = hist_of(row_slot == new_r)
-        hists = st["hists"].at[s].set(hist_l).at[new_r].set(hist_r)
+        with jax.named_scope("dryad.hist"):
+            left_smaller = CL <= CR
+            if p.hist_subtraction:
+                small_slot = jnp.where(left_smaller, s, new_r)
+                shist = hist_of(row_slot == small_slot)
+                ohist = st["hists"][s] - shist
+                hist_l = jnp.where(left_smaller, shist, ohist)
+                hist_r = jnp.where(left_smaller, ohist, shist)
+            else:
+                hist_l = hist_of(row_slot == s)
+                hist_r = hist_of(row_slot == new_r)
+            hists = st["hists"].at[s].set(hist_l).at[new_r].set(hist_r)
 
-        depth_c = st["slot_depth"][s] + 1
-        lo_p, hi_p = st["slot_lo"][s], st["slot_hi"][s]
-        if mono is not None:
-            lo_l, hi_l, lo_r, hi_r = child_bounds(
-                mono, sf, GL, HL, GR, HR, jnp.float32(p.lambda_l2), lo_p, hi_p)
-        else:
-            lo_l = lo_r = lo_p
-            hi_l = hi_r = hi_p
-        res_l = best(hist_l, GL, HL, CL, depth_c, lo_l, hi_l)
-        res_r = best(hist_r, GR, HR, CR, depth_c, lo_r, hi_r)
+        with jax.named_scope("dryad.split_scan"):
+            depth_c = st["slot_depth"][s] + 1
+            lo_p, hi_p = st["slot_lo"][s], st["slot_hi"][s]
+            if mono is not None:
+                lo_l, hi_l, lo_r, hi_r = child_bounds(
+                    mono, sf, GL, HL, GR, HR, jnp.float32(p.lambda_l2), lo_p, hi_p)
+            else:
+                lo_l = lo_r = lo_p
+                hi_l = hi_r = hi_p
+            res_l = best(hist_l, GL, HL, CL, depth_c, lo_l, hi_l)
+            res_r = best(hist_r, GR, HR, CR, depth_c, lo_r, hi_r)
 
-        def put(a, vl, vr):
-            return a.at[s].set(vl).at[new_r].set(vr)
+            def put(a, vl, vr):
+                return a.at[s].set(vl).at[new_r].set(vr)
 
-        return {
-            "row_slot": row_slot,
-            "slot_node": put(st["slot_node"], left_id, right_id),
-            "slot_gain": put(st["slot_gain"], res_l.gain, res_r.gain),
-            "slot_G": put(st["slot_G"], GL, GR),
-            "slot_H": put(st["slot_H"], HL, HR),
-            "slot_C": put(st["slot_C"], CL, CR),
-            "slot_depth": put(st["slot_depth"], depth_c, depth_c),
-            "slot_lo": put(st["slot_lo"], lo_l, lo_r),
-            "slot_hi": put(st["slot_hi"], hi_l, hi_r),
-            "sp_feature": put(st["sp_feature"], res_l.feature, res_r.feature),
-            "sp_thresh": put(st["sp_thresh"], res_l.threshold, res_r.threshold),
-            "sp_GL": put(st["sp_GL"], res_l.g_left, res_r.g_left),
-            "sp_HL": put(st["sp_HL"], res_l.h_left, res_r.h_left),
-            "sp_CL": put(st["sp_CL"], res_l.c_left, res_r.c_left),
-            "sp_catmask": put(st["sp_catmask"], res_l.cat_mask, res_r.cat_mask),
-            "sp_dleft": put(st["sp_dleft"], res_l.default_left, res_r.default_left),
-            "hists": hists,
-            "feature": feature,
-            "threshold": threshold,
-            "left": left,
-            "right": right,
-            "value": st["value"],
-            "gain": gain_arr,
-            "cover": cover_arr,
-            "is_cat": is_cat_arr,
-            "cat_mask_nodes": cat_nodes,
-            "node_dleft": node_dleft,
-            "num_nodes": st["num_nodes"] + 2,
-            "max_depth": jnp.maximum(st["max_depth"], depth_c),
-        }
+            return {
+                "row_slot": row_slot,
+                "slot_node": put(st["slot_node"], left_id, right_id),
+                "slot_gain": put(st["slot_gain"], res_l.gain, res_r.gain),
+                "slot_G": put(st["slot_G"], GL, GR),
+                "slot_H": put(st["slot_H"], HL, HR),
+                "slot_C": put(st["slot_C"], CL, CR),
+                "slot_depth": put(st["slot_depth"], depth_c, depth_c),
+                "slot_lo": put(st["slot_lo"], lo_l, lo_r),
+                "slot_hi": put(st["slot_hi"], hi_l, hi_r),
+                "sp_feature": put(st["sp_feature"], res_l.feature, res_r.feature),
+                "sp_thresh": put(st["sp_thresh"], res_l.threshold, res_r.threshold),
+                "sp_GL": put(st["sp_GL"], res_l.g_left, res_r.g_left),
+                "sp_HL": put(st["sp_HL"], res_l.h_left, res_r.h_left),
+                "sp_CL": put(st["sp_CL"], res_l.c_left, res_r.c_left),
+                "sp_catmask": put(st["sp_catmask"], res_l.cat_mask, res_r.cat_mask),
+                "sp_dleft": put(st["sp_dleft"], res_l.default_left, res_r.default_left),
+                "hists": hists,
+                "feature": feature,
+                "threshold": threshold,
+                "left": left,
+                "right": right,
+                "value": st["value"],
+                "gain": gain_arr,
+                "cover": cover_arr,
+                "is_cat": is_cat_arr,
+                "cat_mask_nodes": cat_nodes,
+                "node_dleft": node_dleft,
+                "num_nodes": st["num_nodes"] + 2,
+                "max_depth": jnp.maximum(st["max_depth"], depth_c),
+            }
 
     def body(k, st):
-        s = pick_slot(st["slot_gain"], st["slot_depth"])
+        with jax.named_scope("dryad.split_scan"):
+            s = pick_slot(st["slot_gain"], st["slot_depth"])
         return jax.lax.cond(
             st["slot_gain"][s] > NEG_INF,
             lambda st_: do_split(k, s, st_),
@@ -417,6 +430,11 @@ def grow_tree(
     )
     cat_bitset = pack_cat_bitset(st["cat_mask_nodes"], M)
 
+    # per-row leaf node id, straight from the partition state — the
+    # train step's score update uses this instead of re-traversing
+    with jax.named_scope("dryad.score"):
+        row_leaf = jnp.maximum(st["slot_node"], 0)[
+            jnp.minimum(st["row_slot"], L - 1)]
     return {
         "feature": st["feature"],
         "threshold": st["threshold"],
@@ -429,8 +447,5 @@ def grow_tree(
         "cat_bitset": cat_bitset,
         "default_left": st["node_dleft"],
         "max_depth": st["max_depth"],
-        # per-row leaf node id, straight from the partition state — the
-        # train step's score update uses this instead of re-traversing
-        "row_leaf": jnp.maximum(st["slot_node"], 0)[
-            jnp.minimum(st["row_slot"], L - 1)],
+        "row_leaf": row_leaf,
     }

@@ -83,6 +83,7 @@ def _chunk_rows(num_rows: int, num_features: int, total_bins: int,
     return max(c, 1)
 
 
+@jax.named_scope("dryad.hist")
 def build_hist(
     Xb: jnp.ndarray,
     g: jnp.ndarray,
@@ -170,6 +171,7 @@ def build_hist_jit(Xb, g, h, mask, total_bins, rows_per_chunk=65536):
     return build_hist(Xb, g, h, mask, total_bins, rows_per_chunk=rows_per_chunk)
 
 
+@jax.named_scope("dryad.hist")
 def build_hist_classes(
     Xb: jnp.ndarray,
     g_all: jnp.ndarray,   # (N, K) f32
@@ -247,6 +249,7 @@ def build_hist_classes(
     return hist
 
 
+@jax.named_scope("dryad.hist")
 def build_hist_multi(
     Xb: jnp.ndarray,
     g: jnp.ndarray,
@@ -325,6 +328,7 @@ def _segment_tile(num_rows: int, num_cols: int) -> int:
     return t
 
 
+@jax.named_scope("dryad.hist")
 def build_hist_segmented(
     Xb: jnp.ndarray,
     g: jnp.ndarray,

@@ -22,7 +22,9 @@ serve/cache.py right before the FIRST dispatch of a program):
   ``compiled.memory_analysis()`` — this one NEEDS a real compile, so it
   is opt-in (``DRYAD_PROG_MEMORY=1``): a second local compile is cheap
   on the CPU backend (tests, the acceptance drill) and deliberate
-  anywhere else.
+  anywhere else.  The same compile's HLO text gives ``scope_maps()``:
+  which ``dryad.*`` stage each instruction of the program belongs to, by
+  the instruction's name, for whoever reads a device trace of it.
 * ``dryad_prog_compiles_total{program=...}`` via the recompile tripwire
   (obs/tripwire.py) — every boundary notes its program key there, so an
   armed family (serve after warmup, train after the first chunk) turns
@@ -34,6 +36,8 @@ serve/cache.py right before the FIRST dispatch of a program):
   runtime actually paid, process-wide, attributed to the boundary family
   that was active on the compiling thread (best-effort sticky label;
   compiles outside any declared boundary land on ``program="other"``).
+  ``attributed(family)`` lends the label to a block of host code that
+  compiles on its own account (``train.materialize``) and restores it.
 
 Cost model: captures are memoized per (family, key) process-wide, so a
 warm re-run (bench arms, repeated serve traffic) pays NOTHING — exactly
@@ -50,7 +54,10 @@ the tripwire must never become a per-iteration host sync.
 
 from __future__ import annotations
 
+import contextlib
+import math
 import os
+import re
 import threading
 import time
 from typing import Optional
@@ -66,6 +73,25 @@ _listener_installed = False
 
 #: the jax.monitoring event real XLA compiles emit
 _COMPILE_EVENT_SUFFIX = "backend_compile_duration"
+
+#: the stages of a boosting iteration, as ``jax.named_scope`` names them
+#: (README "Device truth"): a component of every operation's ``op_name``
+SCOPE_PREFIX = "dryad."
+#: marks a scope that the compiler's own instruction (a copy, a rewritten
+#: reduction: no ``op_name``, or one that lost its stack) takes from the
+#: instructions it reads or feeds
+INFERRED = "~"
+_scope_maps: dict = {}           # HLO module name -> {instruction: scope}
+_HLO_MODULE = re.compile(r"HloModule ([\w.\-]+)")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_HLO_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+# what an instruction's line names besides its operands: computations
+# (``calls=%f``, ``body=%b``, ``branch_computations={%a, %b}``)
+_HLO_CALLED = re.compile(r"\b\w+=(?:%[\w.\-]+|\{[^}]*\})")
+_HLO_REF = re.compile(r"%([\w.\-]+)")
+_HLO_DIMS = re.compile(r"\w+\[([\d,]*)\]")
+_HLO_OPCODE = re.compile(r" ([\w\-]+)\(")
 
 
 def memory_capture_enabled() -> bool:
@@ -101,6 +127,115 @@ def _install_listener() -> None:
         jax.monitoring.register_event_duration_secs_listener(
             _on_compile_duration)
         _listener_installed = True
+
+
+@contextlib.contextmanager
+def attributed(family: str):
+    """Count this thread's backend compiles under ``family`` while the
+    block runs, then give the sticky label back to the boundary that held
+    it.  For host code that compiles small programs of its own between two
+    boundaries (a checkpoint's slices): the listener's half of ``capture``
+    with no key, no ``lower()`` and no tripwire note."""
+    if not default_registry().enabled:
+        yield
+        return
+    _install_listener()
+    prev = getattr(_tls, "program", None)
+    _tls.program = family
+    try:
+        yield
+    finally:
+        _tls.program = prev
+
+
+def _note_scopes(hlo_text: str) -> None:
+    """Record ``{instruction name: innermost dryad.* scope}`` of one compiled
+    program under its HLO module's name.  A device trace names each
+    operation's event by the instruction (``%fusion.12 = ...``) inside the
+    module's event (``jit__chunk_jit(<fingerprint>)``) and keeps nothing of
+    ``op_name`` (TPU v5 lite, jax 0.9.0), so this is where the two meet.
+
+    The compiler's own instructions (copies from layout assignment, loop
+    plumbing, a cumsum rewritten to ``reduce_window_sum``) carry no scope.
+    Each takes, marked ``INFERRED``, the scope of the largest operand that
+    has one (the data it moves; the text is in schedule order, so one pass
+    forward); failing that, of the last instruction that reads it (one pass
+    backward); failing that, of the ``while`` or ``conditional`` that runs
+    the computation it is in (a scatter the compiler turned into a loop).
+    A scalar gives its scope to scalars only (one shared constant would
+    else name a whole loop), and a tuple gives and takes none (it holds a
+    loop's whole state)."""
+    module = _HLO_MODULE.match(hlo_text)
+    if module is None:
+        return
+    scopes: dict = {}
+    order, reads, size, home, caller = [], {}, {}, {}, {}
+    computation = ""
+    for line in hlo_text.splitlines():
+        header = _HLO_COMPUTATION.match(line)
+        if header is not None:
+            computation = header.group(1)
+            continue
+        found = _HLO_INSTR.match(line)
+        opcode = _HLO_OPCODE.search(found.group(2)) if found else None
+        if opcode is None or opcode.group(1) == "tuple":
+            continue
+        instr, rest = found.groups()
+        shape, args = rest[:opcode.start()], rest[opcode.end():]
+        order.append(instr)
+        home[instr] = computation
+        reads[instr] = _HLO_REF.findall(_HLO_CALLED.sub("", args))
+        size[instr] = max((math.prod(int(n) for n in dims.split(",") if n)
+                           for dims in _HLO_DIMS.findall(shape)), default=1)
+        if opcode.group(1) in ("while", "conditional", "call"):
+            for called in _HLO_CALLED.findall(args):
+                caller.update((c, instr) for c in _HLO_REF.findall(called))
+        op_name = _HLO_OP_NAME.search(rest)
+        for part in reversed(op_name.group(1).split("/") if op_name else ()):
+            if part.startswith(SCOPE_PREFIX):
+                scopes[instr] = part
+                break
+
+    def inferred(instr):
+        return INFERRED + scopes[instr].lstrip(INFERRED)
+
+    for instr in order:
+        if instr not in scopes:
+            known = [o for o in reads[instr] if o in scopes
+                     and (size[o] > 1 or size[instr] <= 1)]
+            if known:
+                scopes[instr] = inferred(max(known, key=size.__getitem__))
+    for instr in reversed(order):
+        for o in reads[instr]:
+            if o not in scopes and instr in scopes and o in reads \
+                    and (size[instr] > 1 or size[o] <= 1):
+                scopes[o] = inferred(instr)
+    for instr in reversed(order):         # callers come after their callees
+        if instr not in scopes and caller.get(home[instr]) in scopes:
+            scopes[instr] = inferred(caller[home[instr]])
+    with _seen_lock:
+        _scope_maps[module.group(1)] = scopes
+
+
+def whole_program(scope: str, jit_fn):
+    """Declare the jitted ``jit_fn`` one stage whole (its body runs under a
+    single ``jax.named_scope(scope)``): the small programs the per-iteration
+    path dispatches beside the step program have no compile boundary of
+    their own, so their map is the one entry ``""``, every instruction.
+    Returns ``jit_fn``."""
+    with _seen_lock:
+        _scope_maps["jit_" + jit_fn.__name__] = {"": scope}
+    return jit_fn
+
+
+def scope_maps() -> dict:
+    """``{HLO module name: {instruction name: dryad.* scope}}`` of every
+    program whose compiled text a boundary has read (``DRYAD_PROG_MEMORY=1``)
+    or that was declared one stage whole (``{"": scope}``): what joins a
+    device trace's events to the stages.  A scope that starts with
+    ``INFERRED`` is a neighbour's, not the instruction's own."""
+    with _seen_lock:
+        return {name: dict(scopes) for name, scopes in _scope_maps.items()}
 
 
 def seen(family: str, key) -> bool:
@@ -166,6 +301,7 @@ def capture(family: str, key, jit_fn, *args,
                 val = getattr(ma, attr, None)
                 if val is not None:
                     mem.labels(kind=kind, **lbl).set(float(val))
+            _note_scopes(compiled.as_text())
         reg.counter("dryad_prog_captures_total",
                     "Successful compile-boundary introspections").labels(
             program=family).inc()

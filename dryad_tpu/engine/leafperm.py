@@ -129,6 +129,7 @@ def _perm_kernel(dstl_ref, dstr_ref, pos_ref, rec_ref, init_ref, out_ref,
     cr.wait()
 
 
+@jax.named_scope("dryad.layout")
 @functools.partial(jax.jit, static_argnames=("n_out_tiles", "platform",
                                              "axis_name"))
 def permute_records(rec: jnp.ndarray, pos: jnp.ndarray, dstl: jnp.ndarray,
@@ -198,11 +199,13 @@ def permute_records(rec: jnp.ndarray, pos: jnp.ndarray, dstl: jnp.ndarray,
         # 3=rec, 4=zeros -> alias the zero buffer to the output
         input_output_aliases={4: 0},
         interpret=_interpret(platform),
+        name="permute_records",
     )(dstl // _ALIGN, dstr // _ALIGN, pos.astype(jnp.int32),
       rec.reshape(n_tiles, T, WB), zeros)
     return out.reshape(n_out_tiles * T, WB)
 
 
+@jax.named_scope("dryad.layout")
 def level_moves(tile_slot: jnp.ndarray, side: jnp.ndarray,
                 n_parents: int, T: int = _TILE_ROWS):
     """XLA bookkeeping for one level — O(N) elementwise + O(n_tiles)
@@ -296,6 +299,7 @@ def tiles_bound(n_rows: int, n_parents: int, T: int = _TILE_ROWS) -> int:
 _REC_WB = 128
 
 
+@jax.named_scope("dryad.layout")
 def make_layout_records(Xb: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
                         valid: jnp.ndarray | None = None) -> jnp.ndarray:
     """(N, _REC_WB) uint8 layout records in natural row order — the
@@ -338,6 +342,7 @@ def unpack_layout_records(rec: jnp.ndarray, num_features: int,
     return g, h, valid, xb
 
 
+@jax.named_scope("dryad.hist")
 def hist_from_layout(rec: jnp.ndarray, seg_first: jnp.ndarray,
                      seg_ntiles: jnp.ndarray, num_cols: int,
                      total_bins: int, num_features: int, bin_dtype,
@@ -470,6 +475,7 @@ def wired_sel_tiles_bound(n_row_tiles: int, n_buf_tiles: int,
     return n_buf_tiles + 2 * num_cols
 
 
+@jax.named_scope("dryad.layout")
 def natural_root_layout(rec_nat: jnp.ndarray, num_runs: int,
                         n_buf_tiles: int, first_slot: int = 0,
                         sentinel: int | None = None,
@@ -501,6 +507,7 @@ def natural_root_layout(rec_nat: jnp.ndarray, num_runs: int,
     return rec_lay, tile_run, run_slot
 
 
+@jax.named_scope("dryad.layout")
 def initial_layout(rec_nat: jnp.ndarray, sel: jnp.ndarray,
                    live: jnp.ndarray, num_slots: int, n_buf_tiles: int):
     """Mid-tree layout constructor: group natural-order layout records by
@@ -543,6 +550,7 @@ def initial_layout(rec_nat: jnp.ndarray, sel: jnp.ndarray,
     return rec_lay, tile_run, run_slot
 
 
+@jax.named_scope("dryad.layout")
 def advance_runs(run_slot: jnp.ndarray, run_do: jnp.ndarray,
                  run_right: jnp.ndarray, base_l: jnp.ndarray,
                  base_r: jnp.ndarray, n_buf_tiles: int,

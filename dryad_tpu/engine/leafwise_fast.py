@@ -279,38 +279,40 @@ def grow_tree_leafwise_batched(
     # bag_mask so the init inherits the shard's varying-manual-axes under
     # shard_map (a plain constant would make downstream vma types diverge —
     # same trick as grower.py / levelwise.py)
-    row_node = jnp.where(bag_mask, 1, 1).astype(jnp.int32)
+    with jax.named_scope("dryad.route"):
+        row_node = jnp.where(bag_mask, 1, 1).astype(jnp.int32)
     hist0 = root_hist if root_hist is not None else build_hist(
         Xb, g, h, bag_mask, B,
         rows_per_chunk=p.rows_per_chunk, axis_name=axis_name,
         precision=p.hist_precision, backend=p.hist_backend,
         platform=platform)
-    G0, H0, C0 = root_stats(hist0)
-    ninf, pinf = jnp.float32(-jnp.inf), jnp.float32(jnp.inf)
-    root = best(hist0, G0, H0, C0,
-                (jnp.int32(0) < D) & (C0 >= 2 * p.min_data_in_leaf),
-                ninf, pinf)
-    Bc = root.cat_mask.shape[0]
+    with jax.named_scope("dryad.split_scan"):
+        G0, H0, C0 = root_stats(hist0)
+        ninf, pinf = jnp.float32(-jnp.inf), jnp.float32(jnp.inf)
+        root = best(hist0, G0, H0, C0,
+                    (jnp.int32(0) < D) & (C0 >= 2 * p.min_data_in_leaf),
+                    ninf, pinf)
+        Bc = root.cat_mask.shape[0]
 
-    # heap-node tables (index = heap id; unwritten slots keep the defaults)
-    nd_gain = jnp.full((HN,), NEG_INF, jnp.float32).at[1].set(root.gain)
-    nd_feature = jnp.full((HN,), -1, jnp.int32).at[1].set(root.feature)
-    nd_thresh = jnp.zeros((HN,), jnp.int32).at[1].set(root.threshold)
-    nd_GL = jnp.zeros((HN,), jnp.float32).at[1].set(root.g_left)
-    nd_HL = jnp.zeros((HN,), jnp.float32).at[1].set(root.h_left)
-    nd_CL = jnp.zeros((HN,), jnp.float32).at[1].set(root.c_left)
-    nd_G = jnp.zeros((HN,), jnp.float32).at[1].set(G0)
-    nd_H = jnp.zeros((HN,), jnp.float32).at[1].set(H0)
-    nd_C = jnp.zeros((HN,), jnp.float32).at[1].set(C0)
-    nd_dleft = jnp.ones((HN,), bool).at[1].set(root.default_left)
-    nd_catmask = jnp.zeros((HN, Bc), bool).at[1].set(root.cat_mask)
-    nd_lo = jnp.full((HN,), ninf, jnp.float32)
-    nd_hi = jnp.full((HN,), pinf, jnp.float32)
+        # heap-node tables (index = heap id; unwritten slots keep the defaults)
+        nd_gain = jnp.full((HN,), NEG_INF, jnp.float32).at[1].set(root.gain)
+        nd_feature = jnp.full((HN,), -1, jnp.int32).at[1].set(root.feature)
+        nd_thresh = jnp.zeros((HN,), jnp.int32).at[1].set(root.threshold)
+        nd_GL = jnp.zeros((HN,), jnp.float32).at[1].set(root.g_left)
+        nd_HL = jnp.zeros((HN,), jnp.float32).at[1].set(root.h_left)
+        nd_CL = jnp.zeros((HN,), jnp.float32).at[1].set(root.c_left)
+        nd_G = jnp.zeros((HN,), jnp.float32).at[1].set(G0)
+        nd_H = jnp.zeros((HN,), jnp.float32).at[1].set(H0)
+        nd_C = jnp.zeros((HN,), jnp.float32).at[1].set(C0)
+        nd_dleft = jnp.ones((HN,), bool).at[1].set(root.default_left)
+        nd_catmask = jnp.zeros((HN, Bc), bool).at[1].set(root.cat_mask)
+        nd_lo = jnp.full((HN,), ninf, jnp.float32)
+        nd_hi = jnp.full((HN,), pinf, jnp.float32)
 
-    # feature arm: the expansion buffer carries each shard's OWNED slice
-    hist0_loc = (_dist.feature_shard_slice(hist0, axis_name, axis=1)
-                 if feat_par else hist0)
-    hists = jnp.zeros((Pf, 3, FH, B), jnp.float32).at[0].set(hist0_loc)
+        # feature arm: the expansion buffer carries each shard's OWNED slice
+        hist0_loc = (_dist.feature_shard_slice(hist0, axis_name, axis=1)
+                     if feat_par else hist0)
+        hists = jnp.zeros((Pf, 3, FH, B), jnp.float32).at[0].set(hist0_loc)
 
     exp_st = {
         "row_node": row_node, "hists": hists,
@@ -355,80 +357,82 @@ def grow_tree_leafwise_batched(
     # ---- expansion: every valid split, level-synchronously -------------------
     def make_level_body(P, use_nat=False, use_layout=False, n_sel_tiles=0):
         def level_body(d, st):
-            base = jnp.left_shift(jnp.int32(1), d)         # level-d heap base
-            W = base                                        # level width
-            jarr = jnp.arange(P, dtype=jnp.int32)
-            idx = jnp.minimum(base + jarr, HN - 1)
-            do = (st["nd_gain"][idx] > NEG_INF) & (jarr < W)
-            sf = st["nd_feature"][idx]
-            thr = st["nd_thresh"][idx]
-            GL, HL, CL = st["nd_GL"][idx], st["nd_HL"][idx], st["nd_CL"][idx]
-            Gp, Hp, Cp = st["nd_G"][idx], st["nd_H"][idx], st["nd_C"][idx]
-            GR, HR, CR = Gp - GL, Hp - HL, Cp - CL
+            with jax.named_scope("dryad.split_scan"):
+                base = jnp.left_shift(jnp.int32(1), d)         # level-d heap base
+                W = base                                        # level width
+                jarr = jnp.arange(P, dtype=jnp.int32)
+                idx = jnp.minimum(base + jarr, HN - 1)
+                do = (st["nd_gain"][idx] > NEG_INF) & (jarr < W)
+                sf = st["nd_feature"][idx]
+                thr = st["nd_thresh"][idx]
+                GL, HL, CL = st["nd_GL"][idx], st["nd_HL"][idx], st["nd_CL"][idx]
+                Gp, Hp, Cp = st["nd_G"][idx], st["nd_H"][idx], st["nd_C"][idx]
+                GR, HR, CR = Gp - GL, Hp - HL, Cp - CL
 
             # ---- partition: a row moves iff its node has a valid split.
             # Expansion splits EVERY valid-gain node at its level, so a row
             # can only sit at a valid-gain node when that node is at the
             # current level — no level check needed.  Same packed-word +
             # masked-reduce scheme as levelwise.py (measured there).
-            rn = st["row_node"]
-            valid_n = st["nd_gain"] > NEG_INF
-            rec_t = None
-            if B <= (1 << 13):
-                cat_n = (is_cat_feat[jnp.maximum(st["nd_feature"], 0)]
-                         if has_cat else jnp.zeros((HN,), bool))
-                w0_t = ((valid_n.astype(jnp.uint32) << 31)
-                        | (st["nd_dleft"].astype(jnp.uint32) << 30)
-                        | (cat_n.astype(jnp.uint32) << 29)
-                        | (jnp.clip(st["nd_thresh"], 0, B - 1)
-                           .astype(jnp.uint32) << 16))
-                rec_t = jnp.stack(
-                    [w0_t, jnp.maximum(st["nd_feature"], 0).astype(jnp.uint32)],
-                    axis=1)
+            with jax.named_scope("dryad.route"):
+                rn = st["row_node"]
+                valid_n = st["nd_gain"] > NEG_INF
+                rec_t = None
+                if B <= (1 << 13):
+                    cat_n = (is_cat_feat[jnp.maximum(st["nd_feature"], 0)]
+                             if has_cat else jnp.zeros((HN,), bool))
+                    w0_t = ((valid_n.astype(jnp.uint32) << 31)
+                            | (st["nd_dleft"].astype(jnp.uint32) << 30)
+                            | (cat_n.astype(jnp.uint32) << 29)
+                            | (jnp.clip(st["nd_thresh"], 0, B - 1)
+                               .astype(jnp.uint32) << 16))
+                    rec_t = jnp.stack(
+                        [w0_t, jnp.maximum(st["nd_feature"], 0).astype(jnp.uint32)],
+                        axis=1)
 
-                def packed_route(nodes, bins_of, rr=None):
-                    """Per-row routing off the packed per-NODE table:
-                    (splits?, goes-left?).  Shared by the natural-order
-                    partition and the layout side derivation so the two
-                    can never disagree on a row (identical integer/bool
-                    arithmetic — levelwise.packed_route's convention).
-                    ``rr`` lets the caller pass a pre-composed per-row
-                    record (one small-table gather instead of two
-                    chained ones); ``nodes`` is then only consulted for
-                    the categorical bitset row."""
-                    if rr is None:
-                        rr = rec_t[nodes]                    # ONE gather
-                    w0r = rr[:, 0]
-                    rf = rr[:, 1].astype(jnp.int32)
-                    bins_rf = bins_of(rf)
-                    gl = bins_rf <= ((w0r >> 16)
-                                     & jnp.uint32(0x1FFF)).astype(jnp.int32)
+                    def packed_route(nodes, bins_of, rr=None):
+                        """Per-row routing off the packed per-NODE table:
+                        (splits?, goes-left?).  Shared by the natural-order
+                        partition and the layout side derivation so the two
+                        can never disagree on a row (identical integer/bool
+                        arithmetic — levelwise.packed_route's convention).
+                        ``rr`` lets the caller pass a pre-composed per-row
+                        record (one small-table gather instead of two
+                        chained ones); ``nodes`` is then only consulted for
+                        the categorical bitset row."""
+                        if rr is None:
+                            rr = rec_t[nodes]                    # ONE gather
+                        w0r = rr[:, 0]
+                        rf = rr[:, 1].astype(jnp.int32)
+                        bins_rf = bins_of(rf)
+                        gl = bins_rf <= ((w0r >> 16)
+                                         & jnp.uint32(0x1FFF)).astype(jnp.int32)
+                        if learn_missing:
+                            gl &= ((w0r >> 30) & 1).astype(bool) | (bins_rf > 0)
+                        if has_cat:
+                            cat_row = st["nd_catmask"][
+                                jnp.minimum(nodes, HN - 1),
+                                jnp.minimum(bins_rf, Bc - 1)]
+                            gl = jnp.where(((w0r >> 29) & 1).astype(bool),
+                                           cat_row, gl)
+                        return ((w0r >> 31) != 0), gl
+
+                    row_do, go_left = packed_route(
+                        rn, lambda rf: levelwise.select_bins(Xb, rf))
+                else:
+                    row_do = valid_n[rn]
+                    rf = jnp.maximum(st["nd_feature"][rn], 0)
+                    bins_rf = jnp.take_along_axis(
+                        Xb, rf[:, None].astype(jnp.int32), axis=1)[:, 0]
+                    bins_rf = bins_rf.astype(jnp.int32)
+                    go_left = bins_rf <= st["nd_thresh"][rn]
                     if learn_missing:
-                        gl &= ((w0r >> 30) & 1).astype(bool) | (bins_rf > 0)
+                        go_left &= st["nd_dleft"][rn] | (bins_rf > 0)
                     if has_cat:
-                        cat_row = st["nd_catmask"][
-                            jnp.minimum(nodes, HN - 1),
-                            jnp.minimum(bins_rf, Bc - 1)]
-                        gl = jnp.where(((w0r >> 29) & 1).astype(bool),
-                                       cat_row, gl)
-                    return ((w0r >> 31) != 0), gl
-
-                row_do, go_left = packed_route(
-                    rn, lambda rf: levelwise.select_bins(Xb, rf))
-            else:
-                row_do = valid_n[rn]
-                rf = jnp.maximum(st["nd_feature"][rn], 0)
-                bins_rf = jnp.take_along_axis(
-                    Xb, rf[:, None].astype(jnp.int32), axis=1)[:, 0]
-                bins_rf = bins_rf.astype(jnp.int32)
-                go_left = bins_rf <= st["nd_thresh"][rn]
-                if learn_missing:
-                    go_left &= st["nd_dleft"][rn] | (bins_rf > 0)
-                if has_cat:
-                    cat_row = st["nd_catmask"][rn, jnp.minimum(bins_rf, Bc - 1)]
-                    go_left = jnp.where(is_cat_feat[rf], cat_row, go_left)
-            row_node = jnp.where(
-                row_do, 2 * rn + jnp.where(go_left, 0, 1), rn)
+                        cat_row = st["nd_catmask"][rn, jnp.minimum(bins_rf, Bc - 1)]
+                        go_left = jnp.where(is_cat_feat[rf], cat_row, go_left)
+                row_node = jnp.where(
+                    row_do, 2 * rn + jnp.where(go_left, 0, 1), rn)
 
             # ---- one batched histogram pass for all smaller children -----
             left_smaller = CL <= CR
@@ -440,155 +444,159 @@ def grow_tree_leafwise_batched(
                 # natural-order partition above; one stable per-tile MXU
                 # compaction moves the rows; the smaller children read
                 # back as contiguous tile runs of the new layout.
-                Tl = leafperm._TILE_ROWS
-                lay_rec = st["lay_rec"]
-                lay_tr = st["lay_tile_run"]
-                lay_ns = st["lay_run_slot"]           # run -> heap node
-                row_run = jnp.repeat(lay_tr, Tl)
-                # compose run -> packed word at the (NR,) level, then pay
-                # ONE per-row small-table gather (CLAUDE.md
-                # pack-the-lookups rule); sentinel runs (lay_ns = HN)
-                # compose to the zero pad row -> their rows route
-                # pass-through, and carry no valid rows anyway
-                rec_pad = jnp.concatenate(
-                    [rec_t, jnp.zeros((1, 2), jnp.uint32)])
-                rr_lay = rec_pad[jnp.minimum(lay_ns, HN)][row_run]
-                node_lay = lay_ns[row_run] if has_cat else None
-                _, _, valid_lay, xb_lay = leafperm.unpack_layout_records(
-                    lay_rec, F, Xb.dtype)
-                do_lay, left_lay = packed_route(
-                    node_lay, lambda rf: levelwise.select_bins(xb_lay, rf),
-                    rr=rr_lay)
-                side = jnp.where(
-                    valid_lay,
-                    jnp.where(do_lay & ~left_lay, 1, 0),
-                    2).astype(jnp.int32)
-                pos, dstl, dstr, base_l, base_r, _ = leafperm.level_moves(
-                    lay_tr, side, NR)
-                lay_rec = leafperm.permute_records(
-                    lay_rec, pos, dstl, dstr, lay_tr.shape[0],
-                    platform=platform, axis_name=axis_name)
-                # node -> run inverse BEFORE advancing (candidates are
-                # parents of this level's move); sentinel runs scatter
-                # past the (HN+1,) table so mode="drop" really drops them
-                node_run = jnp.full((HN + 1,), NR, jnp.int32).at[
-                    jnp.where(lay_ns < HN, lay_ns, HN + 1)].set(
-                        jnp.arange(NR, dtype=jnp.int32), mode="drop")
-                # a run's node carries a valid split only while that node
-                # is at the current level (the expansion splits it NOW) —
-                # left child keeps the run with node 2n, right child
-                # appends node 2n+1 (advance_runs' pre-update contract)
-                valid_tab = (rec_pad[:, 0] >> 31) != 0
-                run_do = valid_tab[jnp.minimum(lay_ns, HN)] & (lay_ns < HN)
-                ns2 = jnp.where(run_do, 2 * lay_ns, lay_ns)
-                lay_tr_new, lay_ns_new = leafperm.advance_runs(
-                    ns2, run_do, 2 * lay_ns + 1, base_l, base_r,
-                    lay_tr.shape[0], sentinel=HN)
-                lay_new = (lay_rec, lay_tr_new, lay_ns_new)
-                # smaller children = contiguous segments of the NEW layout
-                rj = node_run[idx]
-                rjc = jnp.minimum(rj, NR - 1)
-                lt_l = base_l[1:] - base_l[:-1]
-                lt_r = base_r[1:] - base_r[:-1]
-                sel_ok = do & (rj < NR)
-                seg_first = jnp.where(
-                    sel_ok,
-                    jnp.where(left_smaller, base_l[rjc], base_r[rjc]), 0)
-                seg_nt = jnp.where(
-                    sel_ok,
-                    jnp.where(left_smaller, lt_l[rjc], lt_r[rjc]), 0)
+                with jax.named_scope("dryad.layout"):
+                    Tl = leafperm._TILE_ROWS
+                    lay_rec = st["lay_rec"]
+                    lay_tr = st["lay_tile_run"]
+                    lay_ns = st["lay_run_slot"]           # run -> heap node
+                    row_run = jnp.repeat(lay_tr, Tl)
+                    # compose run -> packed word at the (NR,) level, then pay
+                    # ONE per-row small-table gather (CLAUDE.md
+                    # pack-the-lookups rule); sentinel runs (lay_ns = HN)
+                    # compose to the zero pad row -> their rows route
+                    # pass-through, and carry no valid rows anyway
+                    rec_pad = jnp.concatenate(
+                        [rec_t, jnp.zeros((1, 2), jnp.uint32)])
+                    rr_lay = rec_pad[jnp.minimum(lay_ns, HN)][row_run]
+                    node_lay = lay_ns[row_run] if has_cat else None
+                    _, _, valid_lay, xb_lay = leafperm.unpack_layout_records(
+                        lay_rec, F, Xb.dtype)
+                    do_lay, left_lay = packed_route(
+                        node_lay, lambda rf: levelwise.select_bins(xb_lay, rf),
+                        rr=rr_lay)
+                    side = jnp.where(
+                        valid_lay,
+                        jnp.where(do_lay & ~left_lay, 1, 0),
+                        2).astype(jnp.int32)
+                    pos, dstl, dstr, base_l, base_r, _ = leafperm.level_moves(
+                        lay_tr, side, NR)
+                    lay_rec = leafperm.permute_records(
+                        lay_rec, pos, dstl, dstr, lay_tr.shape[0],
+                        platform=platform, axis_name=axis_name)
+                    # node -> run inverse BEFORE advancing (candidates are
+                    # parents of this level's move); sentinel runs scatter
+                    # past the (HN+1,) table so mode="drop" really drops them
+                    node_run = jnp.full((HN + 1,), NR, jnp.int32).at[
+                        jnp.where(lay_ns < HN, lay_ns, HN + 1)].set(
+                            jnp.arange(NR, dtype=jnp.int32), mode="drop")
+                    # a run's node carries a valid split only while that node
+                    # is at the current level (the expansion splits it NOW) —
+                    # left child keeps the run with node 2n, right child
+                    # appends node 2n+1 (advance_runs' pre-update contract)
+                    valid_tab = (rec_pad[:, 0] >> 31) != 0
+                    run_do = valid_tab[jnp.minimum(lay_ns, HN)] & (lay_ns < HN)
+                    ns2 = jnp.where(run_do, 2 * lay_ns, lay_ns)
+                    lay_tr_new, lay_ns_new = leafperm.advance_runs(
+                        ns2, run_do, 2 * lay_ns + 1, base_l, base_r,
+                        lay_tr.shape[0], sentinel=HN)
+                    lay_new = (lay_rec, lay_tr_new, lay_ns_new)
+                    # smaller children = contiguous segments of the NEW layout
+                    rj = node_run[idx]
+                    rjc = jnp.minimum(rj, NR - 1)
+                    lt_l = base_l[1:] - base_l[:-1]
+                    lt_r = base_r[1:] - base_r[:-1]
+                    sel_ok = do & (rj < NR)
+                    seg_first = jnp.where(
+                        sel_ok,
+                        jnp.where(left_smaller, base_l[rjc], base_r[rjc]), 0)
+                    seg_nt = jnp.where(
+                        sel_ok,
+                        jnp.where(left_smaller, lt_l[rjc], lt_r[rjc]), 0)
                 hist_small = leafperm.hist_from_layout(
                     lay_rec, seg_first, seg_nt, P, B, F, Xb.dtype,
                     n_sel_tiles, axis_name=axis_name, platform=platform,
                     hist_reduce=hr_mode)
             else:
-                small_heap = 2 * idx + jnp.where(left_smaller, 0, 1)
-                colof = jnp.full((HN,), P, jnp.int32).at[
-                    jnp.where(do, small_heap, HN)].set(jarr, mode="drop")
-                smallsel = jnp.where(bag_mask, colof[row_node], P)
-                bound_ok = axis_name is None and N < (1 << 24)
-                if use_nat:
-                    from dryad_tpu.engine import pallas_hist
+                with jax.named_scope("dryad.hist"):
+                    small_heap = 2 * idx + jnp.where(left_smaller, 0, 1)
+                    colof = jnp.full((HN,), P, jnp.int32).at[
+                        jnp.where(do, small_heap, HN)].set(jarr, mode="drop")
+                    smallsel = jnp.where(bag_mask, colof[row_node], P)
+                    bound_ok = axis_name is None and N < (1 << 24)
+                    if use_nat:
+                        from dryad_tpu.engine import pallas_hist
 
-                    hist_small = pallas_hist.build_hist_small(
-                        nat_tiles, g, h, smallsel, P, B, F,
-                        axis_name=axis_name, platform=platform,
-                        hist_reduce=hr_mode)
-                else:
-                    # exact per-column counts (smaller-child C off the
-                    # parent histogram) admit the pad-injected aligned
-                    # sort inside build_hist_segmented — see levelwise.py
-                    small_cnt = (jnp.where(do,
-                                           jnp.where(left_smaller, CL, CR),
-                                           0.0).astype(jnp.int32)
-                                 if bound_ok else None)
-                    hist_small = build_hist_segmented(
-                        Xb, g, h, smallsel, P, B,
-                        rows_per_chunk=p.rows_per_chunk, axis_name=axis_name,
-                        precision=p.hist_precision, backend=p.hist_backend,
-                        rows_bound=(N // 2 + 1) if bound_ok else None,
-                        platform=platform, records=records,
-                        sel_counts=small_cnt,
-                        # deep caps leave most expansion slots empty —
-                        # exactly where staged gather prefixes pay (see
-                        # levelwise.py)
-                        stage_gather=L < Pf,
-                        hist_reduce=hr_mode,
-                    )
-            hist_large = st["hists"][jnp.minimum(jarr, Pf - 1)] - hist_small
-            ls = left_smaller[:, None, None, None]
-            hist_l = jnp.where(ls, hist_small, hist_large)
-            hist_r = jnp.where(ls, hist_large, hist_small)
-            # children hists land at level-(d+1) offsets 2j / 2j+1; the
-            # final level's children (never split) fall off the buffer and
-            # are dropped
-            hists = st["hists"].at[
-                jnp.where(do, 2 * jarr, Pf)].set(hist_l, mode="drop")
-            hists = hists.at[
-                jnp.where(do, 2 * jarr + 1, Pf)].set(hist_r, mode="drop")
+                        hist_small = pallas_hist.build_hist_small(
+                            nat_tiles, g, h, smallsel, P, B, F,
+                            axis_name=axis_name, platform=platform,
+                            hist_reduce=hr_mode)
+                    else:
+                        # exact per-column counts (smaller-child C off the
+                        # parent histogram) admit the pad-injected aligned
+                        # sort inside build_hist_segmented — see levelwise.py
+                        small_cnt = (jnp.where(do,
+                                               jnp.where(left_smaller, CL, CR),
+                                               0.0).astype(jnp.int32)
+                                     if bound_ok else None)
+                        hist_small = build_hist_segmented(
+                            Xb, g, h, smallsel, P, B,
+                            rows_per_chunk=p.rows_per_chunk, axis_name=axis_name,
+                            precision=p.hist_precision, backend=p.hist_backend,
+                            rows_bound=(N // 2 + 1) if bound_ok else None,
+                            platform=platform, records=records,
+                            sel_counts=small_cnt,
+                            # deep caps leave most expansion slots empty —
+                            # exactly where staged gather prefixes pay (see
+                            # levelwise.py)
+                            stage_gather=L < Pf,
+                            hist_reduce=hr_mode,
+                        )
+            with jax.named_scope("dryad.hist"):
+                hist_large = st["hists"][jnp.minimum(jarr, Pf - 1)] - hist_small
+                ls = left_smaller[:, None, None, None]
+                hist_l = jnp.where(ls, hist_small, hist_large)
+                hist_r = jnp.where(ls, hist_large, hist_small)
+                # children hists land at level-(d+1) offsets 2j / 2j+1; the
+                # final level's children (never split) fall off the buffer and
+                # are dropped
+                hists = st["hists"].at[
+                    jnp.where(do, 2 * jarr, Pf)].set(hist_l, mode="drop")
+                hists = hists.at[
+                    jnp.where(do, 2 * jarr + 1, Pf)].set(hist_r, mode="drop")
 
             # ---- children stats + their best splits ----------------------
-            lo_p, hi_p = st["nd_lo"][idx], st["nd_hi"][idx]
-            if mono is not None:
-                lo_l, hi_l, lo_r, hi_r = child_bounds(
-                    mono, sf, GL, HL, GR, HR, jnp.float32(p.lambda_l2),
-                    lo_p, hi_p)
-            else:
-                lo_l = lo_r = lo_p
-                hi_l = hi_r = hi_p
-            ch_heap = jnp.concatenate([2 * idx, 2 * idx + 1])
-            ch_do = jnp.concatenate([do, do])
-            ch_hist = jnp.concatenate([hist_l, hist_r])
-            ch_G = jnp.concatenate([GL, GR])
-            ch_H = jnp.concatenate([HL, HR])
-            ch_C = jnp.concatenate([CL, CR])
-            ch_lo = jnp.concatenate([lo_l, lo_r])
-            ch_hi = jnp.concatenate([hi_l, hi_r])
-            allow = ch_do & (d + 1 < D) & (ch_C >= 2 * p.min_data_in_leaf)
-            res = level_scan(ch_hist, ch_G, ch_H, ch_C, allow, ch_lo, ch_hi)
+            with jax.named_scope("dryad.split_scan"):
+                lo_p, hi_p = st["nd_lo"][idx], st["nd_hi"][idx]
+                if mono is not None:
+                    lo_l, hi_l, lo_r, hi_r = child_bounds(
+                        mono, sf, GL, HL, GR, HR, jnp.float32(p.lambda_l2),
+                        lo_p, hi_p)
+                else:
+                    lo_l = lo_r = lo_p
+                    hi_l = hi_r = hi_p
+                ch_heap = jnp.concatenate([2 * idx, 2 * idx + 1])
+                ch_do = jnp.concatenate([do, do])
+                ch_hist = jnp.concatenate([hist_l, hist_r])
+                ch_G = jnp.concatenate([GL, GR])
+                ch_H = jnp.concatenate([HL, HR])
+                ch_C = jnp.concatenate([CL, CR])
+                ch_lo = jnp.concatenate([lo_l, lo_r])
+                ch_hi = jnp.concatenate([hi_l, hi_r])
+                allow = ch_do & (d + 1 < D) & (ch_C >= 2 * p.min_data_in_leaf)
+                res = level_scan(ch_hist, ch_G, ch_H, ch_C, allow, ch_lo, ch_hi)
 
-            cidx = jnp.where(ch_do, ch_heap, HN)
-            st_new = dict(st)
-            st_new["row_node"] = row_node
-            st_new["hists"] = hists
-            st_new["nd_gain"] = st["nd_gain"].at[cidx].set(res.gain,
-                                                           mode="drop")
-            st_new["nd_feature"] = st["nd_feature"].at[cidx].set(
-                res.feature, mode="drop")
-            st_new["nd_thresh"] = st["nd_thresh"].at[cidx].set(
-                res.threshold, mode="drop")
-            st_new["nd_GL"] = st["nd_GL"].at[cidx].set(res.g_left, mode="drop")
-            st_new["nd_HL"] = st["nd_HL"].at[cidx].set(res.h_left, mode="drop")
-            st_new["nd_CL"] = st["nd_CL"].at[cidx].set(res.c_left, mode="drop")
-            st_new["nd_G"] = st["nd_G"].at[cidx].set(ch_G, mode="drop")
-            st_new["nd_H"] = st["nd_H"].at[cidx].set(ch_H, mode="drop")
-            st_new["nd_C"] = st["nd_C"].at[cidx].set(ch_C, mode="drop")
-            st_new["nd_dleft"] = st["nd_dleft"].at[cidx].set(
-                res.default_left, mode="drop")
-            st_new["nd_catmask"] = st["nd_catmask"].at[cidx].set(
-                res.cat_mask, mode="drop")
-            st_new["nd_lo"] = st["nd_lo"].at[cidx].set(ch_lo, mode="drop")
-            st_new["nd_hi"] = st["nd_hi"].at[cidx].set(ch_hi, mode="drop")
+                cidx = jnp.where(ch_do, ch_heap, HN)
+                st_new = dict(st)
+                st_new["row_node"] = row_node
+                st_new["hists"] = hists
+                st_new["nd_gain"] = st["nd_gain"].at[cidx].set(res.gain,
+                                                               mode="drop")
+                st_new["nd_feature"] = st["nd_feature"].at[cidx].set(
+                    res.feature, mode="drop")
+                st_new["nd_thresh"] = st["nd_thresh"].at[cidx].set(
+                    res.threshold, mode="drop")
+                st_new["nd_GL"] = st["nd_GL"].at[cidx].set(res.g_left, mode="drop")
+                st_new["nd_HL"] = st["nd_HL"].at[cidx].set(res.h_left, mode="drop")
+                st_new["nd_CL"] = st["nd_CL"].at[cidx].set(res.c_left, mode="drop")
+                st_new["nd_G"] = st["nd_G"].at[cidx].set(ch_G, mode="drop")
+                st_new["nd_H"] = st["nd_H"].at[cidx].set(ch_H, mode="drop")
+                st_new["nd_C"] = st["nd_C"].at[cidx].set(ch_C, mode="drop")
+                st_new["nd_dleft"] = st["nd_dleft"].at[cidx].set(
+                    res.default_left, mode="drop")
+                st_new["nd_catmask"] = st["nd_catmask"].at[cidx].set(
+                    res.cat_mask, mode="drop")
+                st_new["nd_lo"] = st["nd_lo"].at[cidx].set(ch_lo, mode="drop")
+                st_new["nd_hi"] = st["nd_hi"].at[cidx].set(ch_hi, mode="drop")
             if use_layout:
                 (st_new["lay_rec"], st_new["lay_tile_run"],
                  st_new["lay_run_slot"]) = lay_new
@@ -620,26 +628,27 @@ def grow_tree_leafwise_batched(
     nd_C_sel = exp_st["nd_C"]
     nd_lo, nd_hi = exp_st["nd_lo"], exp_st["nd_hi"]
 
-    sel_st = {
-        "slot_heap": jnp.zeros((L,), jnp.int32).at[0].set(1),
-        "slot_tree": jnp.full((L,), -1, jnp.int32).at[0].set(0),
-        "slot_gain": jnp.full((L,), NEG_INF, jnp.float32).at[0].set(
-            nd_gain[1]),
-        "slot_depth": jnp.zeros((L,), jnp.int32),
-        "feature": jnp.full((M,), -1, jnp.int32),
-        "threshold": jnp.zeros((M,), jnp.int32),
-        "gain": jnp.zeros((M,), jnp.float32),
-        "cover": jnp.zeros((M,), jnp.float32).at[0].set(nd_C_sel[1]),
-        "left": jnp.zeros((M,), jnp.int32),
-        "right": jnp.zeros((M,), jnp.int32),
-        "is_cat": jnp.zeros((M,), bool),
-        "cat_nodes": jnp.zeros((M, Bc), bool),
-        "node_dleft": jnp.ones((M,), bool),
-        "selected": jnp.zeros((HN,), bool),
-        "child_tree": jnp.zeros((HN,), jnp.int32),
-        "num_nodes": jnp.int32(1),
-        "max_depth": jnp.int32(0),
-    }
+    with jax.named_scope("dryad.split_scan"):
+        sel_st = {
+            "slot_heap": jnp.zeros((L,), jnp.int32).at[0].set(1),
+            "slot_tree": jnp.full((L,), -1, jnp.int32).at[0].set(0),
+            "slot_gain": jnp.full((L,), NEG_INF, jnp.float32).at[0].set(
+                nd_gain[1]),
+            "slot_depth": jnp.zeros((L,), jnp.int32),
+            "feature": jnp.full((M,), -1, jnp.int32),
+            "threshold": jnp.zeros((M,), jnp.int32),
+            "gain": jnp.zeros((M,), jnp.float32),
+            "cover": jnp.zeros((M,), jnp.float32).at[0].set(nd_C_sel[1]),
+            "left": jnp.zeros((M,), jnp.int32),
+            "right": jnp.zeros((M,), jnp.int32),
+            "is_cat": jnp.zeros((M,), bool),
+            "cat_nodes": jnp.zeros((M, Bc), bool),
+            "node_dleft": jnp.ones((M,), bool),
+            "selected": jnp.zeros((HN,), bool),
+            "child_tree": jnp.zeros((HN,), jnp.int32),
+            "num_nodes": jnp.int32(1),
+            "max_depth": jnp.int32(0),
+        }
 
     def do_split(k, s, st):
         n = st["slot_heap"][s]
@@ -687,33 +696,37 @@ def grow_tree_leafwise_batched(
                             lambda st_: do_split(k, s, st_),
                             lambda st_: st_, st)
 
-    sel_st = jax.lax.fori_loop(0, L - 1, sel_body, sel_st)
+    with jax.named_scope("dryad.split_scan"):
+        sel_st = jax.lax.fori_loop(0, L - 1, sel_body, sel_st)
 
     # ---- finalize -------------------------------------------------------------
-    sh = jnp.clip(sel_st["slot_heap"], 0, HN - 1)
-    value = finalize_leaf_values(
-        p, M, sel_st["slot_tree"], nd_G[sh], nd_H[sh],
-        jnp.zeros((M,), jnp.float32),
-        slot_lo=nd_lo[sh] if mono is not None else None,
-        slot_hi=nd_hi[sh] if mono is not None else None,
-    )
-    cat_bitset = pack_cat_bitset(sel_st["cat_nodes"], M)
+    with jax.named_scope("dryad.split_scan"):
+        sh = jnp.clip(sel_st["slot_heap"], 0, HN - 1)
+        value = finalize_leaf_values(
+            p, M, sel_st["slot_tree"], nd_G[sh], nd_H[sh],
+            jnp.zeros((M,), jnp.float32),
+            slot_lo=nd_lo[sh] if mono is not None else None,
+            slot_hi=nd_hi[sh] if mono is not None else None,
+        )
+        cat_bitset = pack_cat_bitset(sel_st["cat_nodes"], M)
 
-    # map every heap node to its leaf in the SELECTED tree: walking down,
-    # a node resolves to its own tree id where its parent was selected,
-    # else inherits the parent's resolution (D static levels)
-    leaf_of = jnp.zeros((HN,), jnp.int32)
-    selected = sel_st["selected"]
-    child_tree = sel_st["child_tree"]
-    idx_all = jnp.arange(HN, dtype=jnp.int32)
-    for d in range(1, D + 1):
-        lvl = (idx_all >> d) == 1
-        par = idx_all >> 1
-        leaf_of = jnp.where(lvl,
-                            jnp.where(selected[par], child_tree[idx_all],
-                                      leaf_of[par]),
-                            leaf_of)
+        # map every heap node to its leaf in the SELECTED tree: walking down,
+        # a node resolves to its own tree id where its parent was selected,
+        # else inherits the parent's resolution (D static levels)
+        leaf_of = jnp.zeros((HN,), jnp.int32)
+        selected = sel_st["selected"]
+        child_tree = sel_st["child_tree"]
+        idx_all = jnp.arange(HN, dtype=jnp.int32)
+        for d in range(1, D + 1):
+            lvl = (idx_all >> d) == 1
+            par = idx_all >> 1
+            leaf_of = jnp.where(lvl,
+                                jnp.where(selected[par], child_tree[idx_all],
+                                          leaf_of[par]),
+                                leaf_of)
 
+    with jax.named_scope("dryad.score"):
+        row_leaf = leaf_of[jnp.clip(exp_st["row_node"], 0, HN - 1)]
     return {
         "feature": sel_st["feature"],
         "threshold": sel_st["threshold"],
@@ -726,5 +739,5 @@ def grow_tree_leafwise_batched(
         "default_left": sel_st["node_dleft"],
         "cover": sel_st["cover"],
         "max_depth": sel_st["max_depth"],
-        "row_leaf": leaf_of[jnp.clip(exp_st["row_node"], 0, HN - 1)],
+        "row_leaf": row_leaf,
     }

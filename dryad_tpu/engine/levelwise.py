@@ -335,53 +335,55 @@ def grow_tree_levelwise(
     # ALL rows are partitioned (bag gates histograms only) so the final
     # row_slot yields each row's leaf without a separate traversal pass;
     # derived from bag_mask to inherit the shard's varying-manual-axes
-    row_slot = jnp.where(bag_mask, 0, 0).astype(jnp.int32)
+    with jax.named_scope("dryad.route"):
+        row_slot = jnp.where(bag_mask, 0, 0).astype(jnp.int32)
     hist0 = root_hist if root_hist is not None else build_hist(
         Xb, g, h, bag_mask, B,
         rows_per_chunk=p.rows_per_chunk, axis_name=axis_name,
         precision=p.hist_precision, backend=p.hist_backend,
         platform=platform)
-    G0, H0, C0 = root_stats(hist0)
-    ninf, pinf = jnp.float32(-jnp.inf), jnp.float32(jnp.inf)
-    root = best(hist0, G0, H0, C0,
-                (jnp.int32(0) < depth_cap) & (C0 >= 2 * p.min_data_in_leaf),
-                ninf, pinf)
-    Bc = root.cat_mask.shape[0]
+    with jax.named_scope("dryad.split_scan"):
+        G0, H0, C0 = root_stats(hist0)
+        ninf, pinf = jnp.float32(-jnp.inf), jnp.float32(jnp.inf)
+        root = best(hist0, G0, H0, C0,
+                    (jnp.int32(0) < depth_cap) & (C0 >= 2 * p.min_data_in_leaf),
+                    ninf, pinf)
+        Bc = root.cat_mask.shape[0]
 
-    slot_node = jnp.full((L,), -1, jnp.int32).at[0].set(0)
-    slot_gain = jnp.full((L,), NEG_INF, jnp.float32).at[0].set(root.gain)
-    slot_G = jnp.zeros((L,), jnp.float32).at[0].set(G0)
-    slot_H = jnp.zeros((L,), jnp.float32).at[0].set(H0)
-    slot_C = jnp.zeros((L,), jnp.float32).at[0].set(C0)
-    slot_depth = jnp.zeros((L,), jnp.int32)
-    slot_lo = jnp.full((L,), ninf, jnp.float32)
-    slot_hi = jnp.full((L,), pinf, jnp.float32)
-    sp_feature = jnp.full((L,), -1, jnp.int32).at[0].set(root.feature)
-    sp_thresh = jnp.zeros((L,), jnp.int32).at[0].set(root.threshold)
-    sp_GL = jnp.zeros((L,), jnp.float32).at[0].set(root.g_left)
-    sp_HL = jnp.zeros((L,), jnp.float32).at[0].set(root.h_left)
-    sp_CL = jnp.zeros((L,), jnp.float32).at[0].set(root.c_left)
-    sp_catmask = jnp.zeros((L, Bc), bool).at[0].set(root.cat_mask)
-    sp_dleft = jnp.ones((L,), bool).at[0].set(root.default_left)
-    # feature arm: the carried histogram buffer holds each shard's OWNED
-    # slice only (an n-fold HBM saving to boot); the replicated root hist
-    # is sliced once here so level-0 subtraction stays slice-local
-    hist0_loc = (_dist.feature_shard_slice(hist0, axis_name, axis=1)
-                 if feat_par else hist0)
-    hists = jnp.zeros((L, 3, FH, B), jnp.float32).at[0].set(hist0_loc)
+        slot_node = jnp.full((L,), -1, jnp.int32).at[0].set(0)
+        slot_gain = jnp.full((L,), NEG_INF, jnp.float32).at[0].set(root.gain)
+        slot_G = jnp.zeros((L,), jnp.float32).at[0].set(G0)
+        slot_H = jnp.zeros((L,), jnp.float32).at[0].set(H0)
+        slot_C = jnp.zeros((L,), jnp.float32).at[0].set(C0)
+        slot_depth = jnp.zeros((L,), jnp.int32)
+        slot_lo = jnp.full((L,), ninf, jnp.float32)
+        slot_hi = jnp.full((L,), pinf, jnp.float32)
+        sp_feature = jnp.full((L,), -1, jnp.int32).at[0].set(root.feature)
+        sp_thresh = jnp.zeros((L,), jnp.int32).at[0].set(root.threshold)
+        sp_GL = jnp.zeros((L,), jnp.float32).at[0].set(root.g_left)
+        sp_HL = jnp.zeros((L,), jnp.float32).at[0].set(root.h_left)
+        sp_CL = jnp.zeros((L,), jnp.float32).at[0].set(root.c_left)
+        sp_catmask = jnp.zeros((L, Bc), bool).at[0].set(root.cat_mask)
+        sp_dleft = jnp.ones((L,), bool).at[0].set(root.default_left)
+        # feature arm: the carried histogram buffer holds each shard's OWNED
+        # slice only (an n-fold HBM saving to boot); the replicated root hist
+        # is sliced once here so level-0 subtraction stays slice-local
+        hist0_loc = (_dist.feature_shard_slice(hist0, axis_name, axis=1)
+                     if feat_par else hist0)
+        hists = jnp.zeros((L, 3, FH, B), jnp.float32).at[0].set(hist0_loc)
 
-    cover_arr = jnp.zeros((M,), jnp.float32).at[0].set(C0)
-    feature = jnp.full((M,), -1, jnp.int32)
-    threshold = jnp.zeros((M,), jnp.int32)
-    gain_arr = jnp.zeros((M,), jnp.float32)
-    left = jnp.zeros((M,), jnp.int32)
-    right = jnp.zeros((M,), jnp.int32)
-    is_cat_arr = jnp.zeros((M,), bool)
-    cat_nodes = jnp.zeros((M, Bc), bool)
-    node_dleft = jnp.ones((M,), bool)
-    num_nodes = jnp.int32(1)
-    splits_done = jnp.int32(0)
-    max_depth = jnp.int32(0)
+        cover_arr = jnp.zeros((M,), jnp.float32).at[0].set(C0)
+        feature = jnp.full((M,), -1, jnp.int32)
+        threshold = jnp.zeros((M,), jnp.int32)
+        gain_arr = jnp.zeros((M,), jnp.float32)
+        left = jnp.zeros((M,), jnp.int32)
+        right = jnp.zeros((M,), jnp.int32)
+        is_cat_arr = jnp.zeros((M,), bool)
+        cat_nodes = jnp.zeros((M, Bc), bool)
+        node_dleft = jnp.ones((M,), bool)
+        num_nodes = jnp.int32(1)
+        splits_done = jnp.int32(0)
+        max_depth = jnp.int32(0)
 
     # ---- levels: two fori_loop phases with level-appropriate widths ----------
     # A Python unroll over levels would multiply the XLA program by depth_cap
@@ -462,50 +464,51 @@ def grow_tree_levelwise(
                 st["gain"], st["left"], st["right"], st["is_cat"], st["cat_nodes"],
                 st["node_dleft"], st["num_nodes"], st["splits_done"],
                 st["max_depth"])
-            at_level = (slot_depth == d) & (slot_gain > NEG_INF) & (slot_node >= 0)
-            # gain-descending order, stable => lowest slot id wins ties, exactly
-            # the CPU trainer's repeated first-max argmax sequence
-            # dryadlint: disable=wired-grower-sort -- (L,)-slot gain ranking, L <= 512; not a row sort (rows never sort on the wired path)
-            order = jnp.argsort(jnp.where(at_level, -slot_gain, jnp.inf), stable=True)
-            cand = order[:P].astype(jnp.int32)
-            budget_left = (L - 1) - splits_done
-            do = at_level[cand] & (jnp.arange(P) < budget_left)
-            n_do = jnp.sum(do.astype(jnp.int32))
+            with jax.named_scope("dryad.split_scan"):
+                at_level = (slot_depth == d) & (slot_gain > NEG_INF) & (slot_node >= 0)
+                # gain-descending order, stable => lowest slot id wins ties, exactly
+                # the CPU trainer's repeated first-max argmax sequence
+                # dryadlint: disable=wired-grower-sort -- (L,)-slot gain ranking, L <= 512; not a row sort (rows never sort on the wired path)
+                order = jnp.argsort(jnp.where(at_level, -slot_gain, jnp.inf), stable=True)
+                cand = order[:P].astype(jnp.int32)
+                budget_left = (L - 1) - splits_done
+                do = at_level[cand] & (jnp.arange(P) < budget_left)
+                n_do = jnp.sum(do.astype(jnp.int32))
 
-            sj = cand
-            parent_node = slot_node[sj]
-            sf = sp_feature[sj]
-            thr = sp_thresh[sj]
-            GL, HL, CL = sp_GL[sj], sp_HL[sj], sp_CL[sj]
-            Gp, Hp, Cp = slot_G[sj], slot_H[sj], slot_C[sj]
-            GR, HR, CR = Gp - GL, Hp - HL, Cp - CL
-            cat_split = (is_cat_feat[jnp.maximum(sf, 0)] & do) if has_cat else jnp.zeros((P,), bool)
+                sj = cand
+                parent_node = slot_node[sj]
+                sf = sp_feature[sj]
+                thr = sp_thresh[sj]
+                GL, HL, CL = sp_GL[sj], sp_HL[sj], sp_CL[sj]
+                Gp, Hp, Cp = slot_G[sj], slot_H[sj], slot_C[sj]
+                GR, HR, CR = Gp - GL, Hp - HL, Cp - CL
+                cat_split = (is_cat_feat[jnp.maximum(sf, 0)] & do) if has_cat else jnp.zeros((P,), bool)
 
-            # slot/node allocation in execution (gain) order, as the CPU does
-            ks = splits_done + jnp.cumsum(do.astype(jnp.int32)) - do.astype(jnp.int32)
-            right_slot = jnp.where(do, ks + 1, L).astype(jnp.int32)
-            left_id = jnp.where(do, num_nodes + 2 * (ks - splits_done), 0).astype(jnp.int32)
-            right_id = left_id + 1
+                # slot/node allocation in execution (gain) order, as the CPU does
+                ks = splits_done + jnp.cumsum(do.astype(jnp.int32)) - do.astype(jnp.int32)
+                right_slot = jnp.where(do, ks + 1, L).astype(jnp.int32)
+                left_id = jnp.where(do, num_nodes + 2 * (ks - splits_done), 0).astype(jnp.int32)
+                right_id = left_id + 1
 
-            pidx = jnp.where(do, parent_node, M)
-            feature = feature.at[pidx].set(sf, mode="drop")
-            gain_arr = gain_arr.at[pidx].set(
-                jnp.where(do, slot_gain[sj], 0.0), mode="drop")
-            threshold = threshold.at[pidx].set(jnp.where(cat_split, 0, thr), mode="drop")
-            left = left.at[pidx].set(left_id, mode="drop")
-            right = right.at[pidx].set(right_id, mode="drop")
-            is_cat_arr = is_cat_arr.at[pidx].set(cat_split, mode="drop")
-            cat_nodes = cat_nodes.at[pidx].set(
-                jnp.where(cat_split[:, None], sp_catmask[sj], False), mode="drop"
-            )
-            node_dleft = node_dleft.at[pidx].set(sp_dleft[sj] | cat_split,
-                                                 mode="drop")
-            # per-node cover (training row count) for pred_contrib: the
-            # children's counts come off the parent-histogram prefix
-            cover_arr = st["cover"].at[
-                jnp.where(do, left_id, M)].set(CL, mode="drop")
-            cover_arr = cover_arr.at[
-                jnp.where(do, right_id, M)].set(CR, mode="drop")
+                pidx = jnp.where(do, parent_node, M)
+                feature = feature.at[pidx].set(sf, mode="drop")
+                gain_arr = gain_arr.at[pidx].set(
+                    jnp.where(do, slot_gain[sj], 0.0), mode="drop")
+                threshold = threshold.at[pidx].set(jnp.where(cat_split, 0, thr), mode="drop")
+                left = left.at[pidx].set(left_id, mode="drop")
+                right = right.at[pidx].set(right_id, mode="drop")
+                is_cat_arr = is_cat_arr.at[pidx].set(cat_split, mode="drop")
+                cat_nodes = cat_nodes.at[pidx].set(
+                    jnp.where(cat_split[:, None], sp_catmask[sj], False), mode="drop"
+                )
+                node_dleft = node_dleft.at[pidx].set(sp_dleft[sj] | cat_split,
+                                                     mode="drop")
+                # per-node cover (training row count) for pred_contrib: the
+                # children's counts come off the parent-histogram prefix
+                cover_arr = st["cover"].at[
+                    jnp.where(do, left_id, M)].set(CL, mode="drop")
+                cover_arr = cover_arr.at[
+                    jnp.where(do, right_id, M)].set(CR, mode="drop")
 
             # ---- row partition: every splitting leaf in one vectorized pass -----
             # Two measured rules shape this block (exp_level_bisect.py, 10M):
@@ -517,77 +520,78 @@ def grow_tree_levelwise(
             # lookups ride ONE packed two-word record gather instead.
             # Integer/bool results are bit-identical to the gather
             # formulation, so every parity invariant is untouched.
-            rs = jnp.minimum(row_slot, L - 1)
-            rec_t = None
-            if B <= (1 << 13) and L < (1 << 16):
-                # cat_split above is already the per-candidate cat flag (its
-                # & do is a no-op here: records only scatter where do holds)
-                cat_c = cat_split if has_cat else jnp.zeros((P,), bool)
-                w0_c = ((jnp.uint32(1) << 31)
-                        | (sp_dleft[sj].astype(jnp.uint32) << 30)
-                        | (cat_c.astype(jnp.uint32) << 29)
-                        | (jnp.clip(thr, 0, B - 1).astype(jnp.uint32) << 16)
-                        | right_slot.astype(jnp.uint32))
-                rec_t = jnp.zeros((L + 1, 2), jnp.uint32).at[
-                    jnp.where(do, sj, L + 1)].set(
-                        jnp.stack([w0_c,
-                                   jnp.maximum(sf, 0).astype(jnp.uint32)],
-                                  axis=1), mode="drop")
+            with jax.named_scope("dryad.route"):
+                rs = jnp.minimum(row_slot, L - 1)
+                rec_t = None
+                if B <= (1 << 13) and L < (1 << 16):
+                    # cat_split above is already the per-candidate cat flag (its
+                    # & do is a no-op here: records only scatter where do holds)
+                    cat_c = cat_split if has_cat else jnp.zeros((P,), bool)
+                    w0_c = ((jnp.uint32(1) << 31)
+                            | (sp_dleft[sj].astype(jnp.uint32) << 30)
+                            | (cat_c.astype(jnp.uint32) << 29)
+                            | (jnp.clip(thr, 0, B - 1).astype(jnp.uint32) << 16)
+                            | right_slot.astype(jnp.uint32))
+                    rec_t = jnp.zeros((L + 1, 2), jnp.uint32).at[
+                        jnp.where(do, sj, L + 1)].set(
+                            jnp.stack([w0_c,
+                                       jnp.maximum(sf, 0).astype(jnp.uint32)],
+                                      axis=1), mode="drop")
 
-                def packed_route(slot_idx, bins_of, rr=None):
-                    """Per-row split routing off the packed per-slot table:
-                    (splits?, goes-left?, packed word).  Shared by the
-                    natural-order partition and the layout side derivation
-                    so the two can never disagree on a row (identical
-                    integer/bool arithmetic).  ``rr`` lets the caller pass
-                    a pre-composed per-row record (one big gather instead
-                    of two chained ones — the CLAUDE.md pack-the-lookups
-                    rule); ``slot_idx`` is then only consulted for the
-                    categorical bitset row."""
-                    if rr is None:
-                        rr = rec_t[jnp.minimum(slot_idx, L)]  # ONE gather
-                    w0r = rr[:, 0]
-                    rf = rr[:, 1].astype(jnp.int32)
-                    bins_rf = bins_of(rf)
-                    thr_r = ((w0r >> 16)
-                             & jnp.uint32(0x1FFF)).astype(jnp.int32)
-                    gl = bins_rf <= thr_r
+                    def packed_route(slot_idx, bins_of, rr=None):
+                        """Per-row split routing off the packed per-slot table:
+                        (splits?, goes-left?, packed word).  Shared by the
+                        natural-order partition and the layout side derivation
+                        so the two can never disagree on a row (identical
+                        integer/bool arithmetic).  ``rr`` lets the caller pass
+                        a pre-composed per-row record (one big gather instead
+                        of two chained ones — the CLAUDE.md pack-the-lookups
+                        rule); ``slot_idx`` is then only consulted for the
+                        categorical bitset row."""
+                        if rr is None:
+                            rr = rec_t[jnp.minimum(slot_idx, L)]  # ONE gather
+                        w0r = rr[:, 0]
+                        rf = rr[:, 1].astype(jnp.int32)
+                        bins_rf = bins_of(rf)
+                        thr_r = ((w0r >> 16)
+                                 & jnp.uint32(0x1FFF)).astype(jnp.int32)
+                        gl = bins_rf <= thr_r
+                        if learn_missing:
+                            gl &= ((w0r >> 30) & 1).astype(bool) | (bins_rf > 0)
+                        if has_cat:
+                            cat_row = sp_catmask[jnp.minimum(slot_idx, L - 1),
+                                                 jnp.minimum(bins_rf, Bc - 1)]
+                            gl = jnp.where(((w0r >> 29) & 1).astype(bool),
+                                           cat_row, gl)
+                        return ((w0r >> 31) != 0), gl, w0r
+
+                    do_n, left_n, w0r = packed_route(
+                        rs, lambda rf: select_bins(Xb, rf))
+                    row_do = do_n & (row_slot < L)
+                    row_slot = jnp.where(
+                        row_do & ~left_n,
+                        (w0r & jnp.uint32(0xFFFF)).astype(jnp.int32), row_slot)
+                else:
+                    # exotic shapes (bins > 8192 or leaves >= 65536) exceed the
+                    # packed-word budget: keep the gather formulation (static
+                    # per-config choice, so every shard still runs one program)
+                    slot_do = jnp.zeros((L,), bool).at[
+                        jnp.where(do, sj, L)].set(True, mode="drop")
+                    slot_right = jnp.full((L,), L, jnp.int32).at[
+                        jnp.where(do, sj, L)].set(right_slot, mode="drop")
+                    row_do = slot_do[rs] & (row_slot < L)
+                    rf = jnp.maximum(sp_feature[rs], 0)
+                    bins_rf = jnp.take_along_axis(
+                        Xb, rf[:, None].astype(jnp.int32), axis=1)[:, 0]
+                    bins_rf = bins_rf.astype(jnp.int32)
+                    go_left = bins_rf <= sp_thresh[rs]
                     if learn_missing:
-                        gl &= ((w0r >> 30) & 1).astype(bool) | (bins_rf > 0)
+                        go_left &= sp_dleft[rs] | (bins_rf > 0)
                     if has_cat:
-                        cat_row = sp_catmask[jnp.minimum(slot_idx, L - 1),
-                                             jnp.minimum(bins_rf, Bc - 1)]
-                        gl = jnp.where(((w0r >> 29) & 1).astype(bool),
-                                       cat_row, gl)
-                    return ((w0r >> 31) != 0), gl, w0r
-
-                do_n, left_n, w0r = packed_route(
-                    rs, lambda rf: select_bins(Xb, rf))
-                row_do = do_n & (row_slot < L)
-                row_slot = jnp.where(
-                    row_do & ~left_n,
-                    (w0r & jnp.uint32(0xFFFF)).astype(jnp.int32), row_slot)
-            else:
-                # exotic shapes (bins > 8192 or leaves >= 65536) exceed the
-                # packed-word budget: keep the gather formulation (static
-                # per-config choice, so every shard still runs one program)
-                slot_do = jnp.zeros((L,), bool).at[
-                    jnp.where(do, sj, L)].set(True, mode="drop")
-                slot_right = jnp.full((L,), L, jnp.int32).at[
-                    jnp.where(do, sj, L)].set(right_slot, mode="drop")
-                row_do = slot_do[rs] & (row_slot < L)
-                rf = jnp.maximum(sp_feature[rs], 0)
-                bins_rf = jnp.take_along_axis(
-                    Xb, rf[:, None].astype(jnp.int32), axis=1)[:, 0]
-                bins_rf = bins_rf.astype(jnp.int32)
-                go_left = bins_rf <= sp_thresh[rs]
-                if learn_missing:
-                    go_left &= sp_dleft[rs] | (bins_rf > 0)
-                if has_cat:
-                    cat_row = sp_catmask[rs, jnp.minimum(bins_rf, Bc - 1)]
-                    go_left = jnp.where(is_cat_feat[rf], cat_row, go_left)
-                row_slot = jnp.where(row_do & ~go_left, slot_right[rs],
-                                     row_slot)
+                        cat_row = sp_catmask[rs, jnp.minimum(bins_rf, Bc - 1)]
+                        go_left = jnp.where(is_cat_feat[rf], cat_row, go_left)
+                    row_slot = jnp.where(row_do & ~go_left, slot_right[rs],
+                                         row_slot)
 
             # ---- one batched histogram pass for all smaller children ------------
             left_smaller = CL <= CR
@@ -600,204 +604,212 @@ def grow_tree_levelwise(
                 # identical integer/bool math), one stable per-tile MXU
                 # compaction moves the rows, and the children read back
                 # as contiguous tile runs.
-                lay_rec = st["lay_rec"]
-                lay_tr = st["lay_tile_run"]
-                lay_rs = st["lay_run_slot"]
-                row_run = jnp.repeat(lay_tr, leafperm._TILE_ROWS)
-                # compose run -> packed record at the (L,) level, then pay
-                # ONE per-row small-table gather (two chained (n_buf*T,)
-                # gathers cost ~2x — the CLAUDE.md pack-the-lookups rule);
-                # dead runs (lay_rs = L) compose to rec_t[L] = zeros, so
-                # their rows route pass-through — and carry no valid rows
-                # anyway (absorbed segments hold only sentinels)
-                rr_lay = rec_t[jnp.minimum(lay_rs, L)][row_run]
-                slot_lay = lay_rs[row_run] if has_cat else None
-                _, _, valid_lay, xb_lay = leafperm.unpack_layout_records(
-                    lay_rec, F, Xb.dtype)
-                do_lay, left_lay, _ = packed_route(
-                    slot_lay, lambda rf: select_bins(xb_lay, rf),
-                    rr=rr_lay)
-                side = jnp.where(
-                    valid_lay,
-                    jnp.where(do_lay & ~left_lay, 1, 0),
-                    2).astype(jnp.int32)
-                pos, dstl, dstr, base_l, base_r, _ = leafperm.level_moves(
-                    lay_tr, side, L)
-                lay_rec = leafperm.permute_records(
-                    lay_rec, pos, dstl, dstr, lay_tr.shape[0],
-                    platform=platform, axis_name=axis_name)
-                # slot -> run inverse BEFORE advancing (candidates are
-                # parents of this level's move); dead runs scatter to
-                # L + 1 — OUT of the (L+1,) table so mode="drop" really
-                # drops them (index L is in range and would overwrite the
-                # sentinel cell the rj clamp below relies on)
-                slot_run = jnp.full((L + 1,), L, jnp.int32).at[
-                    jnp.where(lay_rs < L, lay_rs, L + 1)].set(
-                        jnp.arange(L, dtype=jnp.int32), mode="drop")
-                slot_do_t = (rec_t[:, 0] >> 31) != 0   # (L+1,) dense tables
-                slot_right_t = (rec_t[:, 0]
-                                & jnp.uint32(0xFFFF)).astype(jnp.int32)
-                lrs_c = jnp.minimum(lay_rs, L)
-                run_do = slot_do_t[lrs_c] & (lay_rs < L)
-                run_right = slot_right_t[lrs_c]
-                lay_tr_new, lay_rs_new = leafperm.advance_runs(
-                    lay_rs, run_do, run_right, base_l, base_r,
-                    lay_tr.shape[0])
-                # children = contiguous segments of the NEW layout
-                rj = slot_run[jnp.minimum(sj, L)]
-                rjc = jnp.minimum(rj, L - 1)
-                lt_l = base_l[1:] - base_l[:-1]
-                lt_r = base_r[1:] - base_r[:-1]
-                sel_ok = do & (rj < L)
+                with jax.named_scope("dryad.layout"):
+                    lay_rec = st["lay_rec"]
+                    lay_tr = st["lay_tile_run"]
+                    lay_rs = st["lay_run_slot"]
+                    row_run = jnp.repeat(lay_tr, leafperm._TILE_ROWS)
+                    # compose run -> packed record at the (L,) level, then pay
+                    # ONE per-row small-table gather (two chained (n_buf*T,)
+                    # gathers cost ~2x — the CLAUDE.md pack-the-lookups rule);
+                    # dead runs (lay_rs = L) compose to rec_t[L] = zeros, so
+                    # their rows route pass-through — and carry no valid rows
+                    # anyway (absorbed segments hold only sentinels)
+                    rr_lay = rec_t[jnp.minimum(lay_rs, L)][row_run]
+                    slot_lay = lay_rs[row_run] if has_cat else None
+                    _, _, valid_lay, xb_lay = leafperm.unpack_layout_records(
+                        lay_rec, F, Xb.dtype)
+                    do_lay, left_lay, _ = packed_route(
+                        slot_lay, lambda rf: select_bins(xb_lay, rf),
+                        rr=rr_lay)
+                    side = jnp.where(
+                        valid_lay,
+                        jnp.where(do_lay & ~left_lay, 1, 0),
+                        2).astype(jnp.int32)
+                    pos, dstl, dstr, base_l, base_r, _ = leafperm.level_moves(
+                        lay_tr, side, L)
+                    lay_rec = leafperm.permute_records(
+                        lay_rec, pos, dstl, dstr, lay_tr.shape[0],
+                        platform=platform, axis_name=axis_name)
+                    # slot -> run inverse BEFORE advancing (candidates are
+                    # parents of this level's move); dead runs scatter to
+                    # L + 1 — OUT of the (L+1,) table so mode="drop" really
+                    # drops them (index L is in range and would overwrite the
+                    # sentinel cell the rj clamp below relies on)
+                    slot_run = jnp.full((L + 1,), L, jnp.int32).at[
+                        jnp.where(lay_rs < L, lay_rs, L + 1)].set(
+                            jnp.arange(L, dtype=jnp.int32), mode="drop")
+                    slot_do_t = (rec_t[:, 0] >> 31) != 0   # (L+1,) dense tables
+                    slot_right_t = (rec_t[:, 0]
+                                    & jnp.uint32(0xFFFF)).astype(jnp.int32)
+                    lrs_c = jnp.minimum(lay_rs, L)
+                    run_do = slot_do_t[lrs_c] & (lay_rs < L)
+                    run_right = slot_right_t[lrs_c]
+                    lay_tr_new, lay_rs_new = leafperm.advance_runs(
+                        lay_rs, run_do, run_right, base_l, base_r,
+                        lay_tr.shape[0])
+                    # children = contiguous segments of the NEW layout
+                    rj = slot_run[jnp.minimum(sj, L)]
+                    rjc = jnp.minimum(rj, L - 1)
+                    lt_l = base_l[1:] - base_l[:-1]
+                    lt_r = base_r[1:] - base_r[:-1]
+                    sel_ok = do & (rj < L)
                 if p.hist_subtraction:
-                    seg_first = jnp.where(
-                        sel_ok,
-                        jnp.where(left_smaller, base_l[rjc], base_r[rjc]), 0)
-                    seg_nt = jnp.where(
-                        sel_ok,
-                        jnp.where(left_smaller, lt_l[rjc], lt_r[rjc]), 0)
+                    with jax.named_scope("dryad.layout"):
+                        seg_first = jnp.where(
+                            sel_ok,
+                            jnp.where(left_smaller, base_l[rjc], base_r[rjc]), 0)
+                        seg_nt = jnp.where(
+                            sel_ok,
+                            jnp.where(left_smaller, lt_l[rjc], lt_r[rjc]), 0)
                     hist_small = leafperm.hist_from_layout(
                         lay_rec, seg_first, seg_nt, P, B, F, Xb.dtype,
                         n_sel_tiles, axis_name=axis_name, platform=platform,
                         hist_reduce=hr_mode)
-                    hist_large = hists[sj] - hist_small
-                    ls = left_smaller[:, None, None, None]
-                    hist_l = jnp.where(ls, hist_small, hist_large)
-                    hist_r = jnp.where(ls, hist_large, hist_small)
+                    with jax.named_scope("dryad.hist"):
+                        hist_large = hists[sj] - hist_small
+                        ls = left_smaller[:, None, None, None]
+                        hist_l = jnp.where(ls, hist_small, hist_large)
+                        hist_r = jnp.where(ls, hist_large, hist_small)
                 else:
                     # non-subtraction lift (r10): BOTH children in ONE
                     # 2P-column pass — columns [left 0..P-1 | right
                     # P..2P-1], every live row read exactly once (the
                     # legacy arm pays a small pass + a full
                     # build_hist_multi)
-                    segf2 = jnp.concatenate([
-                        jnp.where(sel_ok, base_l[rjc], 0),
-                        jnp.where(sel_ok, base_r[rjc], 0)])
-                    segn2 = jnp.concatenate([
-                        jnp.where(sel_ok, lt_l[rjc], 0),
-                        jnp.where(sel_ok, lt_r[rjc], 0)])
+                    with jax.named_scope("dryad.layout"):
+                        segf2 = jnp.concatenate([
+                            jnp.where(sel_ok, base_l[rjc], 0),
+                            jnp.where(sel_ok, base_r[rjc], 0)])
+                        segn2 = jnp.concatenate([
+                            jnp.where(sel_ok, lt_l[rjc], 0),
+                            jnp.where(sel_ok, lt_r[rjc], 0)])
                     h2 = leafperm.hist_from_layout(
                         lay_rec, segf2, segn2, 2 * P, B, F, Xb.dtype,
                         n_sel_tiles, axis_name=axis_name, platform=platform,
                         hist_reduce=hr_mode)
-                    hist_l, hist_r = h2[:P], h2[P:]
+                    with jax.named_scope("dryad.hist"):
+                        hist_l, hist_r = h2[:P], h2[P:]
                 st = dict(st, lay_rec=lay_rec, lay_tile_run=lay_tr_new,
                           lay_run_slot=lay_rs_new)
             else:
-                small_slot = jnp.where(left_smaller, sj, right_slot)
-                large_slot = jnp.where(left_smaller, right_slot, sj)
-                # non-do candidates scatter to L+1 (out of bounds, dropped);
-                # out-of-bag rows are excluded by the explicit bag_mask gate
-                # below — row_slot itself stays in [0, L-1] for every row
-                # now that the partition routes the whole dataset
-                colof = jnp.full((L + 1,), P, jnp.int32).at[
-                    jnp.where(do, small_slot, L + 1)].set(
-                        jnp.arange(P, dtype=jnp.int32), mode="drop")
-                # bag gates the histogram selection; out-of-bag rows are
-                # partitioned but never accumulated
-                smallsel = jnp.where(bag_mask,
-                                     colof[jnp.minimum(row_slot, L)], P)
-                # Single device, smaller children cover at most half the
-                # rows (min(left,right) <= parent/2, parents disjoint) ->
-                # half the tile grid.  Under shard_map the smaller child is
-                # chosen on GLOBAL counts and one shard's share of it may
-                # exceed half that shard, so no bound applies there; ditto
-                # above 2^24 rows, where the fp32 histogram counts backing
-                # the smaller-child choice stop being exact.
-                bound_ok = half_bound_ok
-                if use_nat:
-                    from dryad_tpu.engine import pallas_hist
-
-                    hist_small = pallas_hist.build_hist_small(
-                        nat_tiles, g, h, smallsel, P, B, F,
-                        axis_name=axis_name, platform=platform,
-                        hist_reduce=hr_mode)
-                else:
-                    # exact per-column counts (smaller-child C off the
-                    # parent histogram, integer-exact in f32 below 2**24)
-                    # admit the pad-injected aligned sort inside
-                    # build_hist_segmented — the plan's alignment gather
-                    # drops out; single-device only, where the counts
-                    # describe the whole selection
-                    small_cnt = (jnp.where(do,
-                                           jnp.where(left_smaller, CL, CR),
-                                           0.0).astype(jnp.int32)
-                                 if bound_ok else None)
-                    hist_small = build_hist_segmented(
-                        Xb, g, h, smallsel, P, B,
-                        rows_per_chunk=p.rows_per_chunk, axis_name=axis_name,
-                        precision=p.hist_precision, backend=p.hist_backend,
-                        rows_bound=(N // 2 + 1) if bound_ok else None,
-                        platform=platform, records=records,
-                        sel_counts=small_cnt,
-                        # staged prefixes only pay when the leaf budget caps
-                        # deep levels (fills provably collapse); a full tree
-                        # keeps every prefix ~100% and the extra gather
-                        # branches only bloat (remote) compile
-                        stage_gather=(L - 1) < (1 << (depth_cap - 1)),
-                        hist_reduce=hr_mode,
-                    )
-                if p.hist_subtraction:
-                    hist_large = hists[sj] - hist_small
-                else:
-                    largesel = jnp.full((L + 1,), P, jnp.int32).at[
-                        jnp.where(do, large_slot, L + 1)].set(
+                with jax.named_scope("dryad.hist"):
+                    small_slot = jnp.where(left_smaller, sj, right_slot)
+                    large_slot = jnp.where(left_smaller, right_slot, sj)
+                    # non-do candidates scatter to L+1 (out of bounds, dropped);
+                    # out-of-bag rows are excluded by the explicit bag_mask gate
+                    # below — row_slot itself stays in [0, L-1] for every row
+                    # now that the partition routes the whole dataset
+                    colof = jnp.full((L + 1,), P, jnp.int32).at[
+                        jnp.where(do, small_slot, L + 1)].set(
                             jnp.arange(P, dtype=jnp.int32), mode="drop")
-                    hist_large = build_hist_multi(
-                        Xb, g, h,
-                        jnp.where(bag_mask,
-                                  largesel[jnp.minimum(row_slot, L)], P),
-                        P, B,
-                        rows_per_chunk=p.rows_per_chunk, axis_name=axis_name,
-                        precision=p.hist_precision, hist_reduce=hr_mode,
-                    )
-                ls = left_smaller[:, None, None, None]
-                hist_l = jnp.where(ls, hist_small, hist_large)
-                hist_r = jnp.where(ls, hist_large, hist_small)
-            hists = hists.at[jnp.where(do, sj, L)].set(hist_l, mode="drop")
-            hists = hists.at[jnp.where(do, right_slot, L)].set(hist_r, mode="drop")
+                    # bag gates the histogram selection; out-of-bag rows are
+                    # partitioned but never accumulated
+                    smallsel = jnp.where(bag_mask,
+                                         colof[jnp.minimum(row_slot, L)], P)
+                    # Single device, smaller children cover at most half the
+                    # rows (min(left,right) <= parent/2, parents disjoint) ->
+                    # half the tile grid.  Under shard_map the smaller child is
+                    # chosen on GLOBAL counts and one shard's share of it may
+                    # exceed half that shard, so no bound applies there; ditto
+                    # above 2^24 rows, where the fp32 histogram counts backing
+                    # the smaller-child choice stop being exact.
+                    bound_ok = half_bound_ok
+                    if use_nat:
+                        from dryad_tpu.engine import pallas_hist
+
+                        hist_small = pallas_hist.build_hist_small(
+                            nat_tiles, g, h, smallsel, P, B, F,
+                            axis_name=axis_name, platform=platform,
+                            hist_reduce=hr_mode)
+                    else:
+                        # exact per-column counts (smaller-child C off the
+                        # parent histogram, integer-exact in f32 below 2**24)
+                        # admit the pad-injected aligned sort inside
+                        # build_hist_segmented — the plan's alignment gather
+                        # drops out; single-device only, where the counts
+                        # describe the whole selection
+                        small_cnt = (jnp.where(do,
+                                               jnp.where(left_smaller, CL, CR),
+                                               0.0).astype(jnp.int32)
+                                     if bound_ok else None)
+                        hist_small = build_hist_segmented(
+                            Xb, g, h, smallsel, P, B,
+                            rows_per_chunk=p.rows_per_chunk, axis_name=axis_name,
+                            precision=p.hist_precision, backend=p.hist_backend,
+                            rows_bound=(N // 2 + 1) if bound_ok else None,
+                            platform=platform, records=records,
+                            sel_counts=small_cnt,
+                            # staged prefixes only pay when the leaf budget caps
+                            # deep levels (fills provably collapse); a full tree
+                            # keeps every prefix ~100% and the extra gather
+                            # branches only bloat (remote) compile
+                            stage_gather=(L - 1) < (1 << (depth_cap - 1)),
+                            hist_reduce=hr_mode,
+                        )
+                    if p.hist_subtraction:
+                        hist_large = hists[sj] - hist_small
+                    else:
+                        largesel = jnp.full((L + 1,), P, jnp.int32).at[
+                            jnp.where(do, large_slot, L + 1)].set(
+                                jnp.arange(P, dtype=jnp.int32), mode="drop")
+                        hist_large = build_hist_multi(
+                            Xb, g, h,
+                            jnp.where(bag_mask,
+                                      largesel[jnp.minimum(row_slot, L)], P),
+                            P, B,
+                            rows_per_chunk=p.rows_per_chunk, axis_name=axis_name,
+                            precision=p.hist_precision, hist_reduce=hr_mode,
+                        )
+                    ls = left_smaller[:, None, None, None]
+                    hist_l = jnp.where(ls, hist_small, hist_large)
+                    hist_r = jnp.where(ls, hist_large, hist_small)
+            with jax.named_scope("dryad.hist"):
+                hists = hists.at[jnp.where(do, sj, L)].set(hist_l, mode="drop")
+                hists = hists.at[jnp.where(do, right_slot, L)].set(hist_r, mode="drop")
 
             # ---- children stats + their best splits (vmapped finder) ------------
-            lo_p, hi_p = slot_lo[sj], slot_hi[sj]
-            if mono is not None:
-                lo_l, hi_l, lo_r, hi_r = child_bounds(
-                    mono, sf, GL, HL, GR, HR, jnp.float32(p.lambda_l2), lo_p, hi_p)
-            else:
-                lo_l = lo_r = lo_p
-                hi_l = hi_r = hi_p
+            with jax.named_scope("dryad.split_scan"):
+                lo_p, hi_p = slot_lo[sj], slot_hi[sj]
+                if mono is not None:
+                    lo_l, hi_l, lo_r, hi_r = child_bounds(
+                        mono, sf, GL, HL, GR, HR, jnp.float32(p.lambda_l2), lo_p, hi_p)
+                else:
+                    lo_l = lo_r = lo_p
+                    hi_l = hi_r = hi_p
 
-            ch_slot = jnp.concatenate([sj, right_slot])
-            ch_do = jnp.concatenate([do, do])
-            ch_node = jnp.concatenate([left_id, right_id])
-            ch_hist = jnp.concatenate([hist_l, hist_r])
-            ch_G = jnp.concatenate([GL, GR])
-            ch_H = jnp.concatenate([HL, HR])
-            ch_C = jnp.concatenate([CL, CR])
-            ch_lo = jnp.concatenate([lo_l, lo_r])
-            ch_hi = jnp.concatenate([hi_l, hi_r])
-            allow = ch_do & (d + 1 < depth_cap) & (ch_C >= 2 * p.min_data_in_leaf)
-            res = level_scan(ch_hist, ch_G, ch_H, ch_C, allow, ch_lo, ch_hi)
+                ch_slot = jnp.concatenate([sj, right_slot])
+                ch_do = jnp.concatenate([do, do])
+                ch_node = jnp.concatenate([left_id, right_id])
+                ch_hist = jnp.concatenate([hist_l, hist_r])
+                ch_G = jnp.concatenate([GL, GR])
+                ch_H = jnp.concatenate([HL, HR])
+                ch_C = jnp.concatenate([CL, CR])
+                ch_lo = jnp.concatenate([lo_l, lo_r])
+                ch_hi = jnp.concatenate([hi_l, hi_r])
+                allow = ch_do & (d + 1 < depth_cap) & (ch_C >= 2 * p.min_data_in_leaf)
+                res = level_scan(ch_hist, ch_G, ch_H, ch_C, allow, ch_lo, ch_hi)
 
-            cidx = jnp.where(ch_do, ch_slot, L)
-            slot_node = slot_node.at[cidx].set(ch_node, mode="drop")
-            slot_gain = slot_gain.at[cidx].set(res.gain, mode="drop")
-            slot_G = slot_G.at[cidx].set(ch_G, mode="drop")
-            slot_H = slot_H.at[cidx].set(ch_H, mode="drop")
-            slot_C = slot_C.at[cidx].set(ch_C, mode="drop")
-            slot_depth = slot_depth.at[cidx].set(d + 1, mode="drop")
-            slot_lo = slot_lo.at[cidx].set(ch_lo, mode="drop")
-            slot_hi = slot_hi.at[cidx].set(ch_hi, mode="drop")
-            sp_feature = sp_feature.at[cidx].set(res.feature, mode="drop")
-            sp_thresh = sp_thresh.at[cidx].set(res.threshold, mode="drop")
-            sp_GL = sp_GL.at[cidx].set(res.g_left, mode="drop")
-            sp_HL = sp_HL.at[cidx].set(res.h_left, mode="drop")
-            sp_CL = sp_CL.at[cidx].set(res.c_left, mode="drop")
-            sp_catmask = sp_catmask.at[cidx].set(res.cat_mask, mode="drop")
-            sp_dleft = sp_dleft.at[cidx].set(res.default_left, mode="drop")
+                cidx = jnp.where(ch_do, ch_slot, L)
+                slot_node = slot_node.at[cidx].set(ch_node, mode="drop")
+                slot_gain = slot_gain.at[cidx].set(res.gain, mode="drop")
+                slot_G = slot_G.at[cidx].set(ch_G, mode="drop")
+                slot_H = slot_H.at[cidx].set(ch_H, mode="drop")
+                slot_C = slot_C.at[cidx].set(ch_C, mode="drop")
+                slot_depth = slot_depth.at[cidx].set(d + 1, mode="drop")
+                slot_lo = slot_lo.at[cidx].set(ch_lo, mode="drop")
+                slot_hi = slot_hi.at[cidx].set(ch_hi, mode="drop")
+                sp_feature = sp_feature.at[cidx].set(res.feature, mode="drop")
+                sp_thresh = sp_thresh.at[cidx].set(res.threshold, mode="drop")
+                sp_GL = sp_GL.at[cidx].set(res.g_left, mode="drop")
+                sp_HL = sp_HL.at[cidx].set(res.h_left, mode="drop")
+                sp_CL = sp_CL.at[cidx].set(res.c_left, mode="drop")
+                sp_catmask = sp_catmask.at[cidx].set(res.cat_mask, mode="drop")
+                sp_dleft = sp_dleft.at[cidx].set(res.default_left, mode="drop")
 
-            splits_done = splits_done + n_do
-            num_nodes = num_nodes + 2 * n_do
-            max_depth = jnp.where(n_do > 0, (d + 1).astype(jnp.int32), max_depth)
+                splits_done = splits_done + n_do
+                num_nodes = num_nodes + 2 * n_do
+                max_depth = jnp.where(n_do > 0, (d + 1).astype(jnp.int32), max_depth)
 
             out = {
                 "row_slot": row_slot, "slot_node": slot_node,
@@ -860,6 +872,11 @@ def grow_tree_levelwise(
     )
     cat_bitset = pack_cat_bitset(st["cat_nodes"], M)
 
+    # per-row leaf node id from the partition state (no re-traversal); only
+    # the train step's score update reads it
+    with jax.named_scope("dryad.score"):
+        row_leaf = jnp.maximum(st["slot_node"], 0)[
+            jnp.minimum(st["row_slot"], L - 1)]
     return {
         "feature": st["feature"],
         "threshold": st["threshold"],
@@ -872,7 +889,5 @@ def grow_tree_levelwise(
         "default_left": st["node_dleft"],
         "cover": st["cover"],
         "max_depth": st["max_depth"],
-        # per-row leaf node id from the partition state (no re-traversal)
-        "row_leaf": jnp.maximum(st["slot_node"], 0)[
-            jnp.minimum(st["row_slot"], L - 1)],
+        "row_leaf": row_leaf,
     }

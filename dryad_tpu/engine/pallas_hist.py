@@ -248,6 +248,7 @@ def _hist_tiles(Xt, Wt, tile_leaf, tile_first, tile_skip, *, num_cols: int,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=_interpret(platform),
+        name="_hist_tiles",
     )(tile_leaf, tile_first, tile_skip, Xt, Wt)
 
     # kernel columns are (bin-major, feature-minor) per chunk — untangle
@@ -461,6 +462,7 @@ def tile_plan_aligned(sel: jnp.ndarray, counts: jnp.ndarray, N: int, P: int,
     return buf, tile_leaf, tile_first
 
 
+@jax.named_scope("dryad.hist")
 def make_records(Xb: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray) -> jnp.ndarray:
     """Per-TREE (N, 2 + ceil(F*bytes/4)) int32 record table [g, h, X words].
 
@@ -681,6 +683,7 @@ def maybe_natural_tiles(Xb: jnp.ndarray, total_bins: int,
     return natural_tiles(Xb, total_bins)
 
 
+@jax.named_scope("dryad.hist")
 def build_hist_small(nat_tiles, g, h, sel, num_cols: int, total_bins: int,
                      num_features: int, *, axis_name: str | None = None,
                      platform: str | None = None,
@@ -704,6 +707,7 @@ def build_hist_small(nat_tiles, g, h, sel, num_cols: int, total_bins: int,
                           hist_reduce=hist_reduce)
 
 
+@jax.named_scope("dryad.hist")
 def natural_tiles(Xb: jnp.ndarray, total_bins: int) -> jnp.ndarray:
     """Feature-chunked tiles of the WHOLE matrix in natural row order — a
     pure function of (Xb, bins), so the level-synchronous growers build it
@@ -801,6 +805,7 @@ def build_hist_nat(Xt_nat, g, h, sel, *, total_bins: int, num_features: int,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=_interpret(platform),
+        name="build_hist_nat",
     )(Xt_nat, W)
     out = (out.reshape(n_fb, _NAT_SLOTS, 8, Bp, Fc)
               .transpose(1, 2, 0, 4, 3)

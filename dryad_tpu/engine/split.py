@@ -31,6 +31,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 NEG_INF = float("-inf")  # plain float: a jnp scalar here would init the backend at import
@@ -48,6 +49,7 @@ class SplitResult(NamedTuple):
     default_left: jnp.ndarray  # bool — missing (bin 0) goes left at this split
 
 
+@jax.named_scope("dryad.split_scan")
 def find_best_split(
     hist: jnp.ndarray,          # (3, F, B) f32
     G: jnp.ndarray,
@@ -204,6 +206,7 @@ class LocalSplit(NamedTuple):
     cat_mask: jnp.ndarray     # (B,) raw left membership (pre-ok)
 
 
+@jax.named_scope("dryad.split_scan")
 def find_best_split_sliced(
     hist: jnp.ndarray,          # (3, Fs, B) f32 — the OWNED slice, reduced
     G: jnp.ndarray,

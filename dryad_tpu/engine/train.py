@@ -50,10 +50,11 @@ from dryad_tpu.objectives import get_objective
 
 # per-stage span series (dryad_tpu/obs): host wall around work this loop
 # already does — dispatch cost on the async sites, real fetch wall on the
-# fetch sites.  Never a new device fetch; zero-cost when disabled.
+# fetch sites.  Never a new device fetch; zero-cost when disabled.  Each
+# span is also an annotation on the jax profiler's clock (engine/__init__),
+# so a profile lays them beside the dryad.* stages of the device programs.
 from dryad_tpu.obs.registry import default_registry
-from dryad_tpu.obs.spans import record as record_span
-from dryad_tpu.obs.spans import span
+from dryad_tpu.obs.spans import annotation, record_at, span
 from dryad_tpu.obs.tripwire import default_tripwire
 
 # fetch-stall watchdog (r12): every REAL device->host fetch below is
@@ -113,8 +114,9 @@ def _step_body(p, B, has_cat, mesh, platform, learn_missing, out, score, Xb,
     the same ensemble the gradients saw.
     """
     out = dict(out)
-    g = jnp.take(g_all, k, axis=1)
-    h = jnp.take(h_all, k, axis=1)
+    with jax.named_scope("dryad.grad"):
+        g = jnp.take(g_all, k, axis=1)
+        h = jnp.take(h_all, k, axis=1)
     if mesh is not None:
         from dryad_tpu.engine.distributed import grow_sharded
 
@@ -134,20 +136,21 @@ def _step_body(p, B, has_cat, mesh, platform, learn_missing, out, score, Xb,
         # each row's leaf comes straight out of the grower's partition
         # state — re-traversing 10M rows cost ~5 s/tree (gather-bound)
         leaves = tree.pop("row_leaf")
-    if renew_alpha is not None:
-        tree = dict(tree, value=_renew_values(
-            tree["value"], tree["feature"], leaves, y,
-            jnp.take(score, k, axis=1), bag, renew_alpha,
-            p.effective_learning_rate, p.max_nodes))
-    if value_scale is not None:
-        # DART: the new tree lands pre-scaled by 1/(k+1) — same f32
-        # multiply order as the CPU mirror (finalize with lr, then scale)
-        tree = dict(tree, value=tree["value"] * value_scale)
-    col = jnp.take(score, k, axis=1) + tree["value"][leaves]
-    score = jax.lax.dynamic_update_index_in_dim(score, col, k, axis=1)
-    for key in _TREE_KEYS:
-        out[key] = out[key].at[t].set(tree[key])
-    out["max_depth"] = out["max_depth"].at[t].set(tree["max_depth"])
+    with jax.named_scope("dryad.score"):
+        if renew_alpha is not None:
+            tree = dict(tree, value=_renew_values(
+                tree["value"], tree["feature"], leaves, y,
+                jnp.take(score, k, axis=1), bag, renew_alpha,
+                p.effective_learning_rate, p.max_nodes))
+        if value_scale is not None:
+            # DART: the new tree lands pre-scaled by 1/(k+1) — same f32
+            # multiply order as the CPU mirror (finalize with lr, then scale)
+            tree = dict(tree, value=tree["value"] * value_scale)
+        col = jnp.take(score, k, axis=1) + tree["value"][leaves]
+        score = jax.lax.dynamic_update_index_in_dim(score, col, k, axis=1)
+        for key in _TREE_KEYS:
+            out[key] = out[key].at[t].set(tree[key])
+        out["max_depth"] = out["max_depth"].at[t].set(tree["max_depth"])
     return out, score
 
 
@@ -162,6 +165,7 @@ _step_jit = partial(jax.jit,
 # grower's working set.
 
 
+@jax.named_scope("dryad.grad")
 def _grads_body(p, N, K, pad, score, y, weight, qoff, rank_row_ids,
                 rank_col_ids, rank_Q, rank_S):
     """Per-iteration grad/hess (N+pad, K) from the pre-iteration score.
@@ -189,9 +193,9 @@ def _grads_body(p, N, K, pad, score, y, weight, qoff, rank_row_ids,
     return g[:, None], h[:, None]
 
 
-_grads_jit = partial(jax.jit,
-                     static_argnames=("p", "N", "K", "pad", "rank_Q",
-                                      "rank_S"))(_grads_body)
+_grads_jit = introspect.whole_program("dryad.grad", partial(
+    jax.jit, static_argnames=("p", "N", "K", "pad", "rank_Q",
+                              "rank_S"))(_grads_body))
 
 
 def _grow_iteration(p, B, has_cat, mesh, platform, learn_missing, out, score,
@@ -205,7 +209,8 @@ def _grow_iteration(p, B, has_cat, mesh, platform, learn_missing, out, score,
     if p.boosting == "goss":
         # device-drawn uniforms (bit-identical to the host generator)
         # make GOSS chunkable: no per-iteration upload, same selection
-        u = _goss_uniform_dev(p.seed, it, score.shape[0])
+        with jax.named_scope("dryad.grad"):
+            u = _goss_uniform_dev(p.seed, it, score.shape[0])
         g_all, h_all, bag_i = _goss_body(p, n_rows, g_all, h_all, u, bag_i)
     roots = None
     if K > 1 and _shared_roots_ok(p, platform):
@@ -275,19 +280,22 @@ def _chunk_jit(p, B, has_cat, mesh, platform, learn_missing, N, K, pad,
 
     def body(i, carry):
         out, score, vscores, eval_buf, eval_its, eval_cnt = carry
-        if bag_bits is not None:
-            u8 = bag_bits[i]                       # (ceil(NP/8),) uint8
-            bits = ((u8[:, None] >> jnp.arange(8, dtype=jnp.uint8)) & 1)
-            bag_i = bits.reshape(-1)[:score.shape[0]].astype(bool) & bag
-        else:
-            bag_i = bag
-        fmask_i = fmask if fmask_chunk is None else fmask_chunk[i]
-        # rf: grads at the CONSTANT init score (loop-invariant — XLA hoists
-        # the computation out of the fori body); broadcast inside the trace
-        # so no (NP, K) constant is baked into the program
-        score_g = (jnp.broadcast_to(init_arr.astype(jnp.float32),
-                                    score.shape)
-                   if p.boosting == "rf" else score)
+        # the iteration's row sample rides the gradient stage's scope, as
+        # GOSS's selection does
+        with jax.named_scope("dryad.grad"):
+            if bag_bits is not None:
+                u8 = bag_bits[i]                   # (ceil(NP/8),) uint8
+                bits = ((u8[:, None] >> jnp.arange(8, dtype=jnp.uint8)) & 1)
+                bag_i = bits.reshape(-1)[:score.shape[0]].astype(bool) & bag
+            else:
+                bag_i = bag
+            fmask_i = fmask if fmask_chunk is None else fmask_chunk[i]
+            # rf: grads at the CONSTANT init score (loop-invariant — XLA
+            # hoists the computation out of the fori body); broadcast inside
+            # the trace so no (NP, K) constant is baked into the program
+            score_g = (jnp.broadcast_to(init_arr.astype(jnp.float32),
+                                        score.shape)
+                       if p.boosting == "rf" else score)
         g_all, h_all = _grads_body(p, N, K, pad, score_g, y, weight, qoff,
                                    rank_row, rank_col, rank_Q, rank_S)
         out, score = _grow_iteration(
@@ -296,45 +304,46 @@ def _chunk_jit(p, B, has_cat, mesh, platform, learn_missing, N, K, pad,
             bmask=bmask, n_rows=N, renew_alpha=renew_alpha)
 
         if n_valid:
-            new_vs = []
-            for vi in range(n_valid):
-                vs = vscores[vi]
-                for k in range(K):
-                    t = (it0 + i) * K + k
-                    tree = {key: out[key][t] for key in _TREE_KEYS}
-                    lv = tree_leaves(tree, vXbs[vi], out["max_depth"][t])
-                    vs = vs.at[:, k].set(vs[:, k] + tree["value"][lv])
-                new_vs.append(vs)
-            vscores = tuple(new_vs)
+            with jax.named_scope("dryad.eval"):
+                new_vs = []
+                for vi in range(n_valid):
+                    vs = vscores[vi]
+                    for k in range(K):
+                        t = (it0 + i) * K + k
+                        tree = {key: out[key][t] for key in _TREE_KEYS}
+                        lv = tree_leaves(tree, vXbs[vi], out["max_depth"][t])
+                        vs = vs.at[:, k].set(vs[:, k] + tree["value"][lv])
+                    new_vs.append(vs)
+                vscores = tuple(new_vs)
 
-            from dryad_tpu.metrics.device import eval_value
+                from dryad_tpu.metrics.device import eval_value
 
-            it_now = it0 + i
-            do_eval = (((it_now + 1) % eval_period == 0)
-                       | (it_now + 1 == total_iters))
+                it_now = it0 + i
+                do_eval = (((it_now + 1) % eval_period == 0)
+                           | (it_now + 1 == total_iters))
 
-            def write(args):
-                buf, its, cnt = args
-                if p.boosting == "rf":
-                    # score the AVERAGED model — same fp32 transform as
-                    # predict (_rf_avg_jit / cpu mirror); the reciprocal is
-                    # an exact IEEE division (identical to the host's), the
-                    # iteration count is traced so it can't be host-side
-                    initf = init_arr.astype(jnp.float32)
-                    inv_it = jnp.float32(1.0) / (it_now + 1).astype(jnp.float32)
-                    vs_eval = [initf + (vscores[vi] - initf) * inv_it
-                               for vi in range(n_valid)]
-                else:
-                    vs_eval = list(vscores)
-                vals = jnp.stack([
-                    eval_value(metric_names[vi], ndcg_at, vys[vi],
-                               vs_eval[vi], vqids[vi])
-                    for vi in range(n_valid)])
-                return (buf.at[cnt].set(vals), its.at[cnt].set(it_now),
-                        cnt + 1)
+                def write(args):
+                    buf, its, cnt = args
+                    if p.boosting == "rf":
+                        # score the AVERAGED model — same fp32 transform as
+                        # predict (_rf_avg_jit / cpu mirror); the reciprocal is
+                        # an exact IEEE division (identical to the host's), the
+                        # iteration count is traced so it can't be host-side
+                        initf = init_arr.astype(jnp.float32)
+                        inv_it = jnp.float32(1.0) / (it_now + 1).astype(jnp.float32)
+                        vs_eval = [initf + (vscores[vi] - initf) * inv_it
+                                   for vi in range(n_valid)]
+                    else:
+                        vs_eval = list(vscores)
+                    vals = jnp.stack([
+                        eval_value(metric_names[vi], ndcg_at, vys[vi],
+                                   vs_eval[vi], vqids[vi])
+                        for vi in range(n_valid)])
+                    return (buf.at[cnt].set(vals), its.at[cnt].set(it_now),
+                            cnt + 1)
 
-            eval_buf, eval_its, eval_cnt = jax.lax.cond(
-                do_eval, write, lambda a: a, (eval_buf, eval_its, eval_cnt))
+                eval_buf, eval_its, eval_cnt = jax.lax.cond(
+                    do_eval, write, lambda a: a, (eval_buf, eval_its, eval_cnt))
         return out, score, vscores, eval_buf, eval_its, eval_cnt
 
     return jax.lax.fori_loop(
@@ -535,6 +544,7 @@ def _shared_roots_ok(p, platform) -> bool:
     return resolve_backend(p.hist_backend, platform=platform) != "pallas"
 
 
+@partial(introspect.whole_program, "dryad.hist")
 @partial(jax.jit, static_argnames=("B", "rpc", "precision", "mesh"))
 def _roots_jit(B, rpc, precision, mesh, Xb, g_all, h_all, bag):
     """Shared-plan multiclass root histograms (per-iteration dispatch path);
@@ -575,6 +585,7 @@ def _goss_uniform_dev(seed: int, iteration, num_rows: int) -> jnp.ndarray:
         1.0 / (1 << 24))
 
 
+@jax.named_scope("dryad.grad")
 def _goss_body(p, N, g_all, h_all, u, valid):
     """Device GOSS (mirrors cpu/trainer.py::goss_select_np — both run the
     selection in f32 so boundary rows classify identically): amplified
@@ -596,7 +607,8 @@ def _goss_body(p, N, g_all, h_all, u, valid):
     return g_all * w, h_all * w, is_top | picked
 
 
-_goss_jit = partial(jax.jit, static_argnames=("p", "N"))(_goss_body)
+_goss_jit = introspect.whole_program("dryad.grad", partial(
+    jax.jit, static_argnames=("p", "N"))(_goss_body))
 
 
 _dart_replay_jit = partial(jax.jit, static_argnames=("depth_bound",))(
@@ -604,7 +616,9 @@ _dart_replay_jit = partial(jax.jit, static_argnames=("depth_bound",))(
         trees, Xb, init, depth_bound))
 
 
+@partial(introspect.whole_program, "dryad.eval")
 @jax.jit
+@jax.named_scope("dryad.eval")
 def _rf_avg_jit(vs, init, inv):
     """rf eval transform: averaged raw score init + (Σ - init)*(1/n) with
     the HOST-computed reciprocal — the same arithmetic as both predict
@@ -644,7 +658,9 @@ def _dart_drop_jit(out, score, tids, tcls, Xb, factor_drop, depth_bound):
     return score - dcontrib, newval
 
 
+@partial(introspect.whole_program, "dryad.eval")
 @jax.jit
+@jax.named_scope("dryad.eval")
 def _apply_valid_jit(out, t, vXb, vs_col, depth_bound):
     tree = {key: out[key][t] for key in _TREE_KEYS}
     leaves = tree_leaves(tree, vXb, depth_bound)
@@ -669,9 +685,14 @@ def _empty_out_device(T: int, M: int, cat_words: int) -> dict:
 
 def _materialize(p, mapper, out, T, init, max_depth_prev, best_iteration,
                  best_value=None, stale=0) -> Booster:
-    """Fetch the device tree tables (the one forced sync) into a Booster."""
-    host = {key: np.asarray(out[key][:T]) for key in _TREE_KEYS}
-    depths = np.asarray(out["max_depth"][:T])
+    """Fetch the device tree tables (the one forced sync) into a Booster.
+
+    ``T`` grows with every checkpoint, so each one slices to new shapes and
+    compiles a handful of tiny programs; they count under the family
+    ``train.materialize``, not under whatever boundary came last."""
+    with introspect.attributed("train.materialize"):
+        host = {key: np.asarray(out[key][:T]) for key in _TREE_KEYS}
+        depths = np.asarray(out["max_depth"][:T])
     max_depth_seen = max(int(depths.max(initial=0)), max_depth_prev)
     return Booster(
         p, mapper,
@@ -1229,74 +1250,72 @@ def train_device(
                 chunk_policy.note_dispatch(n)
             if chunk_hook is not None:
                 chunk_hook("dispatch", it)
-            # None (not 0.0) when disabled: an enable() landing mid-chunk
-            # must not record a since-process-boot wall into the counters
-            _t_ch = _time.perf_counter() if _obs.enabled else None
+            # async site: this is host dispatch wall (masks + enqueue), not
+            # device execution — the fetch spans carry that.  A real
+            # interval with its true start, so a profile lays it on the gap
+            # before the chunk's program
+            _obs_on = _obs.enabled
+            with span("train.chunk_dispatch"):
+                bag_bits = fmask_chunk = None
+                if bagging:
+                    bb = (np.zeros((CH0, nbytes), np.uint8) if row_sampled
+                          else None)
+                    fm = (np.ones((CH0, F), bool) if col_sampled else None)
+                    for j in range(n):
+                        rm, fmk = sample_masks(p, it + j, N, F)
+                        if bb is not None:
+                            row = np.ones(N, bool) if rm is None else rm
+                            bb[j] = np.packbits(np.pad(row, (0, pad)),
+                                                bitorder="little")
+                        if fm is not None and fmk is not None:
+                            fm[j] = fmk
+                    if mesh is not None:
+                        # replicate the packed masks over the mesh explicitly: a
+                        # plain asarray commits to one device and the chunk jit
+                        # would reject mixed placements.  The devices unpack the
+                        # replicated bytes and slice their own row range — bit
+                        # packs need no shard alignment (VERDICT r3 #6).
+                        from jax.sharding import NamedSharding
+                        from jax.sharding import PartitionSpec as PS
 
-            bag_bits = fmask_chunk = None
-            if bagging:
-                bb = (np.zeros((CH0, nbytes), np.uint8) if row_sampled
-                      else None)
-                fm = (np.ones((CH0, F), bool) if col_sampled else None)
-                for j in range(n):
-                    rm, fmk = sample_masks(p, it + j, N, F)
-                    if bb is not None:
-                        row = np.ones(N, bool) if rm is None else rm
-                        bb[j] = np.packbits(np.pad(row, (0, pad)),
-                                            bitorder="little")
-                    if fm is not None and fmk is not None:
-                        fm[j] = fmk
-                if mesh is not None:
-                    # replicate the packed masks over the mesh explicitly: a
-                    # plain asarray commits to one device and the chunk jit
-                    # would reject mixed placements.  The devices unpack the
-                    # replicated bytes and slice their own row range — bit
-                    # packs need no shard alignment (VERDICT r3 #6).
-                    from jax.sharding import NamedSharding
-                    from jax.sharding import PartitionSpec as PS
+                        rep = NamedSharding(mesh, PS())
+                        bag_bits = (jax.device_put(bb, rep)
+                                    if bb is not None else None)
+                        fmask_chunk = (jax.device_put(fm, rep)
+                                       if fm is not None else None)
+                    else:
+                        bag_bits = jnp.asarray(bb) if bb is not None else None
+                        fmask_chunk = jnp.asarray(fm) if fm is not None else None
 
-                    rep = NamedSharding(mesh, PS())
-                    bag_bits = (jax.device_put(bb, rep)
-                                if bb is not None else None)
-                    fmask_chunk = (jax.device_put(fm, rep)
-                                   if fm is not None else None)
-                else:
-                    bag_bits = jnp.asarray(bb) if bb is not None else None
-                    fmask_chunk = jnp.asarray(fm) if fm is not None else None
-
-            _chunk_args = (
-                p_key, B, has_cat, mesh, plat, learn_missing, N, K, pad,
-                rank_Q, rank_S, out, score, Xb, y, weight, ones_rows,
-                ones_feat, is_cat_feat, qoff_j, rank_row, rank_col,
-                jnp.int32(it), jnp.int32(n), bmask, bag_bits, fmask_chunk,
-                metric_names, p.ndcg_at, p.eval_period, total_iters,
-                vXbs_t, vys_t, vqids_t, vscores_t, eval_buf, eval_its,
-                eval_cnt)
-            if _obs.enabled:
-                # compile-boundary introspection: the first chunk of a new
-                # program key lowers (NO compile) for dryad_prog_* cost
-                # series and notes the key on the tripwire; warm chunks
-                # cost one memo lookup.  The key is the chunk jit's static
-                # signature, so a changed program mid-run is caught here.
-                introspect.capture(
-                    "train.chunk",
-                    ("chunk", p_key, B, has_cat, plat, N, K, pad,
-                     metric_names, p.eval_period, total_iters, renew_a),
-                    _chunk_jit, *_chunk_args, init_arr=init_dev,
-                    renew_alpha=renew_a,
-                    labels={"growth": p.growth, "shards": _shards_lbl})
-            (out, score, vscores_t, eval_buf, eval_its,
-             eval_cnt) = _chunk_jit(*_chunk_args, init_arr=init_dev,
-                                    renew_alpha=renew_a)
-            # expected-compile budget spent: arm every chunk (idempotent;
-            # a key-less family stays inert, so a mid-run enable() arms
-            # cleanly at the first ENABLED chunk instead of false-firing)
-            _tw.arm("train.chunk")
-            if _t_ch is not None:
-                # async site: this is host dispatch wall (masks + enqueue),
-                # not device execution — the fetch spans carry that
-                record_span("train.chunk_dispatch",
-                            _time.perf_counter() - _t_ch)
+                _chunk_args = (
+                    p_key, B, has_cat, mesh, plat, learn_missing, N, K, pad,
+                    rank_Q, rank_S, out, score, Xb, y, weight, ones_rows,
+                    ones_feat, is_cat_feat, qoff_j, rank_row, rank_col,
+                    jnp.int32(it), jnp.int32(n), bmask, bag_bits, fmask_chunk,
+                    metric_names, p.ndcg_at, p.eval_period, total_iters,
+                    vXbs_t, vys_t, vqids_t, vscores_t, eval_buf, eval_its,
+                    eval_cnt)
+                if _obs.enabled:
+                    # compile-boundary introspection: the first chunk of a new
+                    # program key lowers (NO compile) for dryad_prog_* cost
+                    # series and notes the key on the tripwire; warm chunks
+                    # cost one memo lookup.  The key is the chunk jit's static
+                    # signature, so a changed program mid-run is caught here.
+                    introspect.capture(
+                        "train.chunk",
+                        ("chunk", p_key, B, has_cat, plat, N, K, pad,
+                         metric_names, p.eval_period, total_iters, renew_a),
+                        _chunk_jit, *_chunk_args, init_arr=init_dev,
+                        renew_alpha=renew_a,
+                        labels={"growth": p.growth, "shards": _shards_lbl})
+                (out, score, vscores_t, eval_buf, eval_its,
+                 eval_cnt) = _chunk_jit(*_chunk_args, init_arr=init_dev,
+                                        renew_alpha=renew_a)
+                # expected-compile budget spent: arm every chunk (idempotent;
+                # a key-less family stays inert, so a mid-run enable() arms
+                # cleanly at the first ENABLED chunk instead of false-firing)
+                _tw.arm("train.chunk")
+            if _obs_on:
                 if _obs_chunks is None:
                     _obs_chunks = _obs.counter(
                         "dryad_train_chunks_total",
@@ -1371,29 +1390,34 @@ def train_device(
                             eval_buf[host_cnt - len(evs):host_cnt]))
                 _, higher0, _ = evaluators[0]
                 val_rows = dict(zip(evs, vals))
-                for j in range(it, it + n):
-                    info = {"iteration": j, "ch_max_effective": ch_eff}
-                    if comm is not None:
-                        info.update(comm)
-                    if j in val_rows:
-                        for vi, ((vname, _), (mname, higher, _)) in enumerate(
-                                zip(valids, evaluators)):
-                            info[f"{vname}_{mname}"] = float(val_rows[j][vi])
-                        best_iteration, best_value, stale = update_best(
-                            p, best_iteration, best_value, stale, j,
-                            float(val_rows[j][0]), higher0)
-                        if (p.early_stopping_rounds
-                                and stale >= p.early_stopping_rounds):
-                            stop = True
-                    if callback is not None:
-                        callback(j, info)
+                # user code runs here (a logger, the benchmark's clock): its
+                # wall is the job's, under a name of its own
+                with span("train.callbacks"):
+                    for j in range(it, it + n):
+                        info = {"iteration": j, "ch_max_effective": ch_eff}
+                        if comm is not None:
+                            info.update(comm)
+                        if j in val_rows:
+                            for vi, ((vname, _), (mname, higher, _)) in \
+                                    enumerate(zip(valids, evaluators)):
+                                info[f"{vname}_{mname}"] = float(
+                                    val_rows[j][vi])
+                            best_iteration, best_value, stale = update_best(
+                                p, best_iteration, best_value, stale, j,
+                                float(val_rows[j][0]), higher0)
+                            if (p.early_stopping_rounds
+                                    and stale >= p.early_stopping_rounds):
+                                stop = True
+                        if callback is not None:
+                            callback(j, info)
                 flushed_cnt = host_cnt  # consumed: keep deferred flush exact
             elif callback is not None:
-                for j in range(it, it + n):
-                    info = {"iteration": j, "ch_max_effective": ch_eff}
-                    if comm is not None:
-                        info.update(comm)
-                    callback(j, info)
+                with span("train.callbacks"):
+                    for j in range(it, it + n):
+                        info = {"iteration": j, "ch_max_effective": ch_eff}
+                        if comm is not None:
+                            info.update(comm)
+                        callback(j, info)
             it += n
             if checkpointer is not None and checkpointer.due(it):
                 # _materialize is a real bulk fetch — the site the recorded
@@ -1402,15 +1426,19 @@ def train_device(
                     if chunk_hook is not None:
                         chunk_hook("fetch", it)
                     with span("train.fetch.checkpoint"):
-                        if valids and not sync_eval:
-                            flush_chunk_evals(host_cnt)
-                        ckpt = _materialize(p, data.mapper, out, it * K,
-                                            init, max_depth_prev,
-                                            best_iteration, best_value,
-                                            stale)
+                        # two children: the fetch (with what it compiles)
+                        # and the write to disk
+                        with span("materialize"):
+                            if valids and not sync_eval:
+                                flush_chunk_evals(host_cnt)
+                            ckpt = _materialize(p, data.mapper, out, it * K,
+                                                init, max_depth_prev,
+                                                best_iteration, best_value,
+                                                stale)
                         if eval_history is not None:  # carried from resume
                             ckpt.train_state["eval_history"] = eval_history
-                        checkpointer.save(ckpt, it)
+                        with span("save"):
+                            checkpointer.save(ckpt, it)
             if chunk_policy is not None:
                 # "clean" = dispatched + all due host work done; the async
                 # run-ahead means device completion trails <= 2 chunks, so
@@ -1464,7 +1492,10 @@ def train_device(
             break
         if chunk_hook is not None:
             chunk_hook("dispatch", it)
+        # the iteration's host interval, opened and closed by hand (a
+        # ``with`` would put its name in front of every fetch span's path)
         _t_it = _time.perf_counter() if _obs.enabled else None
+        _ann_it = annotation("train.iteration")
         row_mask_np, feat_mask_np = sample_masks(p, it, N, F)
         if row_mask_np is None:
             bag = ones_rows
@@ -1608,22 +1639,30 @@ def train_device(
                             and stale >= p.early_stopping_rounds):
                         stop = True
         if callback is not None:
-            callback(it, info)
+            with span("train.callbacks"):
+                callback(it, info)
         if checkpointer is not None and checkpointer.due(it + 1):
             with watch_fetch("checkpoint", it + 1):
                 if chunk_hook is not None:
                     chunk_hook("fetch", it + 1)
                 with span("train.fetch.checkpoint"):
-                    flush_deferred()
-                    ckpt = _materialize(p, data.mapper, out, (it + 1) * K,
-                                        init, max_depth_prev,
-                                        best_iteration, best_value, stale)
+                    with span("materialize"):
+                        flush_deferred()
+                        ckpt = _materialize(p, data.mapper, out,
+                                            (it + 1) * K, init,
+                                            max_depth_prev, best_iteration,
+                                            best_value, stale)
                     if eval_history is not None:
                         ckpt.train_state["eval_history"] = eval_history
-                    checkpointer.save(ckpt, it + 1)
+                    with span("save"):
+                        checkpointer.save(ckpt, it + 1)
+        if _ann_it is not None:
+            _ann_it.close()
         if _t_it is not None:
-            # async dispatch: this is the iteration's HOST dispatch wall
-            record_span("train.iteration", _time.perf_counter() - _t_it)
+            # async dispatch: this is the iteration's HOST wall, fetches
+            # and callbacks included, not device execution
+            record_at("train.iteration", _t_it,
+                      _time.perf_counter() - _t_it)
             if _obs_iter is None:
                 _obs_iter = _obs.gauge(
                     "dryad_train_iteration",

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dryad_tpu.engine import introspect
 from dryad_tpu.metrics import DEFAULT_METRIC, HIGHER_BETTER, _METRIC_ALIASES
 
 _EPS = 1e-15
@@ -134,6 +135,7 @@ def ndcg_device(y, s, qids, k):
     return jnp.mean(ndcg)
 
 
+@jax.named_scope("dryad.eval")
 def eval_value(name, ndcg_at, y, raw_score, qids=None):
     """Raw (traceable) metric value — shared by the standalone ``_eval_jit``
     and the chunked trainer, which evaluates INSIDE its device program."""
@@ -163,7 +165,8 @@ def eval_value(name, ndcg_at, y, raw_score, qids=None):
     raise ValueError(f"unknown metric {name!r}")
 
 
-_eval_jit = partial(jax.jit, static_argnames=("name", "ndcg_at"))(eval_value)
+_eval_jit = introspect.whole_program("dryad.eval", partial(
+    jax.jit, static_argnames=("name", "ndcg_at"))(eval_value))
 
 
 def make_evaluator(objective: str, metric: str, valid_ds, ndcg_at: int = 10):

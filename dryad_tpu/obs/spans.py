@@ -12,10 +12,10 @@ per thread: a span opened inside another records under
 
 The timing here is HOST wall around work the caller already performs —
 wrapping an existing fetch measures that fetch; no span ever ADDS a
-device fetch or sync (the registry's host-side contract).  Under the
-device trainer's async dispatch a span around a dispatch site therefore
-measures dispatch cost, not device execution — same caveat as
-callbacks.JsonlLogger's ``dispatch_s``.
+device fetch or sync (the registry's host-side contract).  What the device
+did meanwhile is the profiler's to say: with an ANNOTATOR installed
+(``set_annotator``, below) every span is also an interval on the
+profiler's own clock, beside the device's operations.
 
 Zero-cost when disabled: ``span()`` returns one shared null context
 manager before touching the clock, and ``record()`` returns after the
@@ -30,6 +30,18 @@ span as ``(path, t0_s, dur_s)`` — ``obs/trace_export.py`` installs a ring
 buffer there and renders Chrome trace_event JSON from it.  The sink fires
 only on the registry-enabled path (the disabled fast path is untouched)
 and a sink exception never propagates into the instrumented caller.
+
+An optional ANNOTATOR (``set_annotator(factory)``) puts every span on a
+second clock: ``factory(path)`` must return a context manager, entered at
+the span's ``__enter__`` and left at its ``__exit__``.  This package stays
+jax-free; ``dryad_tpu.engine`` installs ``jax.profiler.TraceAnnotation``,
+so under ``dryad.train(profile_dir=...)`` each span shows by its path on
+the host plane of the jax profile, on the time axis of the device's
+operations (with no profiler session an annotation is one atomic load).
+``annotation(path)`` gives the same interval alone, for a loop body that
+is entered and left by hand and recorded with ``record_at``.  Like the
+sink, the annotator is consulted only on the registry-enabled path and a
+factory or context manager that raises never reaches the caller.
 """
 
 from __future__ import annotations
@@ -56,6 +68,51 @@ def set_trace_sink(sink) -> None:
     trace_export.SpanTrace.record is the intended one."""
     global _TRACE_SINK
     _TRACE_SINK = sink
+
+
+#: annotator: None, or a callable(path) -> context manager — see module doc
+_ANNOTATOR = None
+
+
+def set_annotator(factory) -> None:
+    """Install (or clear, with ``None``) the span annotator: every span
+    enters ``factory(path)`` when it starts and leaves it when it ends."""
+    global _ANNOTATOR
+    _ANNOTATOR = factory
+
+
+class _Annotation:
+    """One annotator interval that cannot raise into its caller."""
+
+    __slots__ = ("_cm",)
+
+    def __init__(self, factory, path: str):
+        try:
+            self._cm = factory(path)
+            self._cm.__enter__()
+        except Exception:   # noqa: BLE001 — tracing must never break
+            self._cm = None  # the instrumented caller
+
+    def close(self) -> None:
+        cm, self._cm = self._cm, None
+        if cm is not None:
+            try:
+                cm.__exit__(None, None, None)
+            except Exception:   # noqa: BLE001 — as above
+                pass
+
+
+def annotation(path: str, registry: Optional[Registry] = None):
+    """Open the annotator's interval for ``path`` by hand; ``close()`` the
+    result to end it.  ``None`` (nothing to close) when the registry is
+    disabled or no annotator is installed."""
+    factory = _ANNOTATOR
+    if factory is None:
+        return None
+    reg = registry if registry is not None else default_registry()
+    if not reg.enabled:
+        return None
+    return _Annotation(factory, path)
 
 
 def sink_active() -> bool:
@@ -87,13 +144,14 @@ def _emit(reg: Registry, path: str, seconds: float) -> None:
 
 
 class _Span:
-    __slots__ = ("_reg", "name", "path", "_t0")
+    __slots__ = ("_reg", "name", "path", "_t0", "_ann")
 
     def __init__(self, reg: Registry, name: str):
         self._reg = reg
         self.name = name
         self.path = name
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
         stack = getattr(_TLS, "stack", None)
@@ -102,11 +160,16 @@ class _Span:
         if stack:
             self.path = stack[-1].path + "/" + self.name
         stack.append(self)
+        factory = _ANNOTATOR
+        if factory is not None:
+            self._ann = _Annotation(factory, self.path)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.close()
         stack = _TLS.stack
         if stack and stack[-1] is self:
             stack.pop()
