@@ -54,8 +54,8 @@ import numpy as np
 def deep_level_probe(rows: int, P: int = 64, B: int = 256,
                      F: int = 28, K: int = 3, reps: int = 2) -> dict | None:
     """Per-arm wall of ONE deep level's data movement + smaller-children
-    histogram: the wired leaf-ordered-layout pipeline (level_moves ->
-    permute_records -> hist_from_layout) vs the legacy plan pipeline
+    histogram: the wired leaf-ordered-layout pipeline (move_level ->
+    hist_from_layout) vs the legacy plan pipeline
     (packed aligned sort -> record gather -> hist_from_plan).  Both arms
     exclude the natural-order partition the two paths share, so the
     numbers isolate exactly the stage the r6 wiring replaced.
@@ -90,43 +90,38 @@ def deep_level_probe(rows: int, P: int = 64, B: int = 256,
     n_buf = leafperm.wired_tiles_bound(-(-rows // T), P)
     # the histogrammed selection (all LEFT children below) must provably
     # cover < half the rows for the shared half-bound: thresholds stay
-    # strictly negative so P(g <= thr) < 0.5 with ~sqrt(N) margin
+    # under 0.45*B on uniform bins, so P(bin <= thr) < 0.5 with margin
     n_sel = leafperm.wired_sel_tiles_bound(-(-rows // T), n_buf, P,
                                            half=True)
     rec_lay, tile_run, run_slot = leafperm.initial_layout(
         rec_nat, jnp.asarray(slot_np), jnp.ones((P,), bool), P, n_buf)
 
     def wired_step(s, rec_lay, tile_run, run_slot):
-        g_l, _, valid, _ = leafperm.unpack_layout_records(
-            rec_lay, F, jnp.uint8)
         smod = s - jnp.floor(s / 8) * 8          # live: period-8 walk (a
         # period that fits inside K would repeat the same contrib multiset
         # at both liveness seeds — the harness would reject it as dead)
-        # the grower's full per-level route rides in the arm: the
-        # run->packed-word compose + ONE per-row small-table gather (the
-        # dominant wired-only bookkeeping cost) and advance_runs — the
+        # the grower's full per-level layout work rides in the arm: the
+        # run -> packed-record compose, move_level (per-tile parameters,
+        # counting pass, level_moves, move kernel) and advance_runs — the
         # probe must price the level the GROWER pays, not just the kernel.
-        # The run table is ROLLED by the carried scalar (whole units) and
-        # the gathered word steps the side threshold: a non-carried table
-        # would let XLA's while-loop LICM hoist the whole route gather out
-        # of the timed fori (the CLAUDE.md dead-input trap, r10)
+        # The per-run table is ROLLED by the carried scalar (whole units)
+        # and its threshold walks with it: a non-carried table would let
+        # XLA's while-loop LICM hoist the bookkeeping out of the timed
+        # fori (the CLAUDE.md dead-input trap, r10)
         si = s.astype(jnp.int32)
         rs_i = jnp.roll(run_slot, si)
-        w0 = ((jnp.uint32(1) << 31)
-              | jnp.arange(P, dtype=jnp.uint32))   # per-run packed words
-        tab = jnp.concatenate([w0, jnp.zeros((1,), jnp.uint32)])
-        rr = tab[jnp.minimum(rs_i, P)][
-            jnp.repeat(tile_run, T)]               # composed row gather
-        live_bit = (rr >> 31) != 0
-        # per-run threshold steps stay strictly negative (half bound)
-        thr = -0.45 + 0.025 * smod + 0.1 * (rr & 1).astype(jnp.float32)
-        side = jnp.where(valid & live_bit,
-                         (g_l > thr).astype(jnp.int32), 2)
-        pos, dstl, dstr, base_l, base_r, _ = leafperm.level_moves(
-            tile_run, side, P)
-        out = leafperm.permute_records(rec_lay, pos, dstl, dstr, n_buf)
-        run_do = (rr[:: leafperm._TILE_ROWS][:P] & 1) == 0  # ~half split
-        tr2, rs2 = leafperm.advance_runs(run_slot, run_do[:P],
+        odd = (rs_i & 1).astype(jnp.float32)
+        # thresholds on feature 0 (bins uniform in [0, B)) stay under
+        # 0.45*B: the left children provably cover < half the rows
+        # every run's rows split (the half bound needs it); advance_runs
+        # below keeps the right child of ~half the runs, as before
+        run_do = (rs_i & 1) == 0
+        run_rec = leafperm.pack_run_records(
+            jnp.ones((P,)), jnp.zeros((P,)),
+            B * (0.05 + 0.025 * smod + 0.1 * odd))
+        out, base_l, base_r = leafperm.move_level(
+            rec_lay, tile_run, run_rec, bin_dtype=jnp.uint8)
+        tr2, rs2 = leafperm.advance_runs(run_slot, run_do,
                                          jnp.arange(P, dtype=jnp.int32),
                                          base_l, base_r, n_buf)
         hist = leafperm.hist_from_layout(
@@ -223,7 +218,7 @@ def leafwise_level_probe(rows: int, D: int = 7, B: int = 256,
     # ---- wired arm: the expansion level at heap-id bookkeeping ------------
     rec_nat = leafperm.make_layout_records(Xb, g, h)
     n_buf = leafperm.wired_tiles_bound(-(-rows // T), NR)
-    # thresholds stay strictly negative so the histogrammed left children
+    # thresholds stay under 0.45*B so the histogrammed left children
     # provably cover < half the rows (shared half-bound rule)
     n_sel = leafperm.wired_sel_tiles_bound(-(-rows // T), n_buf, P,
                                            half=True)
@@ -236,32 +231,25 @@ def leafwise_level_probe(rows: int, D: int = 7, B: int = 256,
         jnp.full((NR - P,), HN, jnp.int32)]).astype(jnp.int32)
 
     def wired_step(s, rec_lay, tile_run, run_slot):
-        g_l, _, valid, _ = leafperm.unpack_layout_records(
-            rec_lay, F, jnp.uint8)
         smod = s - jnp.floor(s / 8) * 8        # live: period-8 walk (see
         # deep_level_probe — a period inside K repeats the contrib
         # multiset across the liveness seeds and reads as dead)
-        # the grower's per-level route: node -> packed word composed at the
-        # (HN+1,) level, then ONE per-row small-table gather + advance_runs.
-        # Table ROLLED by the carried scalar and the gathered word steps
-        # the side threshold — a non-carried table would let while-loop
-        # LICM hoist the route gather out of the timed fori (the CLAUDE.md
-        # dead-input trap, r10; same fix as deep_level_probe)
+        # the grower's per-level layout work: node -> packed record
+        # composed at the (NR,) level, move_level + advance_runs.  Table
+        # ROLLED by the carried scalar and its threshold walks with it — a
+        # non-carried table would let while-loop LICM hoist the
+        # bookkeeping out of the timed fori (the CLAUDE.md dead-input
+        # trap, r10; same fix as deep_level_probe)
         si = s.astype(jnp.int32)
         rs_i = jnp.roll(run_slot, si)
-        w0 = ((jnp.uint32(1) << 31)
-              | jnp.arange(HN + 1, dtype=jnp.uint32))
-        rr = w0[jnp.minimum(rs_i, HN)][
-            jnp.repeat(tile_run, T)]            # composed row gather
-        live_bit = (rr >> 31) != 0
-        # per-run threshold steps stay strictly negative (half bound)
-        thr = -0.45 + 0.025 * smod + 0.1 * (rr & 1).astype(jnp.float32)
-        side = jnp.where(valid & live_bit,
-                         (g_l > thr).astype(jnp.int32), 2)
-        pos, dstl, dstr, base_l, base_r, _ = leafperm.level_moves(
-            tile_run, side, NR)
-        out = leafperm.permute_records(rec_lay, pos, dstl, dstr, n_buf)
+        odd = (rs_i & 1).astype(jnp.float32)
+        # thresholds stay under 0.45*B (half bound, see deep_level_probe)
         run_do = ((rs_i & 1) == 0) & (rs_i < HN)           # ~half split
+        run_rec = leafperm.pack_run_records(
+            jnp.ones((NR,)), jnp.zeros((NR,)),
+            B * (0.05 + 0.025 * smod + 0.1 * odd))
+        out, base_l, base_r = leafperm.move_level(
+            rec_lay, tile_run, run_rec, bin_dtype=jnp.uint8)
         ns2 = jnp.where(run_do, 2 * rs_i, rs_i)
         tr2, rs2 = leafperm.advance_runs(ns2, run_do, 2 * rs_i + 1,
                                          base_l, base_r, n_buf,

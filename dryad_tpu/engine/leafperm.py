@@ -13,8 +13,26 @@ same sentinel algebra pallas_hist's plans use).
 Per level, every segment splits into (left, right) children (pass-through
 segments keep all rows "left").  A row's destination is a pure function
 of (source tile, side, stable rank within that (tile, side)), so the
-movement decomposes into per-tile work with NO sort and NO row scatter:
+movement decomposes into per-tile work with NO sort and NO row scatter —
+and, since every row of a tile belongs to one run and so to one split,
+with nothing row-sized left in XLA either (``move_level``, PR 26):
 
+* **per-tile parameters**: the growers' packed per-run split record
+  (feature, threshold bin, default-left, categorical flag, splits?) is
+  gathered once per TILE into one SMEM word (and, with categorical
+  features, the run's bitset row per tile);
+* **a counting pass** (kernel ``permute_records_count``) reads every record
+  tile once and returns its left/right row counts — destinations are
+  prefixes over EARLIER tiles, so the counts must exist before any row
+  is placed;
+* **``level_moves``** turns the counts into destination offsets and segment
+  bases with tile-sized prefix work (arrays of n_tiles and of P entries);
+* **the move kernel** (``permute_records``) derives, for the tile it holds
+  in VMEM, each row's side (a thin selector product brings the valid
+  flag and the split feature's bin out lane-oriented; then the routing
+  rules of the growers' natural-order partition, integer for integer)
+  and its stable in-tile rank (side rows times a strict upper-triangular
+  ones matrix), then does the
 * **stable two-way compaction on the MXU**: records are uint8 lanes
   (bytes are exact in bf16; the 0/1 one-hot times byte products
   accumulate exactly in f32), ``P_side (T, T) @ rec (T, WB)`` compacts
@@ -38,19 +56,21 @@ The histogram pass then reads the selected children's segments as
 CONTIGUOUS tile runs (tile-granular gathers move ~20 KB per access —
 bandwidth-bound, not access-bound), and no per-level sort exists at all.
 
-Self-contained and bitwise-tested in interpret mode
-(tests/test_leafperm.py); ``scripts/exp_r5_perm.py`` measures it
-on-device against the sort+gather pair it replaces (51.4 vs
-164.1 ms/level at 10M).  WIRED into ``levelwise.py``'s deep phase in r6
-and EVERYWHERE in r10: both level-synchronous growers
-(``levelwise.py`` — shallow AND deep levels — and the batched leaf-wise
-expansion in ``leafwise_fast.py``) carry (rec, tile_run, run_slot)
-through their level fori state.  The layout is now anchored at the ROOT
+Bitwise-tested in interpret mode against the numpy oracle
+(tests/test_leafperm.py: ``permute_records_np`` with sides from
+``layout_sides_np``); ``scripts/exp_r5_perm.py`` measures the level move
+on-device against the sort+gather pair it replaces.  WIRED into
+``levelwise.py``'s deep phase in r6 and EVERYWHERE in r10: both
+level-synchronous growers (``levelwise.py`` — shallow AND deep levels —
+and the batched leaf-wise expansion in ``leafwise_fast.py``) carry
+(rec, tile_run, run_slot) through their level fori state and call the one
+entry point ``move_level``.  The layout is anchored at the ROOT
 (``natural_root_layout``: the natural-order record buffer IS a valid
-one-segment layout, out-of-bag rows encoded as sentinels), so the old
-shallow->deep handoff sort+gather per tree (``initial_layout``) is gone
-from the growers too — it remains as the probe/oracle constructor for
-mid-tree layouts (bench, tests).  ``scripts/smoke_tpu.py --gate`` pins
+one-segment layout, out-of-bag rows encoded as flag-0 records that level
+0's move drops), so the old shallow->deep handoff sort+gather per tree
+(``initial_layout``) is gone from the growers too — it remains as the
+probe/oracle constructor for mid-tree layouts (bench, tests).
+``scripts/smoke_tpu.py --gate`` pins the fused move against the oracle and
 wired-vs-legacy tree equality on device for both growers.
 """
 
@@ -89,22 +109,132 @@ def aligned_layout(counts: jnp.ndarray, T: int = _TILE_ROWS):
     return lt, base
 
 
-def _perm_kernel(dstl_ref, dstr_ref, pos_ref, rec_ref, init_ref, out_ref,
-                 outl_vmem, outr_vmem, seml, semr, *, T: int, WB: int):
-    """One source tile: two stable compactions + two windowed writes.
+# Per-tile split word (int32) the layout kernels read from SMEM — the top
+# half of the growers' packed per-slot word plus the split feature:
+#   bits 0-12 threshold bin | 13 categorical | 14 default-left |
+#   15 the run splits this level | 16-22 feature
+# (a record holds at most (_REC_WB - 9) = 119 feature bytes: 7 bits)
+_PAR_THR_MASK = 0x1FFF
+_PAR_CAT_BIT, _PAR_DLEFT_BIT, _PAR_DO_BIT, _PAR_FEAT_SHIFT = 13, 14, 15, 16
+_CNT_LANES = 128     # tiles per block of the counting pass's output
+_CNT_STEP = 8        # tiles per grid step of the counting pass
+assert _CNT_LANES % _CNT_STEP == 0
+_RANK_LANES = 128    # lane block of the in-tile prefix sum
 
-    ``pos`` (1, 2, T) int32: row j's in-tile output rank on its side
-    (the other side's plane holds T = "no row"), so each one-hot
-    ``iota_o == pos[side]`` compacts one side to the front and zero-fills
-    the rest."""
-    i = pl.program_id(0)
+
+def _tile_bf16(tile_ref):
     # Mosaic has no direct u8->bf16 cast; route through i32/f32 (byte
     # values <= 255 are exact at every step)
-    rec = (rec_ref[0].astype(jnp.int32).astype(jnp.float32)
-           .astype(jnp.bfloat16))                      # (T, WB)
+    return (tile_ref[...].astype(jnp.int32).astype(jnp.float32)
+            .astype(jnp.bfloat16))                     # (T, WB)
+
+
+def _tile_sides(w, rec, cat_ref, *, T: int, WB: int, itemsize: int,
+                learn_missing: bool, n_cat_bins: int):
+    """One record tile's sides from its run's split word ``w`` (scalar).
+
+    rec (T, WB) bf16.  An (8, WB) one-hot selector contracted with the
+    tile on WB brings the valid flag (byte 8) and the split feature's bin
+    (byte ``9 + f*itemsize``, two bytes for u16 bins) out LANE-oriented as
+    (8, T): a byte is exact in bf16 and one product per sum is non-zero.
+    The routing is ``packed_route``'s integer arithmetic (the growers'
+    natural-order partition), so the two agree on every row.  Returns
+    (8, T) int32: row 0 = goes left, row 1 = goes right, rest zero;
+    flag-0 rows (sentinels, out-of-bag) are on neither side."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (8, WB), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, WB), 1)
+    b0 = 9 + ((w >> _PAR_FEAT_SHIFT) & 0x7F) * itemsize
+    tgt = jnp.where(row == 0, 8, jnp.where(row == 1, b0, -1))
+    if itemsize == 2:
+        tgt = jnp.where(row == 2, b0 + 1, tgt)
+    sel = (lane == tgt).astype(jnp.bfloat16)
+    vb = jax.lax.dot_general(
+        sel, rec, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(jnp.int32)   # (8, T)
+    valid = vb[0:1] == 1
+    bins = vb[1:2]
+    if itemsize == 2:
+        bins = bins + vb[2:3] * 256                    # little-endian u16
+    wv = jnp.full((1, T), w, jnp.int32)
+    gl = bins <= (wv & _PAR_THR_MASK)
+    if learn_missing:
+        gl &= (((wv >> _PAR_DLEFT_BIT) & 1) == 1) | (bins > 0)
+    if cat_ref is not None:
+        # the run's bitset row, looked up per row by a one-hot product
+        Bcp = cat_ref.shape[-1]
+        oh = (jax.lax.broadcasted_iota(jnp.int32, (Bcp, T), 0)
+              == jnp.minimum(bins, n_cat_bins - 1)).astype(jnp.bfloat16)
+        mask = jnp.broadcast_to(cat_ref[...], (8, Bcp)).astype(jnp.bfloat16)
+        cat_row = jax.lax.dot_general(
+            mask, oh, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)[0:1] > 0.5
+        # (a select between two i1 vectors does not lower in Mosaic)
+        is_cat = ((wv >> _PAR_CAT_BIT) & 1) == 1
+        gl = (is_cat & cat_row) | (~is_cat & gl)
+    # a run that does not split (pass-through, or a dead run's zero word)
+    # sends every valid row left
+    right = valid & (((wv >> _PAR_DO_BIT) & 1) == 1) & ~gl
+    left = valid & ~right
+    r8 = jax.lax.broadcasted_iota(jnp.int32, (8, T), 0)
+    return jnp.where(r8 == 0, left.astype(jnp.int32),
+                     jnp.where(r8 == 1, right.astype(jnp.int32), 0))
+
+
+def _count_kernel(par_ref, *refs, has_cat: bool, n_tiles: int, **kw):
+    """Counting pass: ``_CNT_STEP`` tiles a grid step (the pass is a thin
+    product per 64 KB tile — one tile a step would be all step overhead);
+    tile t's (left, right) row counts go to lane ``t % 128`` of a
+    resident (8, 128) output block (row 0 / row 1)."""
+    cat_ref, rec_ref, out_ref = refs if has_cat else (None,) + refs
+    t0 = pl.program_id(0) * _CNT_STEP
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, _CNT_LANES), 1)
+    out = out_ref[0]
+    for k in range(_CNT_STEP):
+        # a ragged last step re-reads the last tile's word over whatever
+        # the block holds past the buffer; its lanes are sliced off
+        w = par_ref[jnp.minimum(t0 + k, n_tiles - 1)]
+        S = _tile_sides(w, _tile_bf16(rec_ref.at[k]),
+                        None if cat_ref is None else cat_ref.at[k], **kw)
+        cnt = jnp.sum(S.astype(jnp.float32), axis=1, keepdims=True)
+        out = jnp.where(lane == (t0 + k) % _CNT_LANES,
+                        cnt.astype(jnp.int32), out)
+    out_ref[0] = out
+
+
+def _perm_kernel(dstl_ref, dstr_ref, par_ref, *refs, has_cat: bool,
+                 T: int, WB: int, **kw):
+    """One source tile: sides, stable in-tile ranks, two stable
+    compactions + two windowed writes.
+
+    The ranks are an exclusive prefix sum of the side rows along the
+    tile: per 128-lane block ``side (8, 128) @ strict-upper-triangular
+    ones (128, 128)`` plus the earlier blocks' totals (exact in f32 up to
+    T; one MXU weight tile, where a (T, T) triangle would load sixteen).
+    A row off a side gets rank T = "no row", so each one-hot
+    ``iota_o == rank`` compacts one side to the front and zero-fills the
+    rest."""
+    (tri_ref, cat_ref, rec_ref, init_ref, out_ref, outl_vmem, outr_vmem,
+     seml, semr) = refs if has_cat else refs[:1] + (None,) + refs[1:]
+    i = pl.program_id(0)
+    rec = _tile_bf16(rec_ref.at[0])
+    S = _tile_sides(par_ref[i], rec,
+                    None if cat_ref is None else cat_ref.at[0],
+                    T=T, WB=WB, **kw)
+    Sf = S.astype(jnp.float32)
+    tri = tri_ref[...]
+    carry = jnp.zeros((8, 1), jnp.float32)
+    parts = []
+    for k in range(T // _RANK_LANES):
+        blk = Sf[:, k * _RANK_LANES:(k + 1) * _RANK_LANES]
+        parts.append(carry + jax.lax.dot_general(
+            blk.astype(jnp.bfloat16), tri, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32))
+        carry = carry + jnp.sum(blk, axis=1, keepdims=True)
+    rk = jnp.concatenate(parts, axis=1).astype(jnp.int32)
+    pos = jnp.where(S == 1, rk, T)                     # (8, T)
     iota_o = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
-    PL = (iota_o == pos_ref[0, 0][None, :]).astype(jnp.bfloat16)
-    PR = (iota_o == pos_ref[0, 1][None, :]).astype(jnp.bfloat16)
+    PL = (iota_o == pos[0:1]).astype(jnp.bfloat16)
+    PR = (iota_o == pos[1:2]).astype(jnp.bfloat16)
     outl_vmem[...] = jax.lax.dot_general(
         PL, rec, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(jnp.int32).astype(
@@ -130,97 +260,158 @@ def _perm_kernel(dstl_ref, dstr_ref, pos_ref, rec_ref, init_ref, out_ref,
 
 
 @jax.named_scope("dryad.layout")
-@functools.partial(jax.jit, static_argnames=("n_out_tiles", "platform",
-                                             "axis_name"))
-def permute_records(rec: jnp.ndarray, pos: jnp.ndarray, dstl: jnp.ndarray,
-                    dstr: jnp.ndarray, n_out_tiles: int,
-                    platform: str | None = None,
-                    axis_name: str | None = None) -> jnp.ndarray:
-    """Apply one level's movement.
+@functools.partial(jax.jit, static_argnames=(
+    "bin_dtype", "learn_missing", "n_out_tiles", "platform", "axis_name"))
+def move_level(lay_rec: jnp.ndarray, lay_tile_run: jnp.ndarray,
+               run_rec: jnp.ndarray, run_catmask: jnp.ndarray | None = None,
+               *, bin_dtype, learn_missing: bool = False,
+               n_out_tiles: int | None = None, platform: str | None = None,
+               axis_name: str | None = None):
+    """One level's movement of the leaf-ordered layout — THE entry point
+    both level-synchronous growers (and the probes) call.
 
-    rec (n_tiles*T, WB) uint8; pos (n_tiles, 2, T) int32 in-tile ranks
-    (T = no row, incl. every sentinel row); dstl/dstr (n_tiles,) int32
-    destination ROW offsets.  ``n_out_tiles`` MUST include the two slack
-    tiles ``level_moves`` accounts for.  Returns the new (n_out_tiles*T,
-    WB) uint8 leaf-ordered buffer.
+    lay_rec (n_tiles*T, _REC_WB-wide) uint8 layout records; lay_tile_run
+    (n_tiles,) int32 ascending tile -> run map; run_rec (P, 2) uint32 the
+    growers' packed split record of each run (word 0: bit 31 splits, 30
+    default-left, 29 categorical, 16-28 threshold bin; word 1: feature; a
+    zero row = pass-through); run_catmask (P, Bc) bool each run's
+    categorical left-membership row, or None without categorical
+    features.  ``n_out_tiles`` (default: the input's tile count, the
+    stationary wired buffer) MUST include the two slack tiles
+    ``level_moves`` accounts for.
 
-    ``axis_name`` marks the output device-varying when tracing under
-    ``shard_map`` (each shard permutes its own local layout; no
-    collective here — the histogram psum stays the growers' only one).
+    Every row of a tile belongs to one run, so one split: the per-run
+    records are gathered per TILE (never per row), a counting kernel
+    reads each tile's left/right row counts, ``level_moves`` turns them
+    into destinations with tile-sized prefix work, and the move kernel
+    derives sides and in-tile ranks itself from the tile it already
+    holds.  Returns (lay_rec_new, base_l, base_r) — the
+    (n_out_tiles*T, WB) buffer in [left children | slack | right children
+    | slack] order and ``level_moves``' (P+1,) first-tile indices.
+
+    ``axis_name`` marks the outputs device-varying when tracing under
+    ``shard_map`` (each shard moves its own local layout; no collective
+    here — the histogram psum stays the growers' only one).
 
     The output is ALIASED to a zero buffer: rows no DMA write covers
     (inner pad rows of multi-tile segments with uneven source fill,
     untouched slack) must be zero sentinels — an uninitialized ANY-space
     buffer holds stale HBM bytes on real hardware (interpret mode
     zero-fills and masks this; caught in review)."""
-    n_rows, WB = rec.shape
+    n_rows, WB = lay_rec.shape
     T = _TILE_ROWS
     if n_rows % T:
         # a ragged tail would be dropped, and on hardware the rows past
         # it are stale HBM, not the interpreter's zeros
-        raise ValueError(f"permute_records: {n_rows} rows is not a "
+        raise ValueError(f"move_level: {n_rows} rows is not a "
                          f"multiple of the {T}-row tile")
     n_tiles = n_rows // T
+    assert lay_tile_run.shape == (n_tiles,), (lay_tile_run.shape, n_tiles)
+    n_out_tiles = n_tiles if n_out_tiles is None else int(n_out_tiles)
+    P = run_rec.shape[0]
+    has_cat = run_catmask is not None
+    vma = None if axis_name is None else frozenset({axis_name})
+
+    def varying(x):
+        # constants entering a shard-local kernel must carry the same
+        # varying-manual-axes as the per-shard operands beside them
+        return x if axis_name is None else jax.lax.pcast(
+            x, axis_name, to="varying")
+
+    # ---- per-TILE parameters: (P,)-level compose, (n_tiles,) gathers ------
+    run_par = ((run_rec[:, 0] >> 16)
+               | (run_rec[:, 1] << _PAR_FEAT_SHIFT)).astype(jnp.int32)
+    par = run_par[lay_tile_run]
+    rec3 = lay_rec.reshape(n_tiles, T, WB)
+    cat_ops, n_cat_bins = [], 1
+    if has_cat:
+        n_cat_bins = run_catmask.shape[1]
+        Bcp = -(-n_cat_bins // 128) * 128
+        cat_ops = [jnp.pad(run_catmask.astype(jnp.float32),
+                           ((0, 0), (0, Bcp - n_cat_bins))
+                           )[lay_tile_run][:, None, :]]
+    kw = dict(has_cat=has_cat, T=T, WB=WB,
+              itemsize=jnp.dtype(bin_dtype).itemsize,
+              learn_missing=bool(learn_missing), n_cat_bins=n_cat_bins)
+
+    def tile_specs(k):
+        # [the runs' bitset rows,] the record tiles: k tiles a grid step
+        return ([pl.BlockSpec((k, 1, cat_ops[0].shape[-1]),
+                              lambda i, *_: (i, 0, 0))] if has_cat else []
+                ) + [pl.BlockSpec((k, T, WB), lambda i, *_: (i, 0, 0))]
+
+    # ---- counting pass: destinations are prefixes over EARLIER tiles ------
+    nb = -(-n_tiles // _CNT_LANES)
+    cnt = pl.pallas_call(
+        functools.partial(_count_kernel, n_tiles=n_tiles, **kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(-(-n_tiles // _CNT_STEP),),
+            in_specs=tile_specs(_CNT_STEP),
+            out_specs=pl.BlockSpec(
+                (1, 8, _CNT_LANES),
+                lambda i, *_: (i * _CNT_STEP // _CNT_LANES, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((nb, 8, _CNT_LANES), jnp.int32,
+                                       vma=vma),
+        interpret=_interpret(platform),
+        name="permute_records_count",
+    )(par, *cat_ops, rec3)
+    counts = cnt[:, :2].transpose(0, 2, 1).reshape(nb * _CNT_LANES, 2)
+    dstl, dstr, base_l, base_r, _ = level_moves(
+        lay_tile_run, counts[:n_tiles], P)
+
+    # ---- the move ------------------------------------------------------------
     # memory-safety clamp (tile_plan's "safety squeeze" precedent): a
     # violated caller bound must misplace rows DETERMINISTICALLY inside
     # the buffer, never DMA past it (granule writes cover T rows from dst)
     dst_cap = jnp.int32((n_out_tiles - 1) * T)
     dstl = jnp.minimum(dstl, dst_cap)
     dstr = jnp.minimum(dstr, dst_cap)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, 2, T), lambda i, dl, dr: (i, 0, 0)),
-            pl.BlockSpec((1, T, WB), lambda i, dl, dr: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((T // _ALIGN, _ALIGN, WB), jnp.uint8),
-            pltpu.VMEM((T // _ALIGN, _ALIGN, WB), jnp.uint8),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
+    r = jnp.arange(_RANK_LANES)
+    tri = varying((r[:, None] < r[None, :]).astype(jnp.bfloat16))
     G = n_out_tiles * T // _ALIGN
-    zeros = jnp.zeros((G, _ALIGN, WB), jnp.uint8)
-    if axis_name is not None:
-        # the aliased zero init must carry the same varying-manual-axes
-        # as the (shard-local) output it becomes
-        zeros = jax.lax.pcast(zeros, axis_name, to="varying")
+    zeros = varying(jnp.zeros((G, _ALIGN, WB), jnp.uint8))
+    n_in = 3 + 1 + len(cat_ops) + 1      # prefetched scalars, tri, cat, rec
     out = pl.pallas_call(
-        functools.partial(_perm_kernel, T=T, WB=WB),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (G, _ALIGN, WB), jnp.uint8,
-            vma=None if axis_name is None else frozenset({axis_name})),
-        # operand index counts the 2 prefetched scalars first: 2=pos,
-        # 3=rec, 4=zeros -> alias the zero buffer to the output
-        input_output_aliases={4: 0},
+        functools.partial(_perm_kernel, **kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(n_tiles,),
+            in_specs=[pl.BlockSpec((_RANK_LANES, _RANK_LANES),
+                                   lambda i, *_: (0, 0))]
+            + tile_specs(1)
+            + [pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((T // _ALIGN, _ALIGN, WB), jnp.uint8),
+                pltpu.VMEM((T // _ALIGN, _ALIGN, WB), jnp.uint8),
+                pltpu.SemaphoreType.DMA,
+                pltpu.SemaphoreType.DMA,
+            ]),
+        out_shape=jax.ShapeDtypeStruct((G, _ALIGN, WB), jnp.uint8, vma=vma),
+        # the zero buffer is the last operand: alias it to the output
+        input_output_aliases={n_in: 0},
         interpret=_interpret(platform),
         name="permute_records",
-    )(dstl // _ALIGN, dstr // _ALIGN, pos.astype(jnp.int32),
-      rec.reshape(n_tiles, T, WB), zeros)
-    return out.reshape(n_out_tiles * T, WB)
+    )(dstl // _ALIGN, dstr // _ALIGN, par, tri, *cat_ops, rec3, zeros)
+    return out.reshape(n_out_tiles * T, WB), base_l, base_r
 
 
 @jax.named_scope("dryad.layout")
-def level_moves(tile_slot: jnp.ndarray, side: jnp.ndarray,
+def level_moves(tile_slot: jnp.ndarray, counts: jnp.ndarray,
                 n_parents: int, T: int = _TILE_ROWS):
-    """XLA bookkeeping for one level — O(N) elementwise + O(n_tiles)
-    prefix work, no sort.
+    """XLA bookkeeping for one level — O(n_tiles) prefix work, no sort,
+    nothing row-sized.
 
     tile_slot (n_tiles,) int32: source segment per tile (layout
-    invariant).  side (n_tiles*T,) int32: 0 = left child, 1 = right
-    child, anything else = sentinel (vanishes).  ``n_parents`` (static):
-    parent segment count P; pass-through parents route all rows left —
-    their right segment still gets the mandatory 1-tile allocation but
-    receives only zeros.
+    invariant).  counts (n_tiles, 2) int32: each tile's rows going to the
+    left / right child (the counting pass; sentinel rows in neither).
+    ``n_parents`` (static): parent segment count P; pass-through parents
+    route all rows left — their right segment still gets the mandatory
+    1-tile allocation but receives only zeros.
 
-    Returns (pos, dstl, dstr, base_l, base_r, n_out_tiles): the new
+    Returns (dstl, dstr, base_l, base_r, n_out_tiles): the new
     layout is [left children in parent order | slack | right children |
-    slack]; ``base_l``/``base_r`` are (P+1,) FIRST-TILE indices of each
+    slack]; ``dstl``/``dstr`` are each tile's destination ROW offsets,
+    ``base_l``/``base_r`` the (P+1,) FIRST-TILE indices of each
     parent's left/right child segment (right already offset past the
     left region), from which callers derive the next level's tile→segment
     map.  Within a segment, each source tile's contribution sits at an
@@ -228,17 +419,11 @@ def level_moves(tile_slot: jnp.ndarray, side: jnp.ndarray,
     the _ALIGN note), so real rows are NOT a contiguous prefix.
     ``n_out_tiles`` is a traced scalar — callers pick the static bound
     (see tiles_bound)."""
-    n_tiles = tile_slot.shape[0]
     A = _ALIGN
-    s2 = side.reshape(n_tiles, T)
-    isl = (s2 == 0).astype(jnp.int32)
-    isr = (s2 == 1).astype(jnp.int32)
-    rkl = jnp.cumsum(isl, axis=1) - isl                # stable in-tile ranks
-    rkr = jnp.cumsum(isr, axis=1) - isr
     # each tile's contribution OCCUPIES an _ALIGN-rounded slot run so its
     # write start stays Mosaic-sliceable (see _ALIGN note)
-    nl_t = -(-isl.sum(axis=1) // A) * A
-    nr_t = -(-isr.sum(axis=1) // A) * A
+    nl_t = -(-counts[:, 0] // A) * A
+    nr_t = -(-counts[:, 1] // A) * A
     cl = jnp.cumsum(nl_t) - nl_t                       # global tile prefixes
     cr = jnp.cumsum(nr_t) - nr_t
     first = jnp.concatenate([jnp.ones((1,), bool),
@@ -253,12 +438,10 @@ def level_moves(tile_slot: jnp.ndarray, side: jnp.ndarray,
 
     # segment capacities cover the PADDED contributions (per-segment sum
     # of rounded per-tile sizes = last prefix + last size)
-    lastl = jnp.where(
-        jnp.concatenate([tile_slot[1:] != tile_slot[:-1],
-                         jnp.ones((1,), bool)]), prefl + nl_t, -1)
-    lastr = jnp.where(
-        jnp.concatenate([tile_slot[1:] != tile_slot[:-1],
-                         jnp.ones((1,), bool)]), prefr + nr_t, -1)
+    last = jnp.concatenate([tile_slot[1:] != tile_slot[:-1],
+                            jnp.ones((1,), bool)])
+    lastl = jnp.where(last, prefl + nl_t, -1)
+    lastr = jnp.where(last, prefr + nr_t, -1)
     P = int(n_parents)
     pad_l = jnp.zeros((P,), jnp.int32).at[tile_slot].max(lastl)
     pad_r = jnp.zeros((P,), jnp.int32).at[tile_slot].max(lastr)
@@ -270,10 +453,7 @@ def level_moves(tile_slot: jnp.ndarray, side: jnp.ndarray,
     dstl = (base_l[tile_slot] * T + prefl).astype(jnp.int32)
     dstr = ((off_r + base_r[tile_slot]) * T + prefr).astype(jnp.int32)
     n_out_tiles = off_r + base_r[-1] + 1
-
-    pos = jnp.stack([jnp.where(s2 == 0, rkl, T),
-                     jnp.where(s2 == 1, rkr, T)], axis=1).astype(jnp.int32)
-    return pos, dstl, dstr, base_l, base_r + off_r, n_out_tiles
+    return dstl, dstr, base_l, base_r + off_r, n_out_tiles
 
 
 def tiles_bound(n_rows: int, n_parents: int, T: int = _TILE_ROWS) -> int:
@@ -307,9 +487,8 @@ def make_layout_records(Xb: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
 
     ``valid`` (N,) bool marks rows that participate (the bag mask for a
     root-anchored layout): rows outside it get valid flag 0 and are
-    DROPPED by the first level's move (the side derivation sends
-    flag-0 rows to the sentinel plane), so out-of-bag rows never ride a
-    permute past level 0."""
+    DROPPED by the first level's move (the kernels put flag-0 rows on
+    neither side), so out-of-bag rows never ride a permute past level 0."""
     N, F = Xb.shape
     nbytes = F * Xb.dtype.itemsize
     assert 9 + nbytes <= _REC_WB, "feature bytes exceed the record"
@@ -428,7 +607,7 @@ def hist_from_layout(rec: jnp.ndarray, seg_first: jnp.ndarray,
 # level fori state as (rec, tile_run, run_slot):
 #
 # * ``tile_run`` (n_buf_tiles,) int32 — per-tile RUN index, ascending in
-#   layout order (the write-ordering safety of permute_records requires
+#   layout order (the write-ordering safety of the move kernel requires
 #   destination order == source processing order, which holds exactly when
 #   run ids ascend with tile position — the oracle's implicit invariant).
 # * ``run_slot`` (L,) int32 — run index -> grower leaf-slot id (sentinel L
@@ -493,7 +672,7 @@ def natural_root_layout(rec_nat: jnp.ndarray, num_runs: int,
     (default ``num_runs``) elsewhere.  Under ``shard_map`` pass
     ``axis_name`` so the carried bookkeeping state enters the level loop
     device-varying like the outputs that replace it (same vma rule as
-    permute_records' aliased zero init)."""
+    move_level's aliased zero init)."""
     N = rec_nat.shape[0]
     T = _TILE_ROWS
     assert N <= n_buf_tiles * T, (N, n_buf_tiles)
@@ -525,7 +704,7 @@ def initial_layout(rec_nat: jnp.ndarray, sel: jnp.ndarray,
     preceding run.  Returns (rec_lay, tile_run, run_slot).
 
     Per-slot row order is the plan paths' STABLE row-id order (tile_plan's
-    stable sort), and permute_records preserves source order within
+    stable sort), and move_level preserves source order within
     (segment, side) — so every later level's per-slot order matches what
     tile_plan_aligned would produce for the same selection, by
     construction (the integration contract test_leafperm pins)."""
@@ -593,6 +772,48 @@ def advance_runs(run_slot: jnp.ndarray, run_do: jnp.ndarray,
 # ---------------------------------------------------------------------------
 # numpy reference (the bitwise oracle for tests)
 # ---------------------------------------------------------------------------
+
+def pack_run_records(do, feature, thresh, dleft=None, is_cat=None):
+    """(P, 2) uint32 per-run split records in the growers' packed format
+    (``move_level``'s ``run_rec``) from per-run arrays — for the probes,
+    scripts and tests that drive the move without a grower (traceable:
+    a probe's thresholds may ride its carried scalar)."""
+    def u32(x):
+        return jnp.zeros_like(w0) if x is None else jnp.asarray(x).astype(
+            jnp.uint32)
+
+    w0 = jnp.asarray(do).astype(jnp.uint32) << 31
+    w0 = w0 | (u32(dleft) << 30) | (u32(is_cat) << 29) | (u32(thresh) << 16)
+    return jnp.stack([w0, u32(feature)], axis=1)
+
+
+def layout_sides_np(rec: np.ndarray, tile_run: np.ndarray,
+                    run_rec: np.ndarray, run_catmask=None, *,
+                    bin_dtype=np.uint8, learn_missing: bool = False,
+                    T: int = _TILE_ROWS) -> np.ndarray:
+    """Reference side of every layout row (0 left, 1 right, 2 neither)
+    from its tile's run record — ``packed_route``'s rules in numpy."""
+    itemsize = np.dtype(bin_dtype).itemsize
+    n = rec.shape[0]
+    rr = np.asarray(run_rec)[np.repeat(np.asarray(tile_run), T)]
+    w0, f = rr[:, 0], rr[:, 1].astype(np.int64)
+    b0 = 9 + f * itemsize
+    rows = np.arange(n)
+    bins = rec[rows, b0].astype(np.int64)
+    if itemsize == 2:
+        bins += rec[rows, b0 + 1].astype(np.int64) << 8
+    gl = bins <= ((w0 >> 16) & 0x1FFF)
+    if learn_missing:
+        gl &= ((w0 >> 30) & 1).astype(bool) | (bins > 0)
+    if run_catmask is not None:
+        cm = np.asarray(run_catmask)
+        cat_row = cm[np.repeat(np.asarray(tile_run), T),
+                     np.minimum(bins, cm.shape[1] - 1)]
+        gl = np.where(((w0 >> 29) & 1).astype(bool), cat_row, gl)
+    right = ((w0 >> 31) != 0) & ~gl
+    return np.where(rec[:, 8] == 1, right.astype(np.int32), 2).astype(
+        np.int32)
+
 
 def permute_records_np(rec: np.ndarray, tile_slot: np.ndarray,
                        side: np.ndarray, n_parents: int, n_out_tiles: int,

@@ -390,21 +390,18 @@ def grow_tree_leafwise_batched(
                         [w0_t, jnp.maximum(st["nd_feature"], 0).astype(jnp.uint32)],
                         axis=1)
 
-                    def packed_route(nodes, bins_of, rr=None):
+                    def packed_route(nodes):
                         """Per-row routing off the packed per-NODE table:
-                        (splits?, goes-left?).  Shared by the natural-order
-                        partition and the layout side derivation so the two
-                        can never disagree on a row (identical integer/bool
-                        arithmetic — levelwise.packed_route's convention).
-                        ``rr`` lets the caller pass a pre-composed per-row
-                        record (one small-table gather instead of two
-                        chained ones); ``nodes`` is then only consulted for
-                        the categorical bitset row."""
-                        if rr is None:
-                            rr = rec_t[nodes]                    # ONE gather
+                        (splits?, goes-left?).  The layout's kernels
+                        (leafperm._tile_sides) apply the SAME integer/bool
+                        rules to the same table per tile, so the
+                        natural-order partition and the layout can never
+                        disagree on a row (levelwise.packed_route's
+                        convention)."""
+                        rr = rec_t[nodes]                        # ONE gather
                         w0r = rr[:, 0]
                         rf = rr[:, 1].astype(jnp.int32)
-                        bins_rf = bins_of(rf)
+                        bins_rf = levelwise.select_bins(Xb, rf)
                         gl = bins_rf <= ((w0r >> 16)
                                          & jnp.uint32(0x1FFF)).astype(jnp.int32)
                         if learn_missing:
@@ -417,8 +414,7 @@ def grow_tree_leafwise_batched(
                                            cat_row, gl)
                         return ((w0r >> 31) != 0), gl
 
-                    row_do, go_left = packed_route(
-                        rn, lambda rf: levelwise.select_bins(Xb, rf))
+                    row_do, go_left = packed_route(rn)
                 else:
                     row_do = valid_n[rn]
                     rf = jnp.maximum(st["nd_feature"][rn], 0)
@@ -439,39 +435,25 @@ def grow_tree_leafwise_batched(
             lay_new = None
             if use_layout:
                 # WIRED level (r10): no per-level sort, no full-N record
-                # gather.  Sides come off the carried layout's records
-                # via the SAME packed_route arithmetic as the
-                # natural-order partition above; one stable per-tile MXU
-                # compaction moves the rows; the smaller children read
-                # back as contiguous tile runs of the new layout.
+                # gather, nothing row-sized in XLA.  The per-node table is
+                # composed run -> record at the (NR,) level;
+                # leafperm.move_level gathers it per TILE and its kernels
+                # derive sides and in-tile ranks from the record tiles
+                # (see levelwise.py's wired block).  The smaller children
+                # read back as contiguous tile runs of the new layout.
                 with jax.named_scope("dryad.layout"):
-                    Tl = leafperm._TILE_ROWS
-                    lay_rec = st["lay_rec"]
                     lay_tr = st["lay_tile_run"]
                     lay_ns = st["lay_run_slot"]           # run -> heap node
-                    row_run = jnp.repeat(lay_tr, Tl)
-                    # compose run -> packed word at the (NR,) level, then pay
-                    # ONE per-row small-table gather (CLAUDE.md
-                    # pack-the-lookups rule); sentinel runs (lay_ns = HN)
-                    # compose to the zero pad row -> their rows route
-                    # pass-through, and carry no valid rows anyway
+                    # sentinel runs (lay_ns = HN) compose to the zero pad
+                    # row -> pass-through, and carry no valid rows anyway
                     rec_pad = jnp.concatenate(
                         [rec_t, jnp.zeros((1, 2), jnp.uint32)])
-                    rr_lay = rec_pad[jnp.minimum(lay_ns, HN)][row_run]
-                    node_lay = lay_ns[row_run] if has_cat else None
-                    _, _, valid_lay, xb_lay = leafperm.unpack_layout_records(
-                        lay_rec, F, Xb.dtype)
-                    do_lay, left_lay = packed_route(
-                        node_lay, lambda rf: levelwise.select_bins(xb_lay, rf),
-                        rr=rr_lay)
-                    side = jnp.where(
-                        valid_lay,
-                        jnp.where(do_lay & ~left_lay, 1, 0),
-                        2).astype(jnp.int32)
-                    pos, dstl, dstr, base_l, base_r, _ = leafperm.level_moves(
-                        lay_tr, side, NR)
-                    lay_rec = leafperm.permute_records(
-                        lay_rec, pos, dstl, dstr, lay_tr.shape[0],
+                    lay_rec, base_l, base_r = leafperm.move_level(
+                        st["lay_rec"], lay_tr,
+                        rec_pad[jnp.minimum(lay_ns, HN)],
+                        st["nd_catmask"][jnp.minimum(lay_ns, HN - 1)]
+                        if has_cat else None,
+                        bin_dtype=Xb.dtype, learn_missing=learn_missing,
                         platform=platform, axis_name=axis_name)
                     # node -> run inverse BEFORE advancing (candidates are
                     # parents of this level's move); sentinel runs scatter

@@ -538,21 +538,17 @@ def grow_tree_levelwise(
                                        jnp.maximum(sf, 0).astype(jnp.uint32)],
                                       axis=1), mode="drop")
 
-                    def packed_route(slot_idx, bins_of, rr=None):
+                    def packed_route(slot_idx):
                         """Per-row split routing off the packed per-slot table:
-                        (splits?, goes-left?, packed word).  Shared by the
-                        natural-order partition and the layout side derivation
-                        so the two can never disagree on a row (identical
-                        integer/bool arithmetic).  ``rr`` lets the caller pass
-                        a pre-composed per-row record (one big gather instead
-                        of two chained ones — the CLAUDE.md pack-the-lookups
-                        rule); ``slot_idx`` is then only consulted for the
-                        categorical bitset row."""
-                        if rr is None:
-                            rr = rec_t[jnp.minimum(slot_idx, L)]  # ONE gather
+                        (splits?, goes-left?, packed word).  The layout's
+                        kernels (leafperm._tile_sides) apply the SAME
+                        integer/bool rules to the same table per tile, so
+                        the natural-order partition and the layout can never
+                        disagree on a row."""
+                        rr = rec_t[jnp.minimum(slot_idx, L)]      # ONE gather
                         w0r = rr[:, 0]
                         rf = rr[:, 1].astype(jnp.int32)
-                        bins_rf = bins_of(rf)
+                        bins_rf = select_bins(Xb, rf)
                         thr_r = ((w0r >> 16)
                                  & jnp.uint32(0x1FFF)).astype(jnp.int32)
                         gl = bins_rf <= thr_r
@@ -565,8 +561,7 @@ def grow_tree_levelwise(
                                            cat_row, gl)
                         return ((w0r >> 31) != 0), gl, w0r
 
-                    do_n, left_n, w0r = packed_route(
-                        rs, lambda rf: select_bins(Xb, rf))
+                    do_n, left_n, w0r = packed_route(rs)
                     row_do = do_n & (row_slot < L)
                     row_slot = jnp.where(
                         row_do & ~left_n,
@@ -597,39 +592,29 @@ def grow_tree_levelwise(
             left_smaller = CL <= CR
             if use_layout:
                 # WIRED level (r6 deep phase, r10 everywhere): no
-                # per-level sort, no full-N record gather.  Sides come
-                # straight off the carried leaf-ordered layout's records
-                # via the SAME packed_route arithmetic the natural-order
-                # partition used above (the two agree on every row —
-                # identical integer/bool math), one stable per-tile MXU
-                # compaction moves the rows, and the children read back
-                # as contiguous tile runs.
+                # per-level sort, no full-N record gather, and nothing
+                # row-sized in XLA.  Every row of a layout tile belongs to
+                # one run, so one slot, so one split: the packed per-slot
+                # table the route block built is composed run -> record at
+                # the (L,) level and handed to leafperm.move_level, which
+                # gathers it per TILE, counts each tile's left/right rows
+                # in a small kernel, places the tiles with tile-sized
+                # prefix work, and derives sides and stable in-tile ranks
+                # inside the move kernel from the record tile it already
+                # holds (packed_route's integer rules, so layout and
+                # natural-order partition agree on every row).  The
+                # children read back as contiguous tile runs.
                 with jax.named_scope("dryad.layout"):
-                    lay_rec = st["lay_rec"]
                     lay_tr = st["lay_tile_run"]
                     lay_rs = st["lay_run_slot"]
-                    row_run = jnp.repeat(lay_tr, leafperm._TILE_ROWS)
-                    # compose run -> packed record at the (L,) level, then pay
-                    # ONE per-row small-table gather (two chained (n_buf*T,)
-                    # gathers cost ~2x — the CLAUDE.md pack-the-lookups rule);
-                    # dead runs (lay_rs = L) compose to rec_t[L] = zeros, so
-                    # their rows route pass-through — and carry no valid rows
-                    # anyway (absorbed segments hold only sentinels)
-                    rr_lay = rec_t[jnp.minimum(lay_rs, L)][row_run]
-                    slot_lay = lay_rs[row_run] if has_cat else None
-                    _, _, valid_lay, xb_lay = leafperm.unpack_layout_records(
-                        lay_rec, F, Xb.dtype)
-                    do_lay, left_lay, _ = packed_route(
-                        slot_lay, lambda rf: select_bins(xb_lay, rf),
-                        rr=rr_lay)
-                    side = jnp.where(
-                        valid_lay,
-                        jnp.where(do_lay & ~left_lay, 1, 0),
-                        2).astype(jnp.int32)
-                    pos, dstl, dstr, base_l, base_r, _ = leafperm.level_moves(
-                        lay_tr, side, L)
-                    lay_rec = leafperm.permute_records(
-                        lay_rec, pos, dstl, dstr, lay_tr.shape[0],
+                    # dead runs (lay_rs = L) compose to rec_t[L] = zeros:
+                    # pass-through — they carry no valid rows anyway
+                    # (absorbed segments hold only sentinels)
+                    lay_rec, base_l, base_r = leafperm.move_level(
+                        st["lay_rec"], lay_tr, rec_t[jnp.minimum(lay_rs, L)],
+                        sp_catmask[jnp.minimum(lay_rs, L - 1)]
+                        if has_cat else None,
+                        bin_dtype=Xb.dtype, learn_missing=learn_missing,
                         platform=platform, axis_name=axis_name)
                     # slot -> run inverse BEFORE advancing (candidates are
                     # parents of this level's move); dead runs scatter to

@@ -266,9 +266,10 @@ def _layout_fixture(rows, F, B, P, seed):
 
 
 def _build_permute_records(rows, F, B, P, seed):
-    """The leafperm movement kernel: one level's sides + level_moves +
-    permute_records.  The side threshold alternates with the carried
-    scalar, so the whole move chain stays in the loop."""
+    """The leafperm level move (``move_level``: per-tile parameters,
+    counting pass, ``level_moves``, the move kernel).  The runs' split
+    threshold walks with the carried scalar, so the whole move chain
+    stays in the loop."""
     import jax.numpy as jnp
 
     leafperm, T, n_buf, rec_lay, tile_run, _ = _layout_fixture(
@@ -282,17 +283,17 @@ def _build_permute_records(rows, F, B, P, seed):
     stride = max(1, (n_buf * T) // 256)
 
     def step(s, rec_lay, tile_run):
-        g_l, _, valid, _ = leafperm.unpack_layout_records(
-            rec_lay, F, bin_dtype)
         # period-8 threshold walk: a period-2 alternation summed over K
         # trips gives the SAME contrib multiset at both liveness seeds
-        # (the accumulator is order-independent) and reads as dead
+        # (the accumulator is order-independent) and reads as dead.
+        # Every run splits on feature 0; bins are uniform in [0, B), so
+        # the walk moves ~1/20 of the rows across per step
         smod = s - jnp.floor(s / 8.0) * 8.0
-        thr = -0.45 + 0.05 * smod            # strictly negative: < half go left
-        side = jnp.where(valid, (g_l > thr).astype(jnp.int32), 2)
-        pos, dstl, dstr, base_l, base_r, _ = leafperm.level_moves(
-            tile_run, side, P)
-        out = leafperm.permute_records(rec_lay, pos, dstl, dstr, n_buf)
+        run_rec = leafperm.pack_run_records(
+            jnp.ones((P,)), jnp.zeros((P,)),
+            jnp.full((P,), B * (0.30 + 0.05 * smod)))
+        out, base_l, _ = leafperm.move_level(
+            rec_lay, tile_run, run_rec, bin_dtype=bin_dtype)
         samp = out[::stride, 0].astype(jnp.float32)
         pos_w = jnp.arange(samp.shape[0], dtype=jnp.float32) + 1.0
         return (s + 1.0,
@@ -610,7 +611,7 @@ PROBES: dict[str, StageProbe] = {p.name: p for p in (
                "+ packed record combine (hist_reduce='feature')",
                _build_hist_reduce_scan),
     StageProbe("permute_records",
-               "leafperm movement kernel (sides + level_moves + permute)",
+               "leafperm level move (count + level_moves + permute)",
                _build_permute_records),
     StageProbe("hist_from_layout",
                "layout histogram read (tile-run gather + kernel)",

@@ -105,6 +105,7 @@ class ReplicaProcess:
         self.port: Optional[int] = None
         self.log_path: Optional[str] = None
         self._port_file: Optional[str] = None
+        self._stopped = False
 
     # ---- lifecycle ---------------------------------------------------------
     def start(self) -> "ReplicaProcess":
@@ -126,6 +127,13 @@ class ReplicaProcess:
             self.proc = subprocess.Popen(argv, stdout=log, stderr=log, env=env)
         finally:
             log.close()                      # the child holds its own handle
+        if self._stopped:
+            # a stop() that ran before the child existed found nothing to
+            # terminate; without this the ready wait would outlive it
+            self.stop()
+            raise ReplicaStartupError(
+                f"replica {self.name} was stopped while starting",
+                exit_code=self.proc.poll())
         self._await_ready()
         return self
 
@@ -167,7 +175,10 @@ class ReplicaProcess:
         return f"http://{self.host}:{self.port}"
 
     def stop(self, grace_s: float = 3.0) -> Optional[int]:
-        """Terminate (then kill) the process; returns the exit code."""
+        """Terminate (then kill) the process; returns the exit code.  Final:
+        a ``start()`` this races (the supervisor registers a replica on its
+        slot before spawning it) gives up instead of waiting for ready."""
+        self._stopped = True
         if self.proc is None:
             return None
         if self.proc.poll() is None:

@@ -52,18 +52,21 @@ def device_correctness_check():
         r0 = base[s] * T
         rec[r0: r0 + cnt] = rng.integers(1, 255, (cnt, 128), dtype=np.uint8)
         row_seg[r0: r0 + cnt] = s
-    side = np.where(row_seg >= 0,
-                    (rng.random(row_seg.size) < 0.5).astype(np.int32),
-                    2).astype(np.int32)
-    pos, dstl, dstr, _, _, n_out = leafperm.level_moves(
-        jnp.asarray(tile_slot), jnp.asarray(side), len(seg_counts))
-    bound = leafperm.tiles_bound(rec.shape[0], len(seg_counts))
-    got = np.asarray(leafperm.permute_records(
-        jnp.asarray(rec), pos, dstl, dstr, bound))
-    want, _, _ = leafperm.permute_records_np(rec, tile_slot, side,
-                                             len(seg_counts), bound)
-    np.testing.assert_array_equal(got[: int(n_out) * T],
-                                  want[: int(n_out) * T])
+    rec[:, 8] = row_seg >= 0                       # valid flag
+    # every segment splits on its own feature at bin 127 (bytes are
+    # uniform in [1, 255)); the oracle's sides come from the same records
+    P = len(seg_counts)
+    run_rec = leafperm.pack_run_records(
+        do=np.ones(P), feature=np.arange(P), thresh=np.full(P, 127))
+    side = leafperm.layout_sides_np(rec, tile_slot, run_rec)
+    bound = leafperm.tiles_bound(rec.shape[0], P)
+    got, _, base_r = leafperm.move_level(
+        jnp.asarray(rec), jnp.asarray(tile_slot), jnp.asarray(run_rec),
+        bin_dtype=np.uint8, n_out_tiles=bound)
+    n_out = int(base_r[-1]) + 1
+    want, _, _ = leafperm.permute_records_np(rec, tile_slot, side, P, bound)
+    np.testing.assert_array_equal(np.asarray(got)[: n_out * T],
+                                  want[: n_out * T])
     print("on-device bitwise vs oracle: OK", flush=True)
 
 
@@ -87,27 +90,27 @@ def main():
         row_seg[base[s] * T: base[s] * T + cnt[s]] = s
     rec = rng.integers(0, 255, (n_tiles * T, WB), dtype=np.uint8)
     rec[row_seg < 0] = 0
+    rec[:, 8] = row_seg >= 0                       # valid flag
     rec_d = jnp.asarray(rec)
     tile_slot_d = jnp.asarray(tile_slot)
-    row_seg_d = jnp.asarray(row_seg)
-    u = jnp.asarray(rng.random(n_tiles * T).astype(np.float32))
     bound = leafperm.tiles_bound(rec.shape[0], P)
 
-    # ---- permutation kernel: bookkeeping + move ---------------------------
-    def perm_step(s, rec_d, tile_slot_d, row_seg_d, u):
-        # perturbed split: the side bits change with s, reaching every stage
-        # s advances by whole units per rep (dead-input trap note
-        # in CLAUDE.md): thr alternates between reps
-        thr = 0.45 + 0.05 * (s - jnp.floor(s / 2) * 2)
-        side = jnp.where(row_seg_d >= 0,
-                         (u < thr).astype(jnp.int32), 2)
-        pos, dstl, dstr, _, _, _ = leafperm.level_moves(
-            tile_slot_d, side, P)
-        out = leafperm.permute_records(rec_d, pos, dstl, dstr, bound)
+    # ---- the level move: per-tile parameters, count, bookkeeping, move ----
+    def perm_step(s, rec_d, tile_slot_d):
+        # perturbed split: the runs' threshold on feature 0 (bytes uniform
+        # in [0, 255)) changes with s, reaching every stage; s advances by
+        # whole units per rep (dead-input trap note in CLAUDE.md): thr
+        # alternates between reps
+        run_rec = leafperm.pack_run_records(
+            jnp.ones((P,)), jnp.zeros((P,)),
+            jnp.full((P,), 115 + 13 * (s - jnp.floor(s / 2) * 2)))
+        out, _, _ = leafperm.move_level(
+            rec_d, tile_slot_d, run_rec, bin_dtype=jnp.uint8,
+            n_out_tiles=bound)
         return s + 1.0 + out[0, 0].astype(jnp.float32) * 1e-20
 
-    t_perm = loop_time(perm_step, rec_d, tile_slot_d, row_seg_d, u, K=3)
-    print(f"leafperm (bookkeeping + move, full N): {t_perm:8.1f} ms/level",
+    t_perm = loop_time(perm_step, rec_d, tile_slot_d, K=3)
+    print(f"leafperm (count + bookkeeping + move, full N): {t_perm:8.1f} ms/level",
           flush=True)
 
     # ---- current pipeline: packed sort + record gather --------------------

@@ -133,6 +133,59 @@ def smoke_pallas_natural_order():
     print("pallas natural-order multi-slot: lowers and agrees on device")
 
 
+def smoke_leafperm_move_oracle():
+    """The fused level move (leafperm.move_level: counting pass + move
+    kernel, sides and ranks derived in-kernel from per-tile split
+    records) against the numpy oracle ON THE REAL DEVICE, bitwise, for
+    every record shape the wired gate admits: u8 and u16 bins, learned
+    missing direction, categorical bitsets, a pass-through and a dead run.
+    Interpret mode cannot vouch for the NT selector product, the SMEM
+    parameter words or the resident count block."""
+    import jax.numpy as jnp
+
+    from dryad_tpu.engine import leafperm
+
+    T = leafperm._TILE_ROWS
+    rng = np.random.default_rng(61)
+    N, P = 40_000, 6
+    for dtype, B, F, lm, cat in [(np.uint8, 256, 28, False, False),
+                                 (np.uint8, 200, 100, True, True),
+                                 (np.uint16, 1000, 59, True, False),
+                                 (np.uint16, 1024, 20, False, True)]:
+        Xb = rng.integers(0, B, (N, F)).astype(dtype)
+        rec_nat = leafperm.make_layout_records(
+            jnp.asarray(Xb),
+            jnp.asarray(rng.normal(size=N).astype(np.float32)),
+            jnp.asarray(rng.uniform(0.1, 1, N).astype(np.float32)))
+        n_buf = leafperm.wired_tiles_bound(-(-N // T), P + 1)
+        slot = rng.integers(0, P, N).astype(np.int32)
+        rec_lay, tile_run, _ = leafperm.initial_layout(
+            rec_nat, jnp.asarray(slot), jnp.ones((P + 1,), bool), P + 1,
+            n_buf)
+        # run 4 passes through, run P (empty, absorbed tiles) is dead
+        run_rec = np.array(leafperm.pack_run_records(
+            do=np.arange(P + 1) % 5 != 4,
+            feature=rng.integers(0, F, P + 1),
+            thresh=rng.integers(B // 4, 3 * B // 4, P + 1),
+            dleft=rng.integers(0, 2, P + 1),
+            is_cat=(np.arange(P + 1) % 2 == 1) if cat else None))
+        run_rec[P] = 0
+        cm = (rng.random((P + 1, B)) < 0.5) if cat else None
+        rec_np, tr_np = np.asarray(rec_lay), np.asarray(tile_run)
+        side = leafperm.layout_sides_np(rec_np, tr_np, run_rec, cm,
+                                        bin_dtype=dtype, learn_missing=lm)
+        out, _, _ = leafperm.move_level(
+            rec_lay, tile_run, jnp.asarray(run_rec),
+            None if cm is None else jnp.asarray(cm), bin_dtype=dtype,
+            learn_missing=lm)
+        want, _, _ = leafperm.permute_records_np(rec_np, tr_np, side, P + 1,
+                                                 n_buf)
+        np.testing.assert_array_equal(
+            np.asarray(out), want,
+            err_msg=f"{np.dtype(dtype).name} B={B} lm={lm} cat={cat}")
+    print("leafperm fused move: bitwise vs oracle on device, 4 shapes")
+
+
 def smoke_leafperm_wired_parity():
     """Wired levelwise grower (leaf-ordered layout carried through the
     level fori state, root-anchored since r10 so EVERY level is wired)
@@ -352,6 +405,7 @@ _ALL_SMOKES = [
     smoke_pallas_u16_and_records,
     smoke_pallas_wide_segment_count,
     smoke_pallas_natural_order,
+    smoke_leafperm_move_oracle,
     smoke_leafperm_wired_parity,
     smoke_leafwise_wired_parity,
     smoke_hist_reduce_parity,

@@ -67,6 +67,93 @@ def test_wired_paths_sort_free(audit_report):
         assert c.global_row_sorts == 0 and c.local_row_sorts == 0, name
 
 
+# what a per-row pass over the layout looks like in a jaxpr: the lookups,
+# prefix sums and dtype passes the level move kept in XLA before PR 26
+_ROW_PASS_PRIMS = ("gather", "cumsum", "cumlogsumexp", "cummax", "cummin",
+                   "cumprod", "reduce_window", "reduce_window_sum",
+                   "convert_element_type")
+
+
+def _layout_row_passes(closed):
+    """(layout rows, offenders): the row count of the record buffer the
+    ``permute_records`` kernel is handed, and every equation inside
+    ``dryad.layout`` that is a gather, a prefix sum or a convert with an
+    operand or result of that leading size (flat, or tiled as
+    ``(n_tiles, T, ...)``) — a row-sized XLA pass over the layout.  ``pallas_call`` equations are not entered: the
+    record buffer passed to the kernels is the one row-sized array the
+    scope may hold."""
+    eqns = []
+
+    def walk(jaxpr, stack):
+        for eqn in jaxpr.eqns:
+            here = stack + "/" + str(eqn.source_info.name_stack)
+            eqns.append((here, eqn))
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner, here)
+
+    walk(closed.jaxpr, "")
+    rows = {e.invars[-2].aval.shape[0] * e.invars[-2].aval.shape[1]
+            for _, e in eqns if e.primitive.name == "pallas_call"
+            and e.params["name"] == "permute_records"}
+    assert len(rows) == 1, rows           # one layout buffer size per arm
+    n_rows = rows.pop()
+
+    def row_sized(v):
+        # (n_rows, ...) or its tiled view (n_tiles, T, ...)
+        shape = getattr(v.aval, "shape", ())
+        return bool(shape) and (shape[0] == n_rows or (
+            len(shape) > 1 and shape[0] * shape[1] == n_rows))
+
+    bad = [(e.primitive.name, [v.aval.shape for v in e.invars + e.outvars
+                               if hasattr(v.aval, "shape")])
+           for here, e in eqns
+           if "dryad.layout" in here and e.primitive.name in _ROW_PASS_PRIMS
+           and any(row_sized(v) for v in e.invars + e.outvars)]
+    return n_rows, bad
+
+
+@pytest.mark.parametrize("name", ["levelwise_wired", "leafwise_wired"])
+def test_wired_layout_scope_holds_no_row_sized_pass(name):
+    """The static form of "the level move's mechanism engaged" (PR 26):
+    sides and in-tile ranks are derived inside the layout's kernels from
+    per-TILE split records, so no gather, prefix sum or convert inside
+    ``dryad.layout`` touches an array as large as the layout's rows.  A
+    later edit that brings a per-row pass back fails here, on the CPU."""
+    fn, args, _, _ = ARMS[name].build()
+    n_rows, bad = _layout_row_passes(jax.make_jaxpr(fn)(*args))
+    assert n_rows % 512 == 0 and not bad, (n_rows, bad)
+
+
+def test_row_sized_layout_pass_is_caught():
+    """Mutation direction: the per-row record gather the old level move
+    paid (``rec_t[...][jnp.repeat(tile_run, T)]``) beside the kernels
+    must be flagged."""
+    from dryad_tpu.engine import leafperm
+
+    T = leafperm._TILE_ROWS
+
+    def old_style(rec, tile_run, run_rec):
+        with jax.named_scope("dryad.layout"):
+            rr = run_rec[jnp.repeat(tile_run, T)]     # the per-row gather
+            out, _, _ = leafperm.move_level(rec, tile_run, run_rec,
+                                            bin_dtype=jnp.uint8,
+                                            platform="cpu")
+        return out, rr
+
+    sds = jax.ShapeDtypeStruct
+    closed = jax.make_jaxpr(old_style)(
+        sds((4 * T, leafperm._REC_WB), jnp.uint8), sds((4,), jnp.int32),
+        sds((8, 2), jnp.uint32))
+    n_rows, bad = _layout_row_passes(closed)
+    assert n_rows == 4 * T
+    assert [prim for prim, _ in bad] == ["gather"], bad
+
+
 def test_legacy_arm_keeps_its_tile_plan_sorts(audit_report):
     """The comparison arm must keep sorting — if the legacy path silently
     stopped sorting it is no longer the program the bench compares."""

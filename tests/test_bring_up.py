@@ -171,9 +171,9 @@ def test_layout_kernels_reject_a_ragged_tail():
     T = leafperm._TILE_ROWS
     rec = jnp.zeros((T + 1, leafperm._REC_WB), jnp.uint8)
     with pytest.raises(ValueError, match="multiple"):
-        leafperm.permute_records(rec, jnp.zeros((1, 2, T), jnp.int32),
-                                 jnp.zeros((1,), jnp.int32),
-                                 jnp.zeros((1,), jnp.int32), 4)
+        leafperm.move_level(rec, jnp.zeros((1,), jnp.int32),
+                            jnp.zeros((1, 2), jnp.uint32),
+                            bin_dtype=np.uint8)
     with pytest.raises(ValueError, match="multiple"):
         leafperm.hist_from_layout(rec, jnp.zeros((1,), jnp.int32),
                                   jnp.ones((1,), jnp.int32), 1, 16, 4,

@@ -567,6 +567,25 @@ def test_monitor_retiring_guard_is_load_bearing(tmp_path, monkeypatch):
         slot.retiring = False            # let teardown see a normal slot
 
 
+def test_replica_stopped_before_its_spawn_does_not_wait_for_ready():
+    """The window ``test_stop_reaps_in_flight_scale_up`` falls into under
+    load: the supervisor registers a replica on its slot BEFORE spawning
+    it, so a fleet ``stop()`` can reach the replica while it has no child
+    yet.  The stop is final: the ``start()`` it raced must give up at once
+    (not wait out the startup timeout) and leave no child behind."""
+    from dryad_tpu.fleet.replica import ReplicaProcess
+
+    rp = ReplicaProcess(
+        lambda pf: [sys.executable, "-c", "import time; time.sleep(60)"],
+        name="raced", startup_timeout_s=30.0)
+    assert rp.stop() is None                   # nothing to terminate yet
+    t0 = time.monotonic()
+    with pytest.raises(ReplicaStartupError, match="stopped while starting"):
+        rp.start()
+    assert time.monotonic() - t0 < 10.0
+    assert not rp.alive
+
+
 def test_stop_reaps_in_flight_scale_up(tmp_path):
     """stop() during add_slot's ready wait: the half-born slot is
     registered BEFORE the wait, so the teardown sweep terminates its

@@ -13,9 +13,13 @@ from dryad_tpu.engine import leafperm
 T = leafperm._TILE_ROWS
 
 
-def _mk_layout(rng, seg_counts, WB=64):
+WB = leafperm._REC_WB
+
+
+def _mk_layout(rng, seg_counts):
     """Tile-aligned layout with contiguous-prefix segments (the level-0
-    shape): distinctive record bytes, zero sentinels."""
+    shape): distinctive record bytes, valid flag 1 at byte 8, zero
+    sentinels."""
     lt = np.maximum(-(-np.asarray(seg_counts) // T), 1)
     n_tiles = int(lt.sum())
     rec = np.zeros((n_tiles * T, WB), np.uint8)
@@ -26,6 +30,7 @@ def _mk_layout(rng, seg_counts, WB=64):
         r0 = base[s] * T
         rec[r0:r0 + cnt] = rng.integers(1, 255, (cnt, WB), dtype=np.uint8)
         row_seg[r0:r0 + cnt] = s
+    rec[:, 8] = row_seg >= 0
     return rec, tile_slot, row_seg
 
 
@@ -35,16 +40,32 @@ def _sides(rng, row_seg, p_right=0.5):
                     2).astype(np.int32)
 
 
+def _plant_sides(rec, side, n_seg):
+    """Write the wanted side of every real row into feature 0's bin byte
+    (bin 0 = left, 1 = right) and return the run records that split every
+    segment on it (threshold 0): the move then derives exactly ``side``
+    from the records, as it does from a grower's."""
+    rec[:, 9] = side == 1
+    return leafperm.pack_run_records(
+        do=np.ones(n_seg), feature=np.zeros(n_seg), thresh=np.zeros(n_seg))
+
+
+def _move(rec, tile_slot, run_rec, n_out_tiles):
+    out, base_l, base_r = leafperm.move_level(
+        jnp.asarray(rec), jnp.asarray(tile_slot), jnp.asarray(run_rec),
+        n_out_tiles=n_out_tiles, bin_dtype=np.uint8)
+    return np.asarray(out), base_l, base_r
+
+
 def _run_level(rec, tile_slot, side, n_seg):
-    pos, dstl, dstr, base_l, base_r, n_out = leafperm.level_moves(
-        jnp.asarray(tile_slot), jnp.asarray(side), n_seg)
+    run_rec = _plant_sides(rec, side, n_seg)
     bound = leafperm.tiles_bound(rec.shape[0], n_seg)
-    assert int(n_out) <= bound, (int(n_out), bound)
-    got = np.asarray(leafperm.permute_records(
-        jnp.asarray(rec), pos, dstl, dstr, bound))
+    got, _, base_r = _move(rec, tile_slot, run_rec, bound)
+    n_out = int(base_r[-1]) + 1
+    assert n_out <= bound, (n_out, bound)
     want, ts_new, rs_new = leafperm.permute_records_np(
         rec, tile_slot, side, n_seg, bound)
-    return got, want, ts_new, rs_new, int(n_out)
+    return got, want, ts_new, rs_new, n_out
 
 
 @pytest.mark.parametrize("seg_counts,p_right", [
@@ -62,6 +83,166 @@ def test_permute_matches_oracle(seg_counts, p_right):
     np.testing.assert_array_equal(got[: n_out * T], want[: n_out * T])
 
 
+def _record_layout(rng, seg_counts, F, B, dtype, extra_tiles=0):
+    """A tile-aligned layout of REAL layout records (make_layout_records
+    of random bins in [0, B)), contiguous per segment, plus
+    ``extra_tiles`` all-sentinel tiles absorbed into the last segment."""
+    N = int(sum(seg_counts))
+    Xb = rng.integers(0, B, (N, F)).astype(dtype)
+    rec_nat = np.asarray(leafperm.make_layout_records(
+        jnp.asarray(Xb), jnp.asarray(rng.normal(size=N).astype(np.float32)),
+        jnp.asarray(rng.uniform(0.1, 1, N).astype(np.float32))))
+    lt = np.maximum(-(-np.asarray(seg_counts) // T), 1)
+    lt[-1] += extra_tiles
+    base = np.concatenate([[0], np.cumsum(lt)])
+    rec = np.zeros((int(base[-1]) * T, WB), np.uint8)
+    off = np.concatenate([[0], np.cumsum(seg_counts)])
+    for s, cnt in enumerate(seg_counts):
+        rec[base[s] * T: base[s] * T + cnt] = rec_nat[off[s]: off[s + 1]]
+    tile_run = np.repeat(np.arange(len(seg_counts)), lt).astype(np.int32)
+    return rec, tile_run
+
+
+def _assert_move_matches_oracle(rec, tile_run, run_rec, catmask=None,
+                                n_out_tiles=None, **kw):
+    """The fused move against permute_records_np with the sides computed
+    in numpy from the per-tile split records: the WHOLE buffer, bitwise."""
+    P = run_rec.shape[0]
+    bound = n_out_tiles or leafperm.tiles_bound(rec.shape[0], P)
+    side = leafperm.layout_sides_np(rec, tile_run, run_rec, catmask, **kw)
+    out, base_l, base_r = leafperm.move_level(
+        jnp.asarray(rec), jnp.asarray(tile_run), jnp.asarray(run_rec),
+        None if catmask is None else jnp.asarray(catmask),
+        n_out_tiles=bound, **kw)
+    want, ts_new, _ = leafperm.permute_records_np(
+        rec, tile_run, side, P, bound)
+    np.testing.assert_array_equal(np.asarray(out), want)
+    return want, ts_new, side
+
+
+def _case_numeric(rng):
+    rec, tr = _record_layout(rng, [700, 3, 1200, 0, 513], 28, 256, np.uint8)
+    rr = leafperm.pack_run_records(
+        do=np.ones(5), feature=[0, 27, 13, 5, 1],
+        thresh=[128, 0, 255, 7, 60])
+    _, _, side = _assert_move_matches_oracle(rec, tr, rr, bin_dtype=np.uint8)
+    assert (side == 0).any() and (side == 1).any()
+
+
+def _case_learn_missing(rng):
+    # bin 0 is "missing": it follows the run's default-left bit whatever
+    # the threshold — one run of each default, thresholds that keep bin 0
+    # on the left of the plain rule
+    rec, tr = _record_layout(rng, [900, 900], 6, 8, np.uint8)
+    rr = leafperm.pack_run_records(
+        do=[1, 1], feature=[2, 4], thresh=[3, 3], dleft=[1, 0])
+    _, _, side = _assert_move_matches_oracle(
+        rec, tr, rr, bin_dtype=np.uint8, learn_missing=True)
+    bin0 = np.stack([rec[: 1024, 9 + 2], rec[1024:, 9 + 4]]) == 0
+    valid = np.stack([rec[: 1024, 8], rec[1024:, 8]]) == 1
+    s2 = side.reshape(2, 1024)
+    assert (s2[0][bin0[0] & valid[0]] == 0).all()     # default left
+    assert (s2[1][bin0[1] & valid[1]] == 1).all()     # default right
+    assert (bin0 & valid).sum() > 50
+    # and the flag off: bin 0 is an ordinary bin, left of threshold 3
+    _, _, side = _assert_move_matches_oracle(rec, tr, rr, bin_dtype=np.uint8)
+    assert (side.reshape(2, 1024)[1][bin0[1] & valid[1]] == 0).all()
+
+
+def _case_categorical(rng):
+    # run 0 numeric, runs 1-2 route by their bitset rows; 200 bins, so
+    # the mask pads to the next lane multiple inside move_level
+    B = 200
+    rec, tr = _record_layout(rng, [600, 1100, 300], 9, B, np.uint8)
+    rr = leafperm.pack_run_records(
+        do=[1, 1, 1], feature=[1, 8, 3], thresh=[90, 0, 0],
+        is_cat=[0, 1, 1], dleft=[0, 1, 1])
+    cm = rng.random((3, B)) < 0.5
+    for lm in (False, True):
+        _, _, side = _assert_move_matches_oracle(
+            rec, tr, rr, cm, bin_dtype=np.uint8, learn_missing=lm)
+    bins1 = rec[1024: 1024 + 1100, 9 + 8]
+    np.testing.assert_array_equal(side[1024: 1024 + 1100] == 0, cm[1][bins1])
+
+
+def _case_u16(rng):
+    # bins past 255 live in two record bytes (little-endian); thresholds
+    # on both sides of the byte boundary
+    rec, tr = _record_layout(rng, [800, 800, 500], 20, 1000, np.uint16)
+    rr = np.array(leafperm.pack_run_records(
+        do=[1, 1, 1], feature=[0, 19, 7], thresh=[255, 256, 700],
+        dleft=[1, 0, 1]))
+    cm = rng.random((3, 1000)) < 0.3
+    _assert_move_matches_oracle(rec, tr, rr, bin_dtype=np.uint16)
+    rr[2, 0] |= np.uint32(1) << 29                   # run 2 categorical
+    _assert_move_matches_oracle(rec, tr, rr, cm, bin_dtype=np.uint16,
+                                learn_missing=True)
+
+
+def _case_pass_through_and_dead(rng):
+    # run 1 does not split (do = 0: every valid row left, whatever its
+    # other bits say); run 3 is DEAD (a zero record) and owns only
+    # absorbed all-sentinel tiles, as advance_runs leaves them
+    rec, tr = _record_layout(rng, [700, 900, 300], 5, 64, np.uint8,
+                             extra_tiles=3)
+    tr[-2:] = 3
+    rr = leafperm.pack_run_records(
+        do=[1, 0, 1, 0], feature=[0, 1, 2, 0], thresh=[30, 10, 50, 0],
+        dleft=[0, 1, 0, 0])
+    _, _, side = _assert_move_matches_oracle(rec, tr, rr, bin_dtype=np.uint8)
+    run_of = np.repeat(tr, T)
+    assert set(side[run_of == 1]) == {0, 2}
+    assert set(side[run_of == 3]) == {2}
+
+
+def _case_bagging_root(rng):
+    # level 0 of a bagged tree: the natural-order buffer is the layout,
+    # out-of-bag rows carry flag 0 and are dropped by this first move
+    N, F, L = 3000, 12, 8
+    Xb = rng.integers(0, 256, (N, F)).astype(np.uint8)
+    bag = rng.random(N) < 0.7
+    rec_nat = leafperm.make_layout_records(
+        jnp.asarray(Xb), jnp.asarray(rng.normal(size=N).astype(np.float32)),
+        jnp.asarray(np.ones(N, np.float32)), valid=jnp.asarray(bag))
+    n_buf = leafperm.wired_tiles_bound(-(-N // T), L)
+    rec, tr, _ = leafperm.natural_root_layout(rec_nat, L, n_buf)
+    rr = leafperm.pack_run_records(
+        do=np.arange(L) == 0, feature=np.full(L, 5), thresh=np.full(L, 99))
+    want, _, side = _assert_move_matches_oracle(
+        np.asarray(rec), np.asarray(tr), rr, n_out_tiles=n_buf,
+        bin_dtype=np.uint8)
+    assert (side[:N][~bag] == 2).all()
+    assert int((want[:, 8] == 1).sum()) == int(bag.sum())
+    assert int((side == 1).sum()) == int((bag & (Xb[:, 5] > 99)).sum())
+
+
+def _case_three_level_chain(rng):
+    # each level's output layout (the oracle's maps) feeds the next move,
+    # with fresh features and thresholds; rows are never lost or doubled
+    rec, tr = _record_layout(rng, [5000, 2000], 28, 256, np.uint8)
+    n_rows = int((rec[:, 8] == 1).sum())
+    P = 2
+    for level in range(3):
+        rr = leafperm.pack_run_records(
+            do=np.arange(P) % 3 != 2, feature=rng.integers(0, 28, P),
+            thresh=rng.integers(60, 200, P))
+        want, ts_new, _ = _assert_move_matches_oracle(
+            rec, tr, rr, bin_dtype=np.uint8)
+        rec, tr, P = want, ts_new.astype(np.int32), 2 * P
+        assert int((rec[:, 8] == 1).sum()) == n_rows, level
+
+
+@pytest.mark.parametrize("case", [
+    _case_numeric, _case_learn_missing, _case_categorical, _case_u16,
+    _case_pass_through_and_dead, _case_bagging_root,
+    _case_three_level_chain], ids=lambda f: f.__name__[6:])
+def test_fused_move_matches_oracle(case):
+    """The level move as the growers call it — sides, ranks and
+    destinations derived inside the kernels from per-tile split records —
+    is byte-for-byte the oracle's buffer."""
+    case(np.random.default_rng(11))
+
+
 def test_multi_level_chain():
     """Three refinement levels keep every real record exactly once, all
     pads zero, and the kernel bitwise-equal to the oracle at each level
@@ -69,7 +250,13 @@ def test_multi_level_chain():
     exact bookkeeping a grower integration would)."""
     rng = np.random.default_rng(7)
     rec, tile_slot, row_seg = _mk_layout(rng, [5000, 2000])
-    orig = {bytes(r) for r in rec if r.any()}
+
+    def ident(rec):
+        # a record's identity: every byte but the planted side (byte 9)
+        keep = np.delete(rec, 9, axis=1)
+        return {bytes(r) for r in keep if r.any()}
+
+    orig = ident(rec)
     n_seg = 2
     for level in range(3):
         side = _sides(rng, row_seg, 0.4)
@@ -80,7 +267,7 @@ def test_multi_level_chain():
         tile_slot = ts_new[: n_out].astype(np.int32)
         row_seg = rs_new[: n_out * T].astype(np.int32)
         n_seg = 2 * n_seg
-        assert {bytes(r) for r in rec if r.any()} == orig, \
+        assert ident(rec) == orig, \
             f"level {level}: record set changed"
         assert not rec[row_seg < 0].any(), f"level {level}: nonzero pads"
 
@@ -113,8 +300,10 @@ def test_alignment_of_all_writes():
     rng = np.random.default_rng(9)
     rec, tile_slot, row_seg = _mk_layout(rng, [700, 3, 900])
     side = _sides(rng, row_seg, 0.37)
-    pos, dstl, dstr, _, _, _ = leafperm.level_moves(
-        jnp.asarray(tile_slot), jnp.asarray(side), 3)
+    counts = np.stack([(side.reshape(-1, T) == 0).sum(1),
+                       (side.reshape(-1, T) == 1).sum(1)], axis=1)
+    dstl, dstr, _, _, _ = leafperm.level_moves(
+        jnp.asarray(tile_slot), jnp.asarray(counts, jnp.int32), 3)
     assert (np.asarray(dstl) % leafperm._ALIGN == 0).all()
     assert (np.asarray(dstr) % leafperm._ALIGN == 0).all()
 
@@ -122,13 +311,12 @@ def test_alignment_of_all_writes():
 def test_wired_level_preserves_plan_order():
     """INTEGRATION contract (the wired deep phase rides on this, not just
     the kernel): after the handoff conversion (initial_layout) and one
-    full wired level (level_moves -> permute_records -> advance_runs),
+    full wired level (move_level -> advance_runs),
     every child segment holds its rows in the SAME stable row-id order
     the aligned tile plan would produce for that child's selection — the
     per-slot order convention shared by every histogram path."""
     rng = np.random.default_rng(33)
     N, L = 5000, 8
-    WB = leafperm._REC_WB
     slot_of = rng.integers(0, 4, N).astype(np.int32)   # slots 0..3 live
     bag = rng.random(N) < 0.8
     # records tagged with the row id so order is observable
@@ -136,8 +324,12 @@ def test_wired_level_preserves_plan_order():
     rec_nat[:, :4] = np.arange(1, N + 1, dtype=np.uint32).view(
         np.uint8).reshape(N, 4)
     rec_nat[:, 8] = 1                                  # valid flag
-
-    import jax.numpy as jnp
+    # one level: slots 0 and 2 split (right children -> slots 4, 5) on
+    # feature 0's bin, planted per row: 1 = goes right
+    u = rng.random(N)
+    go_right = {0: u < 0.5, 2: u < 0.3}
+    rec_nat[:, 9] = np.where(slot_of == 0, go_right[0],
+                             np.where(slot_of == 2, go_right[2], 1))
 
     n_buf = leafperm.wired_tiles_bound(-(-N // T), L)
     sel = np.where(bag, slot_of, L).astype(np.int32)
@@ -147,26 +339,12 @@ def test_wired_level_preserves_plan_order():
         jnp.asarray(rec_nat), jnp.asarray(sel), jnp.asarray(live), L, n_buf)
     assert [int(run_slot[r]) for r in range(4)] == [0, 1, 2, 3]
 
-    # one level: slots 0 and 2 split (right children -> slots 4, 5)
-    thr = 0.5
-    u = rng.random(N)
-    go_right = {0: u < thr, 2: u < 0.3}
-    row_run = np.repeat(np.asarray(tile_run), T)
-    rs_lay = np.asarray(run_slot)[row_run]
-    tags_lay = np.asarray(rec_lay)[:, :4].copy().view(np.uint32).ravel()
-    valid_lay = np.asarray(rec_lay)[:, 8] == 1
-    side = np.full(n_buf * T, 2, np.int32)
-    for i in np.nonzero(valid_lay)[0]:
-        s = rs_lay[i]
-        rid = int(tags_lay[i]) - 1
-        if s in go_right:
-            side[i] = 1 if go_right[s][rid] else 0
-        else:
-            side[i] = 0
-    pos, dstl, dstr, base_l, base_r, _ = leafperm.level_moves(
-        jnp.asarray(tile_run), jnp.asarray(side), L)
-    out = np.asarray(leafperm.permute_records(
-        rec_lay, pos, dstl, dstr, n_buf))
+    # runs 1 and 3 pass through (their bin-1 rows stay left all the same)
+    run_rec = leafperm.pack_run_records(
+        do=np.isin(np.arange(L), [0, 2]), feature=np.zeros(L),
+        thresh=np.zeros(L))
+    out, base_l, base_r = _move(np.asarray(rec_lay), np.asarray(tile_run),
+                                run_rec, n_buf)
     run_do = np.zeros(L, bool)
     run_do[[0, 2]] = True
     run_right = np.zeros(L, np.int32)
@@ -222,22 +400,13 @@ def test_hist_from_layout_post_permute_vs_plan():
     rec_lay, tile_run, run_slot = leafperm.initial_layout(
         rec_nat, jnp.asarray(slot_of), jnp.asarray(live), L, n_buf)
 
-    # split slot 0 -> (0, 2); slot 1 passes through
-    u = rng.random(N)
-    right = (slot_of == 0) & (u < 0.45)
-    row_run = np.repeat(np.asarray(tile_run), T)
-    rs_lay = np.asarray(run_slot)[row_run]
-    valid_lay = np.asarray(rec_lay)[:, 8] == 1
-    # recover row ids via the g bytes (unique floats) to map sides
-    gl = np.asarray(rec_lay)[:, 0:4].copy().view(np.float32).ravel()
-    order = {float(v): i for i, v in enumerate(g)}
-    side = np.full(n_buf * T, 2, np.int32)
-    for i in np.nonzero(valid_lay)[0]:
-        rid = order[float(gl[i])]
-        side[i] = 1 if (rs_lay[i] == 0 and right[rid]) else 0
-    pos, dstl, dstr, base_l, base_r, _ = leafperm.level_moves(
-        jnp.asarray(tile_run), jnp.asarray(side), L)
-    out = leafperm.permute_records(rec_lay, pos, dstl, dstr, n_buf)
+    # split slot 0 -> (0, 2) on feature 3 at bin 35 (~45% go right);
+    # slot 1 passes through
+    right = (slot_of == 0) & (Xb[:, 3] > 35)
+    run_rec = leafperm.pack_run_records(
+        do=np.arange(L) == 0, feature=np.full(L, 3), thresh=np.full(L, 35))
+    out, base_l, base_r = leafperm.move_level(
+        rec_lay, tile_run, jnp.asarray(run_rec), bin_dtype=np.uint8)
 
     # children: left of 0 (=slot 0), right of 0 (new), left of 1 (pass)
     lt_l = np.asarray(base_l[1:] - base_l[:-1])

@@ -124,10 +124,14 @@ def test_the_three_kernels_carry_their_names():
         Xt, sds((2 * T,), np.float32), sds((2 * T,), np.float32),
         sds((2 * T,), np.int32)) == ["build_hist_nat"]
     Tl = leafperm._TILE_ROWS
+    # the layout's kernels all carry "permute_records" in their names:
+    # perm_time_share matches that substring, so the counting pass counts
+    # as the layout's kernel time and not as its XLA bookkeeping
     assert _pallas_names(
-        lambda r, p, a, b: leafperm.permute_records(r, p, a, b, 4, platform="cpu"),
-        sds((2 * Tl, leafperm._REC_WB), np.uint8), sds((2, 2, Tl), np.int32),
-        sds((2,), np.int32), sds((2,), np.int32)) == ["permute_records"]
+        lambda r, t, rr: leafperm.move_level(r, t, rr, bin_dtype=np.uint8,
+                                             platform="cpu"),
+        sds((2 * Tl, leafperm._REC_WB), np.uint8), sds((2,), np.int32),
+        sds((4, 2), np.uint32)) == ["permute_records_count", "permute_records"]
 
 
 # the parent commit's goldens for the arms that run no Pallas kernel: scopes
