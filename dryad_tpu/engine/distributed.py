@@ -228,6 +228,9 @@ def grow_sharded(params: Params, total_bins: int, has_cat: bool,
     mode = (hist_reduce_resolved(params, Xb.shape[1], int(total_bins),
                                  mesh.devices.size)
             if level_sync else "fused")
+    if level_sync and params.growth == "leafwise":
+        # the batched grower's two statistics (train._GROW_STATS)
+        tree_specs.update(expanded_splits=rep, selected_splits=rep)
     return jax.shard_map(
         run, mesh=mesh,
         in_specs=(row2, row, row, row, rep, rep) + (rep,) * len(extra),

@@ -118,7 +118,9 @@ def phase_plan(depth_cap: int):
 # levelwise's 512-leaf bound (2L+2 tiles).  At 2^D = 1024 that is ~1.05M
 # zero-sentinel rows per level; past it the mandated movement stops being
 # noise for any row count the expansion budget admits, while the
-# recoverable per-level sort+gather stays fixed (~164 ms/level at 10M) —
+# recoverable per-level sort+gather stays fixed (~164 ms/level at 10M
+# when this was written; 296 ms/level at 10M x 28 on a v5e in PR 27:
+# sort 21, three row-index gathers 193, the staged record gather 82) —
 # so deeper caps keep the legacy plan path (a written verdict, not a
 # TODO; the gate cannot consult N — same-program rule).  r23: the cap
 # lives in the policy table ("leafwise_layout"/"max_segments"); this
@@ -610,7 +612,7 @@ def grow_tree_leafwise_batched(
     nd_C_sel = exp_st["nd_C"]
     nd_lo, nd_hi = exp_st["nd_lo"], exp_st["nd_hi"]
 
-    with jax.named_scope("dryad.split_scan"):
+    with jax.named_scope("dryad.select"):
         sel_st = {
             "slot_heap": jnp.zeros((L,), jnp.int32).at[0].set(1),
             "slot_tree": jnp.full((L,), -1, jnp.int32).at[0].set(0),
@@ -678,7 +680,7 @@ def grow_tree_leafwise_batched(
                             lambda st_: do_split(k, s, st_),
                             lambda st_: st_, st)
 
-    with jax.named_scope("dryad.split_scan"):
+    with jax.named_scope("dryad.select"):
         sel_st = jax.lax.fori_loop(0, L - 1, sel_body, sel_st)
 
     # ---- finalize -------------------------------------------------------------
@@ -722,4 +724,8 @@ def grow_tree_leafwise_batched(
         "cover": sel_st["cover"],
         "max_depth": sel_st["max_depth"],
         "row_leaf": row_leaf,
+        # what the expansion grew against what the selection kept (obs
+        # counters dryad_leafwise_{expanded,selected}_splits_total)
+        "expanded_splits": jnp.sum(nd_gain > NEG_INF, dtype=jnp.int32),
+        "selected_splits": (sel_st["num_nodes"] - 1) // 2,
     }

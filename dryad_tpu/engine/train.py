@@ -65,6 +65,9 @@ from dryad_tpu.obs.watchdog import watch_fetch
 
 _TREE_KEYS = ("feature", "threshold", "left", "right", "value", "is_cat",
               "cat_bitset", "gain", "default_left", "cover")
+# per-tree statistics only the batched leaf-wise grower returns; kept in
+# ``out`` beside the trees where that grower runs (see _count_grow_stats)
+_GROW_STATS = ("expanded_splits", "selected_splits")
 
 # widest (features * bins) program the chunked fori wrapper may compile.
 # Round 2 measured Epsilon-shaped (2000 x 256) chunk programs failing
@@ -136,6 +139,9 @@ def _step_body(p, B, has_cat, mesh, platform, learn_missing, out, score, Xb,
         # each row's leaf comes straight out of the grower's partition
         # state — re-traversing 10M rows cost ~5 s/tree (gather-bound)
         leaves = tree.pop("row_leaf")
+    for key in _GROW_STATS:
+        if key in tree and key in out:
+            out[key] = out[key].at[t].set(tree[key])
     with jax.named_scope("dryad.score"):
         if renew_alpha is not None:
             tree = dict(tree, value=_renew_values(
@@ -667,8 +673,12 @@ def _apply_valid_jit(out, t, vXb, vs_col, depth_bound):
     return vs_col + tree["value"][leaves]
 
 
-def _empty_out_device(T: int, M: int, cat_words: int) -> dict:
+def _empty_out_device(T: int, M: int, cat_words: int,
+                      grow_stats: bool = False) -> dict:
+    stats = {key: jnp.zeros((T,), jnp.int32) for key in _GROW_STATS} \
+        if grow_stats else {}
     return {
+        **stats,
         "feature": jnp.full((T, M), -1, jnp.int32),
         "threshold": jnp.zeros((T, M), jnp.int32),
         "left": jnp.zeros((T, M), jnp.int32),
@@ -681,6 +691,26 @@ def _empty_out_device(T: int, M: int, cat_words: int) -> dict:
         "cover": jnp.zeros((T, M), jnp.float32),
         "max_depth": jnp.zeros((T,), jnp.int32),
     }
+
+
+def _grow_stats_of(out, lo: int, hi: int) -> tuple:
+    """Device slices of the batched leaf-wise grower's statistics of trees
+    [lo, hi), for a fetch that is made anyway; () where ``out`` carries
+    none, nothing is new, or nobody counts."""
+    if _GROW_STATS[0] not in out or lo >= hi or not default_registry().enabled:
+        return ()
+    return tuple(out[key][lo:hi] for key in _GROW_STATS)
+
+
+def _count_grow_stats(fetched: tuple) -> None:
+    """Fetched ``_grow_stats_of`` slices into their counters: splits the
+    expansion grew, and splits the best-first selection kept of them."""
+    for key, vals in zip(_GROW_STATS, fetched):
+        default_registry().counter(
+            f"dryad_leafwise_{key}_total",
+            "Splits of the batched leaf-wise grower: expanded (every valid "
+            "split to the depth cap) / selected (the best-first tree's)",
+        ).inc(int(np.asarray(vals).sum()))
 
 
 def _materialize(p, mapper, out, T, init, max_depth_prev, best_iteration,
@@ -896,7 +926,18 @@ def train_device(
                          y=y, renew_alpha=renew_a)
 
     # ---- resume / warm start -------------------------------------------------
-    out = _empty_out_device(T, p.max_nodes, CAT_WORDS)
+    from dryad_tpu.engine import leafwise_fast
+
+    batched_leafwise = (p.growth == "leafwise"
+                        and leafwise_fast.supports(p, F, B, N))
+    out = _empty_out_device(T, p.max_nodes, CAT_WORDS,
+                            grow_stats=batched_leafwise)
+    if batched_leafwise and default_registry().enabled:
+        default_registry().gauge(
+            "dryad_leafwise_depth_cap",
+            "Depth cap of the batched leaf-wise expansion (max_depth, or "
+            "the unbounded_depth=auto policy's)").set(p.max_depth)
+    stats_done = 0      # trees whose grower statistics the counters hold
     start_iter = 0
     max_depth_prev = 0
     prev_trees = None
@@ -925,6 +966,7 @@ def train_device(
             out[key] = out[key].at[: prev.num_total_trees].set(
                 jnp.asarray(prev.tree_arrays()[key]))
         start_iter = prev.num_iterations
+        stats_done = prev.num_total_trees
         max_depth_prev = prev.max_depth_seen
 
     # every valid set is scored ON DEVICE (metrics/device.py); the FIRST
@@ -1069,11 +1111,13 @@ def train_device(
         if p.growth == "depthwise" and p.max_depth > 0:
             passes_est = p.max_depth
         else:
-            from dryad_tpu.engine import leafwise_fast
-
-            if (p.growth == "leafwise"
-                    and leafwise_fast.supports(p, F, B, N)):
+            # batched_leafwise (resolved where ``out`` is made): growth is
+            # leaf-wise and leafwise_fast.supports(p, F, B, N), so grow_any
+            # takes the level-synchronous expansion
+            if batched_leafwise:
                 # batched leaf-wise: one level pass per expansion depth
+                # (10M x 28, 255 leaves, cap 12: the MAC model below says
+                # 4.35 s an iteration, the chip 4.8 s; my chip run, PR 27)
                 passes_est = p.max_depth
             else:
                 passes_est = max(8, p.effective_num_leaves - 1)
@@ -1386,8 +1430,13 @@ def train_device(
                     if chunk_hook is not None:
                         chunk_hook("fetch", it)
                     with span("train.fetch.eval"):
-                        vals = np.asarray(jax.device_get(
-                            eval_buf[host_cnt - len(evs):host_cnt]))
+                        # the grower's statistics ride this fetch
+                        vals, stats = jax.device_get((
+                            eval_buf[host_cnt - len(evs):host_cnt],
+                            _grow_stats_of(out, stats_done, (it + n) * K)))
+                        vals = np.asarray(vals)
+                _count_grow_stats(stats)
+                stats_done = (it + n) * K
                 _, higher0, _ = evaluators[0]
                 val_rows = dict(zip(evs, vals))
                 # user code runs here (a logger, the benchmark's clock): its
@@ -1459,6 +1508,8 @@ def train_device(
             with span("train.fetch.final"):
                 if valids and not sync_eval:
                     flush_chunk_evals(host_cnt)
+                _count_grow_stats(jax.device_get(
+                    _grow_stats_of(out, stats_done, total_iters * K)))
                 booster = _materialize(p, data.mapper, out, total_iters * K,
                                        init, max_depth_prev, best_iteration,
                                        best_value, stale)
@@ -1680,6 +1731,7 @@ def train_device(
             chunk_hook("fetch", T // K)
         with span("train.fetch.final"):
             flush_deferred()
+            _count_grow_stats(jax.device_get(_grow_stats_of(out, stats_done, T)))
 
             # ---- the single end-of-training fetch ----------------------------
             booster = _materialize(p, data.mapper, out, T, init,

@@ -1,5 +1,6 @@
-"""The training program names its own stages (PR 25): seven ``dryad.*``
-scopes in every lowered training program, three named Pallas kernels, host
+"""The training program names its own stages (PR 25; PR 27 the eighth,
+``dryad.select``, the batched leaf-wise grower's best-first replay): eight
+``dryad.*`` scopes in the lowered training programs, three named Pallas kernels, host
 spans that double as profiler annotations, and a compile family of its own
 for what a checkpoint compiles.  Tracing only: nothing here times anything."""
 
@@ -16,8 +17,10 @@ from dryad_tpu.engine import introspect, leafperm, pallas_hist, train
 from dryad_tpu.obs import Registry, set_default_registry
 from dryad_tpu.obs import spans as S
 
-SEVEN = {"dryad.grad", "dryad.hist", "dryad.route", "dryad.layout",
-         "dryad.split_scan", "dryad.score", "dryad.eval"}
+EIGHT = {"dryad.grad", "dryad.hist", "dryad.route", "dryad.layout",
+         "dryad.split_scan", "dryad.select", "dryad.score", "dryad.eval"}
+# what a program of the level-wise grower carries: it selects nothing
+SEVEN = EIGHT - {"dryad.select"}
 BASE = dict(objective="binary", num_trees=4, num_leaves=7, max_depth=3,
             max_bins=32, seed=3, min_data_in_leaf=5, growth="depthwise")
 
@@ -65,28 +68,29 @@ IN_A_STEP = {"dryad.grad", "dryad.hist", "dryad.route", "dryad.split_scan",
 @pytest.mark.parametrize("program,extra,expected", [
     ("_chunk_jit", dict(hist_backend="pallas"), SEVEN),                 # wired layout on
     ("_chunk_jit", dict(hist_backend="pallas", deep_layout="legacy"), ALL_BUT_LAYOUT),
-    ("_chunk_jit", dict(growth="leafwise", hist_backend="pallas"), SEVEN),   # leafwise_fast
-    ("_chunk_jit", dict(growth="leafwise", max_depth=-1), ALL_BUT_LAYOUT),   # grower.py
+    ("_chunk_jit", dict(growth="leafwise", hist_backend="pallas"), EIGHT),   # leafwise_fast, wired
+    # max_depth=-1: the unbounded_depth="auto" cap, leafwise_fast on the plan path
+    ("_chunk_jit", dict(growth="leafwise", max_depth=-1), EIGHT - {"dryad.layout"}),
     ("_step_jit", dict(hist_backend="pallas"), IN_A_STEP | {"dryad.layout"}),
 ], ids=["chunk-wired", "chunk-legacy", "chunk-leafwise-batched",
-        "chunk-leafwise-sequential", "step-wired"])
+        "chunk-leafwise-unbounded", "step-wired"])
 def test_lowered_program_names_its_stages(monkeypatch, sets, program, extra, expected):
     text = _lowered_text(monkeypatch, sets, program, dict(BASE, **extra))
     found = set(re.findall(r"dryad\.[A-Za-z_0-9]+", text))
     assert found == expected
-    assert found <= SEVEN
+    assert found <= EIGHT
 
 
-def test_engine_sources_name_the_seven_and_no_eighth():
+def test_engine_sources_name_the_eight_and_no_ninth():
     """What the acceptance grep reads: every ``named_scope`` under
-    ``dryad_tpu/engine`` takes one of the seven names, and each is used."""
+    ``dryad_tpu/engine`` takes one of the eight names, and each is used."""
     root = os.path.dirname(os.path.abspath(train.__file__))
     used = set()
     for name in os.listdir(root):
         if name.endswith(".py"):
             with open(os.path.join(root, name), encoding="utf-8") as f:
                 used |= set(re.findall(r'named_scope\("([^"]*)"\)', f.read()))
-    assert used == SEVEN
+    assert used == EIGHT
 
 
 def _pallas_names(fn, *args, **kw):
@@ -134,9 +138,13 @@ def test_the_three_kernels_carry_their_names():
         sds((4, 2), np.uint32)) == ["permute_records_count", "permute_records"]
 
 
-# the parent commit's goldens for the arms that run no Pallas kernel: scopes
-# are metadata, so these two programs digest as they did before the scopes
-SEED_DIGESTS = {"renewal_iteration": "4de6ad398110",
+# the goldens, from before the scopes, of the arms that run no Pallas kernel:
+# scopes are metadata, so these two programs digest as they did then.
+# ``renewal_iteration`` grows with leafwise_fast, which since PR 27 also
+# returns its two statistics (expanded_splits, selected_splits): two
+# equations, so 4de6ad398110 became da449f98fd88; ``dryad.select`` itself
+# moved nothing
+SEED_DIGESTS = {"renewal_iteration": "da449f98fd88",
                 "multiclass_shared_roots": "57de8b33dee0"}
 
 
@@ -157,7 +165,7 @@ def test_scopes_add_no_equation(arm):
 
     walk(closed.jaxpr)
     scoped = {s for stack in stacks for s in re.findall(r"dryad\.[a-z_]+", stack)}
-    assert {"dryad.grad", "dryad.hist", "dryad.split_scan", "dryad.score"} <= scoped <= SEVEN
+    assert {"dryad.grad", "dryad.hist", "dryad.split_scan", "dryad.score"} <= scoped <= EIGHT
     digest = jaxpr_audit.canonical_digest(closed)
     assert digest.startswith(SEED_DIGESTS[arm])
     assert load_goldens()["arms"][arm]["digest"] == digest
