@@ -115,16 +115,27 @@ def phase_plan(depth_cap: int):
 # produce one segment per level-D heap node, so the dense run bookkeeping
 # is (2^D,)-wide and level_moves mandates >= 2*2^D + 2 tiles per level
 # (one per run index per region) — the same structural cost class as
-# levelwise's 512-leaf bound (2L+2 tiles).  At 2^D = 1024 that is ~1.05M
-# zero-sentinel rows per level; past it the mandated movement stops being
-# noise for any row count the expansion budget admits, while the
-# recoverable per-level sort+gather stays fixed (~164 ms/level at 10M
-# when this was written; 296 ms/level at 10M x 28 on a v5e in PR 27:
-# sort 21, three row-index gathers 193, the staged record gather 82) —
-# so deeper caps keep the legacy plan path (a written verdict, not a
-# TODO; the gate cannot consult N — same-program rule).  r23: the cap
-# lives in the policy table ("leafwise_layout"/"max_segments"); this
-# name is the compatibility re-export of the committed default.
+# levelwise's 512-leaf bound (2L+2 tiles).  The verdict, re-derived from
+# chip numbers in PR 29 (v5e, 10M x 28, 255 leaves, max_depth=-1 = cap
+# 12; it stopped at 1024 before, argued from ~164 ms/level recoverable):
+#   * recoverable: the plan path's level costs 296-305 ms at 10M (sort
+#     21, three row-index gathers 193, the staged record gather 82),
+#     and build_hist_nat reads all rows at each of levels 0-3 (127 ms);
+#   * mandated: 2^D = 4096 is 8194 tiles of 64 KB a level, 11 ms a
+#     level in the move kernel (1.35 us a tile), and the layout buffer
+#     is wired_tiles_bound(19532, 4096) = 31,689 tiles, 2.08 GB;
+#   * measured: an iteration of the benchmark's leaf-wise cell
+#     4.78 -> 2.99 s of device time, the chunk program's
+#     temporaries 6.30 -> 7.78 GB.
+# So 4096 segments ride the layout.  Caps 13 and 14 (_MAX_FAST_DEPTH)
+# would carry 2.7 and 3.9 GB buffers and no cell measures them: they keep
+# the legacy plan path (a written verdict, not a TODO).  The gate cannot
+# consult N (same-program rule), so a small table at cap 11 or 12 pays
+# the mandated tiles whatever it holds: on 300k rows x 28 at cap 12,
+# 474 ms a tree against 233 for the plan path, both on the host's clock
+# (scripts/smoke_tpu.py --gate, v5e, PR 29).  r23: the cap lives in the
+# policy table ("leafwise_layout"/"max_segments"); this name is the
+# compatibility re-export of the committed default.
 _MAX_WIRED_SEGMENTS = _POLICY_DEFAULTS["leafwise_layout"]["max_segments"]
 
 

@@ -267,8 +267,8 @@ PARITY_CASES: dict = {
         ({"num_leaves": 31, "record_bytes": 37}, "layout"),
     ],
     "leafwise_layout": [
-        ({"max_depth": 10}, "layout"),
-        ({"max_depth": 11}, "legacy"),
+        ({"max_depth": 12}, "layout"),
+        ({"max_depth": 13}, "legacy"),
         ({"max_depth": 1}, "layout"),
         ({"max_depth": 0}, "legacy"),
     ],

@@ -71,8 +71,8 @@ def _deep_layout(v: dict, f: dict) -> str:
 
 @_resolver("leafwise_layout")
 def _leafwise_layout(v: dict, f: dict) -> str:
-    # leafwise_fast's expansion-width cap: 2^D run slots vs the
-    # calibrated mandatory-tile budget (_MAX_WIRED_SEGMENTS, r10)
+    # leafwise_fast's expansion-width cap: 2^D run slots vs the measured
+    # mandatory-tile cost (_MAX_WIRED_SEGMENTS: depth caps to 12, PR 29)
     d = f["max_depth"]
     if not 0 < d or (1 << d) > v["max_segments"]:
         return "legacy"

@@ -59,10 +59,11 @@ GATE_DEFAULTS: dict = {
     # budgets past 512 mandate non-noise empty-segment movement; records
     # past 128 B multiply moved bytes past the recoverable sort+gather
     "deep_layout": {"max_leaves": 512, "max_record_bytes": 128},
-    # leafwise_fast._MAX_WIRED_SEGMENTS (r10): the dense run bookkeeping
-    # mandates >= 2*2^D + 2 tiles per level; past 1024 segments the
-    # mandated movement stops being noise for any admitted row count
-    "leafwise_layout": {"max_segments": 1024},
+    # leafwise_fast._MAX_WIRED_SEGMENTS (r10; 1024 until PR 29): the
+    # dense run bookkeeping mandates >= 2*2^D + 2 tiles per level.  4096
+    # admits depth cap 12, what unbounded depth maps 255 leaves to; the
+    # verdict and its chip numbers are written at that name
+    "leafwise_layout": {"max_segments": 4096},
     # predict.stage_trees "auto" (r21): the packed node-word table when
     # every traversal field fits its limb width, legacy otherwise
     "predict_layout": {"preferred": "packed"},

@@ -235,36 +235,61 @@ def smoke_leafwise_wired_parity():
     leaf-wise wiring's hardware-only risks are its own: heap-node run
     bookkeeping with sentinel HN and run capacity 2^D drive the same DMA
     movement kernel through different scalar prefetch values, which
-    interpret-mode CI cannot vouch for."""
+    interpret-mode CI cannot vouch for.
+
+    Two fixtures: depth 8 (256 run slots), and the benchmark cell's own
+    cap (PR 29): 255 leaves with ``max_depth=-1`` is cap 12, 4096 run
+    slots, the last the ``leafwise_layout`` gate admits, on 300k rows,
+    where the 8194 mandated tiles a level outnumber the table's 586.  A
+    second, warm run of each arm there is timed: what a small table pays
+    for the wired layout at this cap."""
+    import time
+
     import numpy as np
 
     import dryad_tpu as dryad
-    from dryad_tpu.config import make_params
+    from dryad_tpu.config import effective_depth_params, make_params
     from dryad_tpu.datasets import higgs_like
     from dryad_tpu.engine.leafwise_fast import (
         leafwise_layout_supported, supports,
     )
     from dryad_tpu.engine.train import train_device
 
-    X, y = higgs_like(50_000, seed=43)
-    ds = dryad.Dataset(X, y, max_bins=64)
-    base = dict(objective="binary", num_trees=4, num_leaves=128,
-                max_bins=64, growth="leafwise", max_depth=8)
-    p_w = make_params(base)
-    B = int(ds.mapper.total_bins)
-    F = ds.X_binned.shape[1]
-    assert supports(p_w, F, B, ds.X_binned.shape[0]), \
-        "fixture no longer takes the batched expansion"
-    assert leafwise_layout_supported(p_w, F, B, ds.X_binned.dtype.itemsize), \
-        "gate fixture no longer admits the wired leaf-wise path"
-    b_w = train_device(p_w, ds)
-    b_l = train_device(make_params(dict(base, deep_layout="legacy")), ds)
-    for k in ("feature", "threshold", "left", "right", "is_cat"):
-        np.testing.assert_array_equal(
-            b_w.tree_arrays()[k], b_l.tree_arrays()[k],
-            err_msg=f"wired vs legacy leafwise expansion: {k!r}")
-    np.testing.assert_allclose(b_w.value, b_l.value, atol=1e-5)
-    print("leafwise wired expansion: trees bitwise vs legacy on device")
+    for rows, seed, extra, depth, timed in (
+            (50_000, 43, dict(num_leaves=128, max_depth=8), 8, False),
+            (300_000, 53, dict(num_leaves=255, max_depth=-1,
+                               min_child_weight=100.0), 12, True)):
+        X, y = higgs_like(rows, seed=seed)
+        ds = dryad.Dataset(X, y, max_bins=64)
+        base = dict(objective="binary", num_trees=4, max_bins=64,
+                    growth="leafwise", **extra)
+        B = int(ds.mapper.total_bins)
+        F = ds.X_binned.shape[1]
+        p_w = effective_depth_params(make_params(base), F, B, rows)
+        assert p_w.max_depth == depth and supports(p_w, F, B, rows), \
+            "fixture no longer takes the batched expansion"
+        assert leafwise_layout_supported(
+            p_w, F, B, ds.X_binned.dtype.itemsize), \
+            "gate fixture no longer admits the wired leaf-wise path"
+        arms = {}
+        for arm, params in (("wired", base),
+                            ("legacy", dict(base, deep_layout="legacy"))):
+            arms[arm] = train_device(make_params(params), ds)
+            if timed:
+                t0 = time.perf_counter()
+                train_device(make_params(params), ds)
+                ms = (time.perf_counter() - t0) * 1e3 / base["num_trees"]
+                print(f"  leafwise depth cap {depth}, {rows} rows, {arm}: "
+                      f"{ms:.1f} ms a tree (second run, host clock)")
+        b_w, b_l = arms["wired"], arms["legacy"]
+        for k in ("feature", "threshold", "left", "right", "is_cat"):
+            np.testing.assert_array_equal(
+                b_w.tree_arrays()[k], b_l.tree_arrays()[k],
+                err_msg=f"wired vs legacy leafwise expansion, depth cap "
+                        f"{depth}: {k!r}")
+        np.testing.assert_allclose(b_w.value, b_l.value, atol=1e-5)
+    print("leafwise wired expansion: trees bitwise vs legacy on device "
+          "(depth 8; depth cap 12 = 4096 run slots)")
 
 
 def smoke_hist_reduce_parity():

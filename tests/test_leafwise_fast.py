@@ -140,9 +140,9 @@ def test_wired_gate_admits_fixture():
         p.replace(deep_layout="legacy"), 8, 32, 1, "cpu")
     # run-capacity cap: 2^max_depth must fit the dense run bookkeeping
     assert leafwise_layout_supported(
-        _wired_params(num_leaves=512, max_depth=10), 8, 32, 1, "cpu")
+        _wired_params(num_leaves=512, max_depth=12), 8, 32, 1, "cpu")
     assert not leafwise_layout_supported(
-        _wired_params(num_leaves=512, max_depth=11), 8, 32, 1, "cpu")
+        _wired_params(num_leaves=512, max_depth=13), 8, 32, 1, "cpu")
     # CPU 'auto' resolves to XLA -> no tile layout to feed
     assert not leafwise_layout_supported(
         _wired_params(hist_backend="auto"), 8, 32, 1, "cpu")

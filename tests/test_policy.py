@@ -95,10 +95,10 @@ def test_call_sites_straddle_every_threshold():
     assert resolve_backend("auto", platform="cpu") == "xla"
     assert resolve_backend("xla", platform="tpu") == "xla"
 
-    p10 = Params(num_trees=1, max_depth=10, hist_backend="pallas")
-    p11 = Params(num_trees=1, max_depth=11, hist_backend="pallas")
-    assert leafwise_layout_supported(p10, 28, 256, 1, platform="tpu")
-    assert not leafwise_layout_supported(p11, 28, 256, 1, platform="tpu")
+    p12 = Params(num_trees=1, max_depth=12, hist_backend="pallas")
+    p13 = Params(num_trees=1, max_depth=13, hist_backend="pallas")
+    assert leafwise_layout_supported(p12, 28, 256, 1, platform="tpu")
+    assert not leafwise_layout_supported(p13, 28, 256, 1, platform="tpu")
 
     assert SHARDED_MIN_WORK == 32768
     assert RetryPolicy().ch_max_ladder == (8, 4, 2)
