@@ -461,8 +461,8 @@ def main(argv=None) -> int:
         if current_device_kind() is None:
             failed.append("device_kind")
             print("FAIL device_kind: policy.device.current_device_kind() "
-                  "is None — the policy table would resolve on defaults "
-                  "without knowing the device")
+                  "is None — /stats and every artifact stamp would not "
+                  "name the device the gates dispatched for")
         phases = [("parity", phase_parity, ()),
                   ("train", phase_train, ()),
                   ("predict", phase_predict, ("full",)),

@@ -253,17 +253,8 @@ def cmd_profile(args) -> int:
         for name, probe in probes.PROBES.items():
             print(f"{name:20s} {probe.doc}")
         return 0
-    if args.selftest and (args.calibrate or args.check_calib):
-        # the r23 ci.sh gate: seeded CPU table/gates logic, NO probes —
-        # default-table parity with the pre-policy constants, exact
-        # single-gate perturbation flips, round-trip, derive rules
-        from dryad_tpu.policy import calibrate as calib
-
-        return calib.run_selftest(quiet=args.quiet)
     if args.selftest:
         return probes.run_selftest(quiet=args.quiet)
-    if args.calibrate or args.check_calib:
-        return _profile_calibrate(args)
 
     names = args.stage or list(probes.PROBES)
     unknown = [n for n in names if n not in probes.PROBES]
@@ -312,39 +303,6 @@ def cmd_profile(args) -> int:
             print(json.dumps({"profile_trends": report}))
             if args.check_trend and not report["ok"]:
                 return 1
-    return 0
-
-
-def _profile_calibrate(args) -> int:
-    """``profile --calibrate``: A/B-sweep the stage probes per gate and
-    write the refreshed device-keyed table + the stamped CALIB artifact
-    the trend ledger ingests; ``--check-calib`` instead diffs the live
-    sweep's gate resolutions against the committed table (exit 1 on
-    drift, like ``bench_trend --check``; suspect captures report but
-    never fail)."""
-    from dryad_tpu.policy import calibrate as calib
-    from dryad_tpu.policy import table as ptable
-    from dryad_tpu.policy.device import current_device_kind
-
-    kind = current_device_kind()
-    if args.check_calib:
-        report = calib.check_calib(device_kind=kind, rows=args.rows,
-                                   quiet=args.quiet)
-        print(json.dumps({"calib_check": report}))
-        return 0 if report["ok"] else 1
-    devices, artifact = calib.calibrate(device_kind=kind, rows=args.rows,
-                                        quiet=args.quiet)
-    print(json.dumps(artifact))
-    if args.calib_out:
-        ptable.save_table(devices, args.calib_out)
-        if not args.quiet:
-            print(f"calibration table ({len(devices)} device entr"
-                  f"{'y' if len(devices) == 1 else 'ies'}) -> "
-                  f"{args.calib_out}", file=sys.stderr)
-    if args.out:
-        with open(args.out, "w") as f:
-            json.dump(artifact, f, indent=1)
-            f.write("\n")
     return 0
 
 
@@ -849,20 +807,8 @@ def main(argv=None) -> int:
                     help="timed programs per probe (min is the estimator)")
     pf.add_argument("--slots", type=int, default=64,
                     help="segment/slot count P for the per-level stages")
-    pf.add_argument("--out", help="also write the stamped PROFILE (or, "
-                                  "with --calibrate, CALIB) JSON here")
-    pf.add_argument("--calibrate", action="store_true",
-                    help="A/B-sweep the dispatch-gate probes and derive a "
-                         "refreshed device-keyed policy table (r23; with "
-                         "--selftest: the seeded CPU table/gates gate — "
-                         "no probes)")
-    pf.add_argument("--check-calib", action="store_true",
-                    help="diff a live sweep's gate resolutions against the "
-                         "committed policy table; exit 1 on drift (spread-"
-                         "vetoed, like bench_trend --check)")
-    pf.add_argument("--calib-out", default=None,
-                    help="with --calibrate: write the refreshed calibration "
-                         "table JSON here (committed devices + this one)")
+    pf.add_argument("--out",
+                    help="also write the stamped PROFILE JSON here")
     pf.add_argument("--trend-root", default=None,
                     help="compare against the PROFILE_r*.json history in "
                          "this directory (newest-vs-median, spread veto)")

@@ -333,7 +333,7 @@ register(Rule(
 
 
 # ---------------------------------------------------------------------------
-# policy-jax-free (r23) — the calibration table keys dispatch decisions
+# policy-jax-free (r23) — the thresholds dict keys dispatch decisions
 # and must load in the fleet control plane (serve /stats, the supervisor)
 # while a device is wedged; resolvers are pure dict-and-compare code.
 # The ONE sanctioned exception is the lazy best-effort device_kind probe
@@ -345,17 +345,14 @@ def _check_policy_direct(path, src, tree):
         out.append(Violation(
             "policy-jax-free", path, line,
             f"import {mod} in dryad_tpu/policy — gate resolution is "
-            "host-side table lookup and jax-free by lint (r23); the "
-            "calibration SWEEP reaches devices only through "
-            "engine/probes, imported lazily inside calibrate.run_sweep"))
+            "host-side dict lookup and jax-free by lint (r23)"))
     for node in ast.walk(tree):
         if isinstance(node, ast.Attribute) and node.attr in (
                 "device_get", "addressable_data", "asnumpy"):
             out.append(Violation(
                 "policy-jax-free", path, node.lineno,
                 f".{node.attr} in dryad_tpu/policy — a gate resolver "
-                "must never touch device buffers; walls arrive as floats "
-                "from the probe harness"))
+                "must never touch device buffers"))
     return out
 
 
@@ -368,8 +365,7 @@ def _tree_check_policy(sources, tree):
         out.append(Violation(
             "policy-jax-free", _module_rel(entry, tree), 1,
             "transitive jax import: " + " -> ".join(chain)
-            + " — importing dryad_tpu.policy must not pull in jax (r23; "
-            "probe/trends imports stay lazy inside the sweep functions)"))
+            + " — importing dryad_tpu.policy must not pull in jax (r23)"))
     return out
 
 
@@ -384,13 +380,13 @@ register(Rule(
 
 # ---------------------------------------------------------------------------
 # gate-through-policy (r23) — the dispatch-gate functions must read their
-# thresholds from the policy calibration table, never from re-inlined
+# thresholds from policy/gates.py's thresholds dict, never from re-inlined
 # literals: a constant hand-edited at ONE call site silently forks the
-# gate from the committed table (and from every other caller), which is
+# gate from the dict (and from every other caller), which is
 # exactly the two-copy drift select_bins' r5 review caught.  Structural
 # encoding widths stay at the call sites as NAMED module constants
 # (levelwise._MAX_PACKED_BINS) — the rule flags folded int literals at or
-# past 512 (the smallest calibrated threshold) inside the known gate
+# past 512 (the smallest numeric threshold) inside the known gate
 # functions only, so shape arithmetic like ``9 + F * itemsize`` passes.
 
 _GATE_FUNCTIONS = {
@@ -450,13 +446,10 @@ def _check_gate_literals(path, src, tree):
                     out.append(Violation(
                         "gate-through-policy", path, node.lineno,
                         f"literal {folded} inside gate function "
-                        f"{fn.name}() — dispatch thresholds live in the "
-                        "policy calibration table "
-                        "(policy/table.GATE_DEFAULTS + goldens/"
-                        "calibration.json); resolve through "
-                        "policy.gates.resolve()/gate_value() so a device "
-                        "entry can move them and the committed default "
-                        "stays the single source"))
+                        f"{fn.name}() — dispatch thresholds live in "
+                        "policy/gates.py's thresholds dict; resolve "
+                        "through policy.gates.resolve()/gate_value() so "
+                        "the dict stays the single source"))
                 continue
             stack.extend(ast.iter_child_nodes(node))
     return out
@@ -464,7 +457,7 @@ def _check_gate_literals(path, src, tree):
 
 register(Rule(
     name="gate-through-policy",
-    doc="dispatch-gate functions read thresholds from the policy table, "
+    doc="dispatch-gate functions read thresholds from policy/gates.py, "
         "not re-inlined literals",
     targets=("dryad_tpu/config.py", "dryad_tpu/engine/levelwise.py",
              "dryad_tpu/engine/leafwise_fast.py",

@@ -14,8 +14,6 @@ import dataclasses
 import json
 from typing import Any, Mapping, Sequence
 
-from dryad_tpu.policy.table import GATE_DEFAULTS as _POLICY_DEFAULTS
-
 OBJECTIVES = ("binary", "multiclass", "regression", "lambdarank",
               "l1", "huber", "fair", "quantile", "poisson")
 GROWTH_POLICIES = ("leafwise", "depthwise")
@@ -192,8 +190,8 @@ class Params:
     # tiny per-shard best-split records with one all-gather per level
     # (LightGBM's reduce-scatter data-parallel mode) — at Epsilon shape
     # (F=2000, B=256) the per-device reduced payload shrinks ~n-fold.
-    # "auto" picks "feature" iff F * B * bin_bytes clears
-    # HIST_REDUCE_WIDE_BYTES AND more than one shard participates — a pure
+    # "auto" picks "feature" iff F * B * bin_bytes clears the hist_reduce
+    # gate's wide_bytes AND more than one shard participates — a pure
     # function of (params, feature/bin shape, shard count), never of rows
     # (CLAUDE.md same-program rule).  An explicit "feature" at 1 shard
     # runs the degenerate full-slice program, so near-tie argmaxes can
@@ -381,27 +379,18 @@ MAX_FAST_DEPTH = 14
 LEAFWISE_TOTAL_BYTES_BUDGET = 12 << 30
 
 
-# Wide-shape threshold for hist_reduce="auto": the feature-parallel
-# reduction pays one combine all-gather per level, so it only wins where
-# the per-slot histogram column is big — F * B * bin_bytes at or past
-# 256 KB (Epsilon's 2000 x 256 u8 = 500 KB clears it; Higgs' 28 x 256 =
-# 7 KB stays fused).  bin_bytes is the binned-matrix itemsize (1 below
-# 257 bins, else 2) so the gate is jax-free and shard-count aware only
-# through its explicit argument.  r23: the constant lives in the policy
-# calibration table (policy/table.GATE_DEFAULTS["hist_reduce"]); this
-# name is the compatibility re-export of the committed default.
-HIST_REDUCE_WIDE_BYTES = _POLICY_DEFAULTS["hist_reduce"]["wide_bytes"]
-
-
 def hist_reduce_resolved(p: Params, num_features: int, total_bins: int,
                          n_shards: int) -> str:
     """The ONE hist_reduce gate — shared by both level-synchronous growers
     AND train._comm_stats so the observability accounting can never drift
     from the program choice (the nat-gate/phase-plan precedent, ADVICE
     r4).  A pure function of (params, feature/bin shape, shard count) —
-    NEVER of the row count (CLAUDE.md same-program rule).  r23: the
-    threshold comes from the device-keyed policy table; the committed
-    default resolves bitwise-identically to the pre-r23 constant."""
+    NEVER of the row count (CLAUDE.md same-program rule).  "auto": the
+    feature-parallel reduction pays one combine all-gather per level, so
+    it only wins where the per-slot histogram column is big — F * B *
+    bin_bytes at or past the gate's wide_bytes (policy/gates.py: 256 KB;
+    Epsilon's 2000 x 256 u8 = 500 KB clears it, Higgs' 28 x 256 = 7 KB
+    stays fused)."""
     if p.hist_reduce != "auto":
         return p.hist_reduce
     from dryad_tpu.policy.gates import resolve

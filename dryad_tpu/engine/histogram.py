@@ -32,15 +32,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from dryad_tpu.policy.table import GATE_DEFAULTS as _POLICY_DEFAULTS
-
-
-# r23: the platform list lives in the policy table
-# ("hist_backend"/"pallas_platforms"); this name is the compatibility
-# re-export of the committed default.
-_PALLAS_PLATFORMS = tuple(
-    _POLICY_DEFAULTS["hist_backend"]["pallas_platforms"])
-
 
 def resolve_backend(backend: str, *, segmented: bool = False,
                     platform: str | None = None) -> str:

@@ -72,7 +72,7 @@ from dryad_tpu.engine.grower import (
 from dryad_tpu.engine import levelwise
 from dryad_tpu.engine.histogram import build_hist, build_hist_segmented
 from dryad_tpu.engine.split import NEG_INF, find_best_split
-from dryad_tpu.policy.table import GATE_DEFAULTS as _POLICY_DEFAULTS
+from dryad_tpu.policy.gates import gate_value
 
 from dryad_tpu.config import (  # noqa: F401  (re-exported API)
     LEAFWISE_HIST_BYTES_BUDGET as _HIST_BYTES_BUDGET,
@@ -133,10 +133,10 @@ def phase_plan(depth_cap: int):
 # consult N (same-program rule), so a small table at cap 11 or 12 pays
 # the mandated tiles whatever it holds: on 300k rows x 28 at cap 12,
 # 474 ms a tree against 233 for the plan path, both on the host's clock
-# (scripts/smoke_tpu.py --gate, v5e, PR 29).  r23: the cap lives in the
-# policy table ("leafwise_layout"/"max_segments"); this name is the
-# compatibility re-export of the committed default.
-_MAX_WIRED_SEGMENTS = _POLICY_DEFAULTS["leafwise_layout"]["max_segments"]
+# (scripts/smoke_tpu.py --gate, v5e, PR 29).  The number is assigned
+# once, in policy/gates.py THRESHOLDS ("leafwise_layout"/"max_segments");
+# this name reads it for the tests and carries the verdict.
+_MAX_WIRED_SEGMENTS = gate_value("leafwise_layout", "max_segments")
 
 
 def leafwise_layout_supported(p: Params, num_features: int, total_bins: int,

@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dryad_tpu.policy.table import GATE_DEFAULTS as _POLICY_DEFAULTS
-
 # ---- packed node-word layout (r21) ----------------------------------------
 # Gather cost on TPU is per-ACCESS, not per-byte (CLAUDE.md measured
 # lowering facts), so the traversal fields of one node are packed into a
@@ -250,18 +248,6 @@ def sharded_accumulate_fn(mesh, depth_bound: int):
         in_specs=(P(), P(AXIS, None), P()),
         out_specs=P(AXIS, None),
     ))
-
-
-# Sharding a predict dispatch pays only once the batch carries real work:
-# below ~32k row-outputs the per-shard blocks are too small to beat the
-# single-device program's dispatch cost, and interactive traffic stays on
-# the fast path.  The serving layer exposes this as its default
-# ``sharded_threshold``; callers gate on rows × num_outputs.  r23: the
-# constant lives in the policy table ("predict_sharded"/"min_work");
-# this name is the compatibility re-export of the committed default —
-# serve resolves its live default through gate_value() so a calibrated
-# device entry can move it.
-SHARDED_MIN_WORK = _POLICY_DEFAULTS["predict_sharded"]["min_work"]
 
 
 def predict_binned_sharded(booster, Xb, num_iteration: Optional[int] = None,

@@ -53,15 +53,6 @@ PROFILE_PATTERN = "PROFILE_r*.json"
 STAGE_MS_PREFIX = "stage_ms_"
 STAGE_SPREAD_PREFIX = "stage_spread_"
 
-#: the r23 calibration-sweep artifacts (policy/calibrate.py writes
-#: them): every ``calib_ms_<gate>_<arm>_f<width>`` A/B wall is
-#: lower-better with its spread in the sibling ``calib_spread_*`` field
-#: — same prefix discipline as the stage profiler, so new sweep arms
-#: are trend-tracked with no table edit here
-CALIB_PATTERN = "CALIB_r*.json"
-CALIB_MS_PREFIX = "calib_ms_"
-CALIB_SPREAD_PREFIX = "calib_spread_"
-
 #: r17 fleet-bench per-priority latency percentiles
 #: (``fleet_<priority>_p{50,95,99}_ms_n<replicas>``) — pattern rule like
 #: the stage profiler's, so new priorities/fleet sizes are tracked with
@@ -153,7 +144,6 @@ def _direction(name: str) -> Optional[str]:
     if name in HIGHER_BETTER:
         return "higher_better"
     if (name in LOWER_BETTER or name.startswith(STAGE_MS_PREFIX)
-            or name.startswith(CALIB_MS_PREFIX)
             or _FLEET_PCT_RE.match(name)):
         return "lower_better"
     return None
@@ -163,8 +153,6 @@ def _spread_fields_of(name: str) -> tuple:
     """The newest point's spread fields vouching for ``name``."""
     if name.startswith(STAGE_MS_PREFIX):
         return (STAGE_SPREAD_PREFIX + name[len(STAGE_MS_PREFIX):],)
-    if name.startswith(CALIB_MS_PREFIX):
-        return (CALIB_SPREAD_PREFIX + name[len(CALIB_MS_PREFIX):],)
     m = _FLEET_PCT_RE.match(name)
     if m:
         # percentile capture quality rides that fleet size's arm spread
@@ -180,7 +168,7 @@ def _extract_metrics(doc: dict) -> Optional[dict]:
     if isinstance(doc.get("parsed"), dict):
         return doc["parsed"]
     if ("metric" in doc or "bench" in doc or "schema_version" in doc
-            or "profile_schema" in doc or "calib_schema" in doc):
+            or "profile_schema" in doc):
         return doc
     return None
 
@@ -342,13 +330,9 @@ def stats_provider(root: str = ".", tolerance: float = DEFAULT_TOLERANCE):
                 "ok": True, "n_points": 0, "newest": None, "metrics": {}}
             prof = load_history(root, pattern=PROFILE_PATTERN)
             cache["profile"] = compare(prof, tolerance) if prof else None
-            cal = load_history(root, pattern=CALIB_PATTERN)
-            cache["calib"] = compare(cal, tolerance) if cal else None
         out = {"bench_trends": cache["report"]}
         if cache["profile"] is not None:
             out["profile_trends"] = cache["profile"]
-        if cache["calib"] is not None:
-            out["calib_trends"] = cache["calib"]
         return out
 
     return provide

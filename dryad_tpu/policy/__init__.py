@@ -1,61 +1,41 @@
-"""Self-tuning dispatch: the device-keyed calibration policy layer (r23).
+"""Dispatch gates: every threshold that picks between pre-audited program
+arms, and the comparison that uses it, in one jax-free place.
 
-Every hand-tuned dispatch gate in the engine/serve/resilience stack —
-``partition_prefers_reduce``'s 4 KB/row, ``hist_reduce_resolved``'s
-256 KB wide-shape gate, ``deep_layout_supported``'s leaf/record caps,
-serve's ``SHARDED_MIN_WORK``, hist backend "auto",
-``Params.predict_layout="auto"``, the resilience chunk-cap ladder —
-routes through ONE entry here (``gates.resolve``), backed by a
-committed, schema-versioned, device-keyed calibration table
-(``goldens/calibration.json``).  The committed defaults ARE the
-hand-tuned constants, and an empty/missing/unknown device key resolves
-bitwise-identically to the pre-policy behavior — so pre-existing saved
-models, the jaxpr program digests, and every parity test are untouched
-by construction.  ``calibrate.py`` (CLI: ``python -m dryad_tpu profile
---calibrate``) refreshes the table from the liveness-proven stage
-probes; ``--check-calib`` diffs a live sweep against the committed
-table the way ``bench_trend --check`` does.
+``gates.THRESHOLDS`` is the single assignment of each constant
+(``partition``'s 4 KB/row, ``hist_reduce``'s 256 KB wide-shape gate,
+``deep_layout``'s leaf/record caps, ``leafwise_layout``'s run slots,
+serve's sharded-dispatch work floor, hist backend "auto",
+``Params.predict_layout="auto"``, the resilience chunk-cap ladder);
+``gates.resolve`` is the one entry the engine, serve and resilience call
+sites route through, and it records each choice (``decisions()``, the
+``dryad_policy_choice{gate,arm}`` gauge, serve's ``/stats``).  Nothing
+overrides the dict at run time: a gate moves by an edit to it, argued
+from a chip run.
 
-The hard invariant, machine-pinned by ``analysis --ci``: policy flips
-never change traced-program semantics — only which PRE-AUDITED arm
+The hard invariant, machine-pinned by ``analysis --ci``: a gate never
+changes traced-program semantics — only which PRE-AUDITED arm
 dispatches.  The package is jax-free by lint (``policy-jax-free``,
 transitive): gate resolution must work in the fleet control plane while
-a device is wedged.  The two sanctioned jax boundaries are lazy and
-outside the resolution path: ``device.current_device_kind`` (waived
-probe) and the calibration sweep's probe imports (an explicitly
-device-facing operation, like fleet's replica subprocesses).
+a device is wedged.  The one sanctioned jax boundary is lazy and outside
+the resolution path: ``device.current_device_kind`` (waived probe).
 """
 
 from dryad_tpu.policy.device import current_device_kind
 from dryad_tpu.policy.gates import (
+    GATE_NAMES,
+    THRESHOLDS,
     decisions,
     gate_value,
     resolve,
     stats_block,
 )
-from dryad_tpu.policy.table import (
-    GATE_DEFAULTS,
-    GOLDEN_PATH,
-    SCHEMA_VERSION,
-    CalibrationTable,
-    current_table,
-    load_table,
-    reset_cache,
-    save_table,
-)
 
 __all__ = [
-    "CalibrationTable",
-    "GATE_DEFAULTS",
-    "GOLDEN_PATH",
-    "SCHEMA_VERSION",
+    "GATE_NAMES",
+    "THRESHOLDS",
     "current_device_kind",
-    "current_table",
     "decisions",
     "gate_value",
-    "load_table",
-    "reset_cache",
     "resolve",
-    "save_table",
     "stats_block",
 ]

@@ -1,28 +1,60 @@
-"""``resolve(gate, shape_features, device_kind) -> arm`` — the ONE entry.
+"""``resolve(gate, shape_features) -> arm`` — the ONE entry, and the ONE
+home of every dispatch threshold.
 
 Each routed call site keeps its existing signature and calls in with its
-shape features; the threshold CONSTANTS live in the table, the
-COMPARISON SEMANTICS live here, verbatim from the pre-policy gate
-bodies (cited per resolver).  Everything stays a pure function of
-(params, feature/bin shape, shard count) — NEVER of the row count,
-which under shard_map is the local shard and would let 1-shard and
-N-shard runs choose different histogram programs (the CLAUDE.md
-same-program rule).  Every resolution is recorded: ``decisions()`` is
-the /stats block, ``dryad_policy_choice{gate,arm}`` the obs gauge
-(no-ops with obs disabled — the registry owns that contract).
+shape features; the threshold CONSTANTS live in ``THRESHOLDS`` below and
+nowhere else under ``dryad_tpu/``, the COMPARISON SEMANTICS in the
+resolvers beside it.  Everything stays a pure function of (params,
+feature/bin shape, shard count) — NEVER of the row count, which under
+shard_map is the local shard and would let 1-shard and N-shard runs
+choose different histogram programs (the CLAUDE.md same-program rule).
+Every resolution is recorded: ``decisions()`` is the /stats block,
+``dryad_policy_choice{gate,arm}`` the obs gauge (no-ops with obs
+disabled — the registry owns that contract).
 """
 
 from __future__ import annotations
 
 from typing import Callable, Optional
 
-from dryad_tpu.policy import table as _table
 from dryad_tpu.policy.device import current_device_kind
 
-_UNSET = object()
+#: Every dispatch threshold, each assigned here and only here.  Each
+#: entry names its evidence: "chip, PR n" where a chip run decided the
+#: value, else "hand-tuned": set before the chip, and no benchmark cell
+#: straddles it (ROADMAP "Gates without a far side").
+THRESHOLDS: dict = {
+    # levelwise.partition_prefers_reduce: masked reduce over the (N, F)
+    # matrix while F*itemsize <= 4 KB/row, else gather.  Hand-tuned
+    "partition": {"reduce_max_row_bytes": 4096},
+    # config.hist_reduce_resolved: feature-parallel reduction once
+    # F * B * bin_bytes >= 256 KB AND >1 shard participates.  Hand-tuned
+    "hist_reduce": {"wide_bytes": 262144},
+    # histogram.resolve_backend "auto": the Pallas kernel where Mosaic
+    # runs, XLA everywhere else.  Hand-tuned (structural, in effect)
+    "hist_backend": {"pallas_platforms": ["tpu"]},
+    # levelwise.deep_layout_supported: past 512 leaves the empty
+    # segments' mandated tiles stop being noise; past 128 B a record
+    # (leafperm._REC_WB is the structural twin) the moved bytes outgrow
+    # the sort+gather they replace.  Hand-tuned
+    "deep_layout": {"max_leaves": 512, "max_record_bytes": 128},
+    # leafwise_fast's run-slot cap: 4096 admits depth cap 12, what
+    # unbounded depth gives 255 leaves.  Chip, PR 29 (1024 before): the
+    # verdict and its numbers are at leafwise_fast._MAX_WIRED_SEGMENTS
+    "leafwise_layout": {"max_segments": 4096},
+    # predict.stage_trees "auto": the packed node-word table when every
+    # traversal field fits its limb width.  Hand-tuned
+    "predict_layout": {"preferred": "packed"},
+    # serve's default sharded_threshold: below ~32k row-outputs the
+    # per-shard blocks lose to one device's dispatch cost.  Hand-tuned
+    "predict_sharded": {"min_work": 32768},
+    # resilience.RetryPolicy.ch_max_ladder: chunk-cap degradation steps,
+    # widest first, ending on the 2-iteration floor.  Hand-tuned
+    "chunk_cap": {"ladder": [8, 4, 2]},
+}
 
 # gate -> (values, features) -> arm.  Comparison semantics only; every
-# constant comes from the overlaid table values.
+# constant comes from the gate's THRESHOLDS entry.
 _RESOLVERS: dict = {}
 
 
@@ -36,7 +68,7 @@ def _resolver(name: str):
 @_resolver("partition")
 def _partition(v: dict, f: dict) -> str:
     # levelwise.partition_prefers_reduce (r5): masked reduce while the
-    # per-row sequential traffic stays under the calibrated row budget
+    # per-row sequential traffic stays under the row budget
     row_bytes = f["num_features"] * f["itemsize"]
     return "reduce" if row_bytes <= v["reduce_max_row_bytes"] else "gather"
 
@@ -44,7 +76,7 @@ def _partition(v: dict, f: dict) -> str:
 @_resolver("hist_reduce")
 def _hist_reduce(v: dict, f: dict) -> str:
     # config.hist_reduce_resolved (r16).  bin_bytes is the binned-matrix
-    # itemsize (u8 below 257 bins, else u16) — structural, not calibrated
+    # itemsize (u8 below 257 bins, else u16) — structural, no threshold
     bin_bytes = 1 if f["total_bins"] <= 256 else 2
     wide = (f["num_features"] * f["total_bins"] * bin_bytes
             >= v["wide_bytes"])
@@ -59,9 +91,8 @@ def _hist_backend(v: dict, f: dict) -> str:
 
 @_resolver("deep_layout")
 def _deep_layout(v: dict, f: dict) -> str:
-    # levelwise.deep_layout_supported's CALIBRATED caps (the structural
-    # exclusions — backend, packed-word widths, _REC_WB — stay at the
-    # call site; a table can only narrow them, never widen past them)
+    # levelwise.deep_layout_supported's TUNED caps (the structural
+    # exclusions — backend, packed-word widths — stay at the call site)
     if f["num_leaves"] > v["max_leaves"]:
         return "legacy"
     if f["record_bytes"] > v["max_record_bytes"]:
@@ -88,7 +119,8 @@ def _predict_layout(v: dict, f: dict) -> str:
 
 @_resolver("predict_sharded")
 def _predict_sharded(v: dict, f: dict) -> str:
-    # predict.SHARDED_MIN_WORK: rows x num_outputs must carry real work
+    # serve's sharded_threshold default: rows x num_outputs must carry
+    # real work
     return "sharded" if f["work"] >= v["min_work"] else "single"
 
 
@@ -99,7 +131,7 @@ def _chunk_cap(v: dict, f: dict) -> str:
     return "/".join(str(int(s)) for s in v["ladder"])
 
 
-#: the gate catalog (stable order: README table, selftest sweep)
+#: the gate catalog (stable order: the README table's)
 GATE_NAMES = tuple(_RESOLVERS)
 
 #: newest decision per gate: {gate: {"arm", "detail", "count"}}
@@ -108,42 +140,20 @@ _LAST_ARM: dict = {}
 
 
 def resolve(gate: str, shape_features: dict,
-            device_kind=_UNSET, table=None,
             detail: Optional[str] = None) -> str:
-    """Resolve one gate for one shape.  ``device_kind`` defaults to the
-    process's device (``None`` explicitly = committed defaults);
-    ``table`` defaults to the process table (``current_table``)."""
+    """Resolve one gate for one shape against ``THRESHOLDS``."""
     if gate not in _RESOLVERS:
         raise KeyError(f"unknown policy gate {gate!r} "
                        f"(catalog: {', '.join(GATE_NAMES)})")
-    tab = table if table is not None else _table.current_table()
-    values = tab.gate_values(gate, _device_kind_for(tab, device_kind))
-    arm = _RESOLVERS[gate](values, shape_features)
+    arm = _RESOLVERS[gate](THRESHOLDS[gate], shape_features)
     _note(gate, arm, detail)
     return arm
 
 
-def _device_kind_for(tab, device_kind):
-    """Resolve the effective device key WITHOUT waking a device runtime
-    when no table entry could change the answer: the committed table
-    ships only ``_default``, so the common path (fleet control plane,
-    RetryPolicy construction, CLI startup before the CPU-audit env is
-    pinned) must never trigger the lazy jax probe.  Only a table that
-    actually carries device-keyed entries pays the (memoized,
-    best-effort) ``current_device_kind()`` call."""
-    if device_kind is not _UNSET:
-        return device_kind
-    if not any(k != _table.DEFAULT_DEVICE_KEY for k in tab.devices):
-        return None
-    return current_device_kind()
-
-
-def gate_value(gate: str, key: str, device_kind=_UNSET, table=None):
-    """The raw calibrated value behind a gate (serve's threshold default,
-    the resilience ladder) — same overlay as ``resolve``."""
-    tab = table if table is not None else _table.current_table()
-    device_kind = _device_kind_for(tab, device_kind)
-    values = tab.gate_values(gate, device_kind)
+def gate_value(gate: str, key: str):
+    """The raw threshold behind a gate (serve's threshold default, the
+    resilience ladder)."""
+    values = THRESHOLDS[gate]
     if key not in values:
         raise KeyError(f"gate {gate!r} has no value {key!r}")
     v = values[key]
@@ -182,17 +192,10 @@ def reset_decisions() -> None:
 
 
 def stats_block() -> dict:
-    """The serve ``/stats`` "policy" block: where the table came from,
-    whether it fell back, which device key resolutions use, and the
-    newest decision per gate (incl. predict_layout's fallback reason —
-    the r23 small-fix satellite: /stats now says WHY a model serves
-    legacy)."""
-    tab = _table.current_table()
+    """The serve ``/stats`` "policy" block: the device the process runs
+    on and the newest decision per gate (incl. predict_layout's fallback
+    reason: /stats says WHY a model serves legacy)."""
     return {
         "device_kind": current_device_kind(),
-        "table_source": tab.source,
-        "table_explicit": tab.explicit,
-        "fallback_reason": tab.fallback_reason,
-        "device_keys": sorted(tab.devices),
         "decisions": decisions(),
     }

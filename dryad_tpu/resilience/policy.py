@@ -27,8 +27,7 @@ import dataclasses
 
 
 def _default_ladder() -> tuple[int, ...]:
-    """The calibrated degradation ladder (r23: policy table
-    "chunk_cap"/"ladder"; the committed default is the pre-r23
+    """The degradation ladder (policy/gates.py "chunk_cap"/"ladder":
     ``(8, 4, 2)``, ending on the 2-iteration floor).  The policy
     package is stdlib-only, so this keeps the module jax-free."""
     from dryad_tpu.policy.gates import gate_value

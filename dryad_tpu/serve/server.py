@@ -122,9 +122,8 @@ class PredictServer:
             self.registry.metrics = self.metrics
         self.mesh = self._make_mesh(sharded)
         if sharded_threshold is None:
-            # r23: the live default comes from the policy table (the
-            # committed value IS predict.SHARDED_MIN_WORK; a calibrated
-            # device entry can move it without a redeploy)
+            # the default is the predict_sharded gate's work floor
+            # (policy/gates.py says why 32k row-outputs)
             from dryad_tpu.policy.gates import gate_value
 
             sharded_threshold = int(gate_value("predict_sharded",
@@ -459,7 +458,7 @@ class PredictServer:
         snap["memory"] = self.registry.memory()
         from dryad_tpu.policy.gates import stats_block
 
-        # r23: table provenance + newest decision per gate (incl. the
+        # device kind + newest decision per gate (incl. the
         # predict_layout fallback reason when a model serves legacy)
         snap["policy"] = stats_block()
         drift = self.drift_report()

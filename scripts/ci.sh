@@ -66,21 +66,6 @@ if ! env JAX_PLATFORMS=cpu \
 fi
 tail -1 /tmp/_profile_self.log
 
-# Calibration-policy selftest (r23): seeded CPU, NO probes — the
-# committed calibration.json must equal the code defaults, every gate
-# must resolve bitwise-identically to the pre-policy hand-tuned
-# constants across shapes straddling each threshold, a perturbed table
-# entry must flip EXACTLY the intended gate and nothing else, and
-# save/load must round-trip resolutions.
-if ! env JAX_PLATFORMS=cpu \
-    PYTHONPATH="$PWD${PYTHONPATH:+:$PYTHONPATH}" \
-    python -m dryad_tpu profile --calibrate --selftest > /tmp/_calib_self.log 2>&1; then
-  echo "CALIB SELFTEST FAIL: python -m dryad_tpu profile --calibrate --selftest (see /tmp/_calib_self.log)" >&2
-  tail -5 /tmp/_calib_self.log >&2
-  exit 1
-fi
-tail -1 /tmp/_calib_self.log
-
 # Observability smoke (r9; r12 adds the device-truth families): the CLI's
 # live metrics endpoint — train 5 trees through the DEVICE trainer with
 # --metrics-port, scrape /healthz + /stats + /metrics while the run is
