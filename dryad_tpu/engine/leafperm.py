@@ -53,8 +53,11 @@ this: an L tail can cross into R territory that earlier steps already
 wrote — found in design review, hence the region split.)
 
 The histogram pass then reads the selected children's segments as
-CONTIGUOUS tile runs (tile-granular gathers move ~20 KB per access —
-bandwidth-bound, not access-bound), and no per-level sort exists at all.
+CONTIGUOUS tile runs where they lie: the histogram kernel takes this
+buffer itself, one 64 KB record tile a grid step, addressed by a
+prefetched tile index (``hist_from_layout``, PR 31 — no gather, no
+relayout and no weight rows are staged for it), and no per-level sort
+exists at all.
 
 Bitwise-tested in interpret mode against the numpy oracle
 (tests/test_leafperm.py: ``permute_records_np`` with sides from
@@ -143,8 +146,8 @@ def _tile_sides(w, rec, cat_ref, *, T: int, WB: int, itemsize: int,
     flag-0 rows (sentinels, out-of-bag) are on neither side."""
     row = jax.lax.broadcasted_iota(jnp.int32, (8, WB), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (8, WB), 1)
-    b0 = 9 + ((w >> _PAR_FEAT_SHIFT) & 0x7F) * itemsize
-    tgt = jnp.where(row == 0, 8, jnp.where(row == 1, b0, -1))
+    b0 = _REC_X + ((w >> _PAR_FEAT_SHIFT) & 0x7F) * itemsize
+    tgt = jnp.where(row == 0, _REC_FLAG, jnp.where(row == 1, b0, -1))
     if itemsize == 2:
         tgt = jnp.where(row == 2, b0 + 1, tgt)
     sel = (lane == tgt).astype(jnp.bfloat16)
@@ -477,6 +480,7 @@ def tiles_bound(n_rows: int, n_parents: int, T: int = _TILE_ROWS) -> int:
 # sentinels without assuming anything about g/h values; zero rows decode
 # to valid=0, g=h=0, bin 0 — inert in every consumer by construction.
 _REC_WB = 128
+_REC_G, _REC_H, _REC_FLAG, _REC_X = 0, 4, 8, 9     # byte offsets
 
 
 @jax.named_scope("dryad.layout")
@@ -491,7 +495,7 @@ def make_layout_records(Xb: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
     neither side), so out-of-bag rows never ride a permute past level 0."""
     N, F = Xb.shape
     nbytes = F * Xb.dtype.itemsize
-    assert 9 + nbytes <= _REC_WB, "feature bytes exceed the record"
+    assert _REC_X + nbytes <= _REC_WB, "feature bytes exceed the record"
     gb = jax.lax.bitcast_convert_type(
         g.astype(jnp.float32), jnp.uint8).reshape(N, 4)
     hb = jax.lax.bitcast_convert_type(
@@ -504,23 +508,6 @@ def make_layout_records(Xb: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
     return jnp.pad(rec, ((0, 0), (0, _REC_WB - rec.shape[1])))
 
 
-def unpack_layout_records(rec: jnp.ndarray, num_features: int,
-                          bin_dtype) -> tuple:
-    """(g, h, valid, X_rows) views of a layout record buffer."""
-    n = rec.shape[0]
-    g = jax.lax.bitcast_convert_type(
-        rec[:, 0:4].reshape(n, 1, 4), jnp.float32)[:, 0]
-    h = jax.lax.bitcast_convert_type(
-        rec[:, 4:8].reshape(n, 1, 4), jnp.float32)[:, 0]
-    valid = rec[:, 8] == 1
-    itemsize = jnp.dtype(bin_dtype).itemsize
-    xb = rec[:, 9:9 + num_features * itemsize]
-    if itemsize != 1:
-        xb = jax.lax.bitcast_convert_type(
-            xb.reshape(n, num_features, itemsize), bin_dtype)
-    return g, h, valid, xb
-
-
 @jax.named_scope("dryad.hist")
 def hist_from_layout(rec: jnp.ndarray, seg_first: jnp.ndarray,
                      seg_ntiles: jnp.ndarray, num_cols: int,
@@ -530,10 +517,13 @@ def hist_from_layout(rec: jnp.ndarray, seg_first: jnp.ndarray,
                      platform: str | None = None,
                      hist_reduce: str = "fused") -> jnp.ndarray:
     """(P, 3, F, B) histograms for P selected segments of a leaf-ordered
-    layout — NO sort, NO per-row gather: each segment is a CONTIGUOUS
-    tile run, so the only data movement is a tile-granular gather
-    (~_TILE_ROWS·_REC_WB = 64 KB per access — bandwidth-bound, unlike
-    the per-access-bound row gather it replaces).
+    layout — NO sort, NO gather, nothing row-sized staged: each segment is
+    a CONTIGUOUS tile run, so the histogram kernel reads the record tiles
+    where they lie (``pallas_hist._hist_tiles_rec``: the tile of each plan
+    slot is a prefetched scalar in the block's ``index_map``; bins, g, h
+    and the valid flag are unpacked from the 64 KB tile in VMEM).  What
+    XLA does here is the tile-sized plan: which tile each of the
+    ``n_sel_tiles`` slots reads, its column, and whether it is read at all.
 
     seg_first/seg_ntiles (P,) int32: each selected segment's first tile
     and tile count in ``rec``.  ``n_sel_tiles`` MUST bound
@@ -543,9 +533,11 @@ def hist_from_layout(rec: jnp.ndarray, seg_first: jnp.ndarray,
     alone would shift later segments past the end and silently truncate
     their histograms (caught in review; test-pinned).
 
-    Parity note (test_hist_from_layout_bitwise_vs_plan): on a PAD-FREE
-    layout (contiguous per-segment rows — the per-tree initial layout)
-    this is BITWISE equal to the tile-plan path.  Post-permute layouts
+    Parity note (test_hist_from_layout_bitwise_vs_plan, and
+    test_hist_from_layout_in_place_vs_plan for the shapes no cell runs):
+    on a PAD-FREE layout (contiguous per-segment rows — the per-tree
+    initial layout) this is BITWISE equal to the tile-plan path, whose
+    staged kernel shares the step's body.  Post-permute layouts
     carry _ALIGN interior sentinels that shift rows across tile
     boundaries, regrouping the kernel's per-tile partial sums — an
     ulp-class difference (the chunked-vs-dispatch tolerance class in
@@ -569,27 +561,19 @@ def hist_from_layout(rec: jnp.ndarray, seg_first: jnp.ndarray,
     lc = jnp.minimum(tile_leaf, P - 1)
     off = idx - base[lc]
     live = (tile_leaf < P) & (off < seg_ntiles[lc])
+    # a slot past its segment's tiles (an empty selection's mandatory slot,
+    # the plan's tail) is skipped from this tile-sized data; a live tile
+    # that holds only sentinels is the kernel's own branch
     src = jnp.where(live, seg_first[lc] + off, 0)
     src = jnp.clip(src, 0, n_tiles_in - 1)
-    # ONE tile-granular gather of the selected runs
-    sel_rec = rec.reshape(n_tiles_in, T * _REC_WB)[src].reshape(
-        n_sel_tiles * T, _REC_WB)
-    g, h, valid, X_rows = unpack_layout_records(sel_rec, num_features,
-                                                bin_dtype)
-    valid &= jnp.repeat(live, T)
-    Xt = pallas_hist._tiles_from_rows(X_rows, n_sel_tiles, T, total_bins)
-    Wt = pallas_hist._pack_weights(g.reshape(n_sel_tiles, T),
-                                   h.reshape(n_sel_tiles, T),
-                                   valid.reshape(n_sel_tiles, T))
     tile_first = jnp.concatenate([
         jnp.ones((1,), jnp.int32),
         (lc[1:] != lc[:-1]).astype(jnp.int32)])
-    tile_skip = 1 - jnp.any(valid.reshape(n_sel_tiles, T),
-                            axis=1).astype(jnp.int32)
-    hist = pallas_hist._hist_tiles(
-        Xt, Wt, lc, tile_first, tile_skip, num_cols=P,
+    hist = pallas_hist._hist_tiles_rec(
+        rec, src, lc, tile_first, 1 - live.astype(jnp.int32), num_cols=P,
         total_bins=int(total_bins), num_features=int(num_features),
-        axis_name=axis_name, platform=platform)
+        bin_dtype=jnp.dtype(bin_dtype), axis_name=axis_name,
+        platform=platform)
     if axis_name is not None:
         # the same per-arm histogram reduction every builder tail issues:
         # the fused grad/hess/count psum (default) or the feature-arm

@@ -41,6 +41,15 @@ stream through.  Feature chunking bounds the VMEM one-hot for wide data
 The kernel is pure accumulation; the surrounding XLA program does the
 cheap O(N) bookkeeping (leaf bucketing, gathers, weight limb splitting)
 and the cross-device ``psum`` that replaces the reference's NCCL allreduce.
+
+Two entries share the step (``_accumulate_tile``) and the output's
+untangling (``_untangle``); which one runs follows from what the caller
+holds.  ``_hist_tiles`` takes STAGED tiles: natural-order rows that XLA
+gathered, transposed to feature-major u8 and gave weight-limb rows (the
+root pass, the plan path).  ``_hist_tiles_rec`` takes a leaf-ordered
+layout buffer as it lies (``leafperm.hist_from_layout``, the wired
+levels): the selected tile is the block's address and the unpacking
+happens in VMEM, so nothing row-sized is staged at all.
 """
 
 from __future__ import annotations
@@ -52,6 +61,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from dryad_tpu.engine import leafperm   # the layout's record format
 
 # weight rows: g_hi g_mid g_lo h_hi h_mid h_lo count (+ pad to the MXU tile)
 _WROWS = 8
@@ -100,8 +111,8 @@ def _feature_chunk(F: int, Bp: int) -> int:
     return best
 
 
-def _split3(x: jnp.ndarray):
-    """f32 -> three bf16 limbs whose f32 sum reconstructs x exactly.
+def _split3_f32(x: jnp.ndarray):
+    """f32 -> three f32 limbs, each exact in bf16, whose sum is x exactly.
 
     Implemented by masking mantissa bits (truncation split), for two
     reasons: (a) XLA's excess-precision simplifier folds the naive
@@ -117,7 +128,15 @@ def _split3(x: jnp.ndarray):
     r1 = x - hi
     u1 = jax.lax.bitcast_convert_type(r1, jnp.uint32)
     mid = jax.lax.bitcast_convert_type(u1 & mask16, jnp.float32)
-    lo = (r1 - mid).astype(jnp.bfloat16)
+    return hi, mid, r1 - mid
+
+
+def _split3(x: jnp.ndarray):
+    """``_split3_f32``'s limbs as bf16 (the staged weight rows)."""
+    hi, mid, lo = _split3_f32(x)
+    # lo first: the order of the three converts is part of every traced
+    # program's digest (analysis/goldens), so it stays as it always was
+    lo = lo.astype(jnp.bfloat16)
     return hi.astype(jnp.bfloat16), mid.astype(jnp.bfloat16), lo
 
 
@@ -135,22 +154,72 @@ def _pack_weights(g: jnp.ndarray, h: jnp.ndarray, valid: jnp.ndarray) -> jnp.nda
     return jnp.pad(w, ((0, 0), (0, _WROWS - w.shape[-2]), (0, 0)))
 
 
-def _hist_kernel(tile_leaf_ref, tile_first_ref, tile_skip_ref, x_ref, w_ref,
-                 o_ref, *, padded_bins: int):
-    """One (feature-chunk, row-tile) step: w (128,T) @ one-hot (Fc*Bp,T)^T.
+def _accumulate_tile(o_ref, first, x, weight_rows, padded_bins: int):
+    """The shared step of both entries: one tile's bin ids ``x`` (Fc, T)
+    int32 and weight rows ``weight_rows()`` (8, T) bf16 (a thunk: the
+    staged entry reads them from VMEM only once the one-hot exists) ->
+    w (128, T) @ one-hot (Fc*Bp, T)^T, written to (``first``) or added
+    into the leaf's resident output block.
 
-    Tiles arrive FEATURE-MAJOR (Fc, T): the row dim T sits in lanes, so the
-    HBM tile buffer has no lane padding (a (T, Fc) layout with Fc < 128
-    pads up to 8x under XLA's (8,128) tiling — 12.9 GB for Epsilon-shaped
-    data — and reads ~20x slower in-kernel).  The one-hot is built in the
-    matching sublane-tiled layout: ``pltpu.repeat`` TILES the bin-id block
-    Bp times along sublanes (row r of the one-hot holds feature r mod Fc,
+    The one-hot is built in the sublane-tiled layout that matches
+    feature-major bin ids: ``pltpu.repeat`` TILES the bin-id block Bp
+    times along sublanes (row r of the one-hot holds feature r mod Fc,
     bin r >> log2(Fc)); a shifted iota supplies the compared bin.  (The
     obvious 3-D reshape is an "unsupported shape cast" to Mosaic whenever
     Bp < 128; this layout needs no relayout at all.)  Both dot operands
     contract their trailing (lane) dim — the MXU consumes the transposed
     RHS natively.  The caller untangles the bin-major row order once,
-    outside the kernel.
+    outside the kernel (``_untangle``)."""
+    Fc, T = x.shape
+    Bp = padded_bins
+    shift = Fc.bit_length() - 1                # Fc is a power of two
+    x_rep = pltpu.repeat(x, Bp, axis=0)       # (Fc*Bp, T) TILED copies
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (Fc * Bp, T), 0) >> shift
+    onehot = (x_rep == iota_b).astype(jnp.bfloat16)
+    # zero-pad the 8 weight rows to the 128-row MXU tile in VMEM (HBM
+    # never holds more than the real rows — see _pack_weights)
+    w = jnp.concatenate(
+        [weight_rows(), jnp.zeros((_MXU_M - _WROWS, T), jnp.bfloat16)],
+        axis=0)
+    part = jax.lax.dot_general(
+        w, onehot,
+        (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )[:_WROWS]                                 # (8, Fc*Bp)
+
+    @pl.when(first)
+    def _():
+        o_ref[0] = part
+
+    @pl.when(jnp.logical_not(first))
+    def _():
+        o_ref[0] = o_ref[0] + part
+
+
+def _tile_flags(tile_first_ref, tile_skip_ref, o_ref):
+    """(first, skip) of this grid step's plan slot, and the one write a
+    skipped slot may owe: an empty leaf's mandatory first tile
+    zero-initializes its output block."""
+    i = pl.program_id(1)
+    first = tile_first_ref[i] == 1
+    skip = tile_skip_ref[i] == 1
+
+    @pl.when(first & skip)
+    def _():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    return first, skip
+
+
+def _hist_kernel(tile_leaf_ref, tile_first_ref, tile_skip_ref, x_ref, w_ref,
+                 o_ref, *, padded_bins: int):
+    """One (feature-chunk, row-tile) step over STAGED tiles (the root pass
+    and the plan path, whose rows the surrounding XLA code gathered).
+
+    Tiles arrive FEATURE-MAJOR (Fc, T): the row dim T sits in lanes, so the
+    HBM tile buffer has no lane padding (a (T, Fc) layout with Fc < 128
+    pads up to 8x under XLA's (8,128) tiling — 12.9 GB for Epsilon-shaped
+    data — and reads ~20x slower in-kernel).
 
     ``tile_skip`` marks tiles with zero live rows (the plan's static grid
     covers the worst-case N/2 smaller-children bound, but real levels often
@@ -160,40 +229,30 @@ def _hist_kernel(tile_leaf_ref, tile_first_ref, tile_skip_ref, x_ref, w_ref,
     DMA.  An empty leaf's mandatory first tile still zero-initializes its
     output block.
     """
-    i = pl.program_id(1)
-    first = tile_first_ref[i] == 1
-    skip = tile_skip_ref[i] == 1
-
-    @pl.when(first & skip)
-    def _():
-        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+    first, skip = _tile_flags(tile_first_ref, tile_skip_ref, o_ref)
 
     @pl.when(jnp.logical_not(skip))
     def _():
-        x = x_ref[0, 0].astype(jnp.int32)          # (Fc, T) uint8 -> i32
-        Fc, T = x.shape
-        Bp = padded_bins
-        shift = Fc.bit_length() - 1                # Fc is a power of two
-        x_rep = pltpu.repeat(x, Bp, axis=0)       # (Fc*Bp, T) TILED copies
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (Fc * Bp, T), 0) >> shift
-        onehot = (x_rep == iota_b).astype(jnp.bfloat16)
-        # zero-pad the 8 weight rows to the 128-row MXU tile in VMEM (HBM
-        # only ever holds the real rows — see _pack_weights)
-        w = jnp.concatenate(
-            [w_ref[0], jnp.zeros((_MXU_M - _WROWS, T), jnp.bfloat16)], axis=0)
-        part = jax.lax.dot_general(
-            w, onehot,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )[:_WROWS]                                 # (8, Fc*Bp)
+        _accumulate_tile(o_ref, first,
+                         x_ref[0, 0].astype(jnp.int32),  # (Fc, T) u8 -> i32
+                         lambda: w_ref[0], padded_bins)
 
-        @pl.when(first)
-        def _():
-            o_ref[0] = part
 
-        @pl.when(jnp.logical_not(first))
-        def _():
-            o_ref[0] = o_ref[0] + part
+def _untangle(out, num_features: int, total_bins: int, chunk: int):
+    """(P, 8, n_fb*Fc*Bp) kernel output -> (P, 3, F, B): the kernel's
+    columns are (bin-major, feature-minor) per chunk, and the limb rows
+    sum to grad / hess / count."""
+    P = out.shape[0]
+    Bp = _pow2_bins(total_bins)
+    n_fb = out.shape[2] // (chunk * Bp)
+    out = (out.reshape(P, _WROWS, n_fb, Bp, chunk)
+              .transpose(0, 1, 2, 4, 3)
+              .reshape(P, _WROWS, n_fb * chunk, Bp))[:, :, :num_features,
+                                                     :total_bins]
+    hg = out[:, 0] + out[:, 1] + out[:, 2]
+    hh = out[:, 3] + out[:, 4] + out[:, 5]
+    hc = out[:, 6]
+    return jnp.stack([hg, hh, hc], axis=1)         # (P, 3, F, B)
 
 
 @functools.partial(
@@ -251,22 +310,159 @@ def _hist_tiles(Xt, Wt, tile_leaf, tile_first, tile_skip, *, num_cols: int,
         name="_hist_tiles",
     )(tile_leaf, tile_first, tile_skip, Xt, Wt)
 
-    # kernel columns are (bin-major, feature-minor) per chunk — untangle
-    out = (out.reshape(P, _WROWS, n_fb, Bp, Fc)
-              .transpose(0, 1, 2, 4, 3)
-              .reshape(P, _WROWS, n_fb * Fc, Bp))[:, :, :F, :B]
-    hg = out[:, 0] + out[:, 1] + out[:, 2]
-    hh = out[:, 3] + out[:, 4] + out[:, 5]
-    hc = out[:, 6]
-    return jnp.stack([hg, hh, hc], axis=1)         # (P, 3, F, B)
+    return _untangle(out, F, B, Fc)
+
+
+def _hist_rec_kernel(src_ref, tile_leaf_ref, tile_first_ref, tile_skip_ref,
+                     rec_ref, o_ref, *, padded_bins: int, chunk: int,
+                     num_features: int, itemsize: int):
+    """One (feature-chunk, plan-slot) step over a layout record tile IN
+    PLACE: the block is the (T, _REC_WB) uint8 tile ``src[i]`` of the
+    leaf-ordered layout buffer itself, so nothing row-sized is staged for
+    the kernel (PR 31: the tile gather, the u8 relayout to feature-major
+    tiles and the weight limbs were 45 ms of XLA passes a level).
+
+    Unpacking happens in VMEM, the way the layout's own kernels read a
+    tile (``leafperm._tile_sides``): a one-hot selector contracted with
+    the tile on its byte dimension brings the wanted bytes out
+    LANE-oriented, exactly (a byte is exact in bf16, one product a sum is
+    non-zero).  Selector rows: this chunk's ``Fc`` feature bytes (u16
+    bins: ``Fc`` low bytes, then ``Fc`` high bytes), then four groups of
+    eight rows, group k holding byte k of g (row 0) and of h (row 1), and
+    group 0 the valid flag (row 2).  The groups recombine with integer
+    shifts into one (8, T) word block whose rows 0 and 1 bitcast to g and
+    h; the flag multiplies them, ``_split3_f32`` splits them, and the
+    seven rows ``_pack_weights`` would have written are assembled by
+    sublane selects: same limbs, same one-hot, same product, same
+    per-tile grouping as the staged entry, so the histogram is bitwise
+    the staged one.
+
+    A live slot whose tile holds only sentinels (flag 0 on every row)
+    skips the one-hot and the product on a branch over the flag row it
+    already holds; no pass over the records' rows outside the kernel
+    decides it."""
+    first, skip = _tile_flags(tile_first_ref, tile_skip_ref, o_ref)
+    j = pl.program_id(0)       # read out here: the interpreter's branch
+                               # bodies have no grid position
+
+    @pl.when(jnp.logical_not(skip))
+    def _():
+        rec = leafperm._tile_bf16(rec_ref.at[0])       # (T, WB) bf16
+        T, WB = rec.shape
+        Fc, F = chunk, num_features
+        nx = Fc * itemsize                             # feature-byte rows
+        R = -(-(nx + 32) // 16) * 16                   # whole bf16 tiles
+        row = jax.lax.broadcasted_iota(jnp.int32, (R, WB), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (R, WB), 1)
+        f = j * Fc + (row & (Fc - 1))                  # Fc is a power of two
+        tgt_x = leafperm._REC_X + f * itemsize + (row >> (Fc.bit_length() - 1))
+        q, k = (row - nx) & 7, (row - nx) >> 3
+        tgt_w = jnp.where(q == 0, leafperm._REC_G + k,
+                          jnp.where(q == 1, leafperm._REC_H + k,
+                                    jnp.where((q == 2) & (k == 0),
+                                              leafperm._REC_FLAG, -1)))
+        tgt = jnp.where(row < nx, jnp.where(f < F, tgt_x, -1),
+                        jnp.where(row < nx + 32, tgt_w, -1))
+        sel = (lane == tgt).astype(jnp.bfloat16)
+        vb = jax.lax.dot_general(
+            sel, rec, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(jnp.int32)  # (R, T)
+        x = vb[0:Fc]
+        if itemsize == 2:
+            x = x + vb[Fc:2 * Fc] * 256                # little-endian u16
+        words = (vb[nx:nx + 8] | (vb[nx + 8:nx + 16] << 8)
+                 | (vb[nx + 16:nx + 24] << 16) | (vb[nx + 24:nx + 32] << 24))
+        v = (words[2:3] == 1).astype(jnp.float32)      # (1, T) valid rows
+
+        def weight_rows():
+            gh = jax.lax.bitcast_convert_type(words, jnp.float32) * v
+            hi, mid, lo = _split3_f32(gh)              # rows 0 / 1: g / h
+            r8 = jax.lax.broadcasted_iota(jnp.int32, (_WROWS, T), 0)
+            w8 = jnp.zeros((_WROWS, T), jnp.float32)
+            for r, limb in enumerate((hi[0:1], mid[0:1], lo[0:1],
+                                      hi[1:2], mid[1:2], lo[1:2], v)):
+                w8 = jnp.where(r8 == r, limb, w8)
+            return w8.astype(jnp.bfloat16)             # every limb is exact
+
+        any_valid = jnp.max(v) > 0
+
+        @pl.when(any_valid)
+        def _():
+            _accumulate_tile(o_ref, first, x, weight_rows, padded_bins)
+
+        @pl.when(jnp.logical_not(any_valid) & first)
+        def _():
+            o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("num_cols", "total_bins", "num_features",
+                              "bin_dtype", "axis_name", "platform")
+)
+def _hist_tiles_rec(rec, src, tile_leaf, tile_first, tile_skip, *,
+                    num_cols: int, total_bins: int, num_features: int,
+                    bin_dtype, axis_name: str | None = None,
+                    platform: str | None = None) -> jnp.ndarray:
+    """``_hist_tiles`` for rows that lie in a leaf-ordered layout buffer:
+    plan slot i histograms record tile ``src[i]`` of ``rec``
+    (n_tiles_in*T, _REC_WB) uint8 where it lies — the gather is the
+    block's ``index_map``, the unpacking the kernel's own
+    (``_hist_rec_kernel``).  tile_leaf / tile_first / tile_skip as in
+    ``_hist_tiles``; a skipped slot's block remaps to tile 0, so runs of
+    skips move nothing.  Wide records (more than one feature chunk) keep
+    the ``(n_fb, n_tiles)`` grid: chunk j selects its own bytes from the
+    same record block.  -> (P, 3, F, B) f32."""
+    T = _TILE_ROWS
+    n_tiles = src.shape[0]
+    B = int(total_bins)
+    P = int(num_cols)
+    F = int(num_features)
+    Bp = _pow2_bins(B)
+    Fc = _feature_chunk(F, Bp)
+    n_fb = -(-F // Fc)
+    itemsize = jnp.dtype(bin_dtype).itemsize
+    rec3 = rec.reshape(rec.shape[0] // T, T, rec.shape[1])
+    if _interpret(platform):
+        # the HLO interpreter writes every blocked operand back whole at
+        # every grid step, so a layout buffer of some hundred MB costs tens
+        # of ms a step there: on the CPU the kernel is handed the selected
+        # tiles' used bytes alone, with the same addressing over them
+        rec3 = rec3[src][:, :, :leafperm._REC_X + F * itemsize]
+        src = jnp.arange(n_tiles, dtype=src.dtype)
+    WB = rec3.shape[2]
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(n_fb, n_tiles),
+        in_specs=[
+            pl.BlockSpec((1, T, WB),
+                         lambda j, i, sr, tl, tf, sk: (sr[i] * (1 - sk[i]),
+                                                       0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, _WROWS, Fc * Bp),
+                               lambda j, i, sr, tl, tf, sk: (tl[i], 0, j)),
+    )
+    out_shape = jax.ShapeDtypeStruct(
+        (P, _WROWS, n_fb * Fc * Bp), jnp.float32,
+        vma=None if axis_name is None else frozenset({axis_name}))
+    out = pl.pallas_call(
+        functools.partial(_hist_rec_kernel, padded_bins=Bp, chunk=Fc,
+                          num_features=F, itemsize=itemsize),
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        interpret=_interpret(platform),
+        name="_hist_tiles_rec",
+    )(src, tile_leaf, tile_first, tile_skip, rec3)
+    return _untangle(out, F, B, Fc)
 
 
 def _tiles_from_rows(X_rows: jnp.ndarray, n_tiles: int, T: int, B: int) -> jnp.ndarray:
     """(n_tiles*T, F) gathered bin rows -> feature-chunked (n_fb, n_tiles, Fc, T).
 
     Always a real transpose (T and Fc swap) — its cost is part of every
-    histogram call; the payoff is the unpadded, fast-reading tile buffer
-    (see _hist_kernel).  Stays in the narrow storage dtype end to end (the
+    root-pass and plan-path call (the wired levels stage nothing:
+    ``_hist_tiles_rec``); the payoff is the unpadded, fast-reading tile
+    buffer (see _hist_kernel).  Stays in the narrow storage dtype end to end (the
     kernel converts): the u8 transpose measured ~2x faster than i32 and the
     tile buffer is 4x smaller in HBM.
     """

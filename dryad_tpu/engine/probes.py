@@ -303,8 +303,9 @@ def _build_permute_records(rows, F, B, P, seed):
 
 
 def _build_hist_from_layout(rows, F, B, P, seed):
-    """The layout histogram read (tile-run gather + kernel): the selection
-    rotates over the P runs, so a different run is segment 0 every trip."""
+    """The layout histogram read (tile plan + in-place kernel): the
+    selection rotates over the P runs, so a different run is segment 0
+    every trip."""
     import jax.numpy as jnp
 
     leafperm, T, n_buf, rec_lay, tile_run, _ = _layout_fixture(
@@ -614,7 +615,7 @@ PROBES: dict[str, StageProbe] = {p.name: p for p in (
                "leafperm level move (count + level_moves + permute)",
                _build_permute_records),
     StageProbe("hist_from_layout",
-               "layout histogram read (tile-run gather + kernel)",
+               "layout histogram read (tile plan + in-place kernel)",
                _build_hist_from_layout),
     StageProbe("route_gather",
                "wired per-level packed route small-table gather",

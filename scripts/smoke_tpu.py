@@ -186,6 +186,60 @@ def smoke_leafperm_move_oracle():
     print("leafperm fused move: bitwise vs oracle on device, 4 shapes")
 
 
+def smoke_hist_in_place_vs_plan():
+    """The in-place histogram kernel (``_hist_tiles_rec``: the layout's
+    record tiles read where they lie, addressed by a prefetched tile index
+    and unpacked in VMEM by a selector product) against the plan path ON
+    THE REAL DEVICE, bitwise, on a pad-free layout: the cells' shape (28
+    features at 256 bins), two feature chunks, and u16 bins.  Each
+    selection holds an empty column, and the last run's absorbed trailing
+    tiles are live and all sentinels, so the kernel's own empty-tile
+    branch runs.  Interpret mode cannot vouch for Mosaic's lowering of
+    the byte reassembly, the bitcast or that branch."""
+    import jax.numpy as jnp
+
+    from dryad_tpu.engine import leafperm
+    from dryad_tpu.engine.histogram import build_hist_segmented
+
+    T = leafperm._TILE_ROWS
+    rng = np.random.default_rng(67)
+    N, L = 60_000, 6
+    for dtype, B, F in [(np.uint8, 256, 28), (np.uint8, 256, 40),
+                        (np.uint16, 1000, 20)]:
+        Xb = jnp.asarray(rng.integers(0, B, (N, F)).astype(dtype))
+        g = jnp.asarray(rng.normal(size=N).astype(np.float32))
+        h = jnp.asarray(rng.uniform(0.1, 1, N).astype(np.float32))
+        slot = rng.integers(0, L + 1, N).astype(np.int32)   # L: out of bag
+        n_buf = leafperm.wired_tiles_bound(-(-N // T), L)
+        rec_lay, tile_run, _ = leafperm.initial_layout(
+            leafperm.make_layout_records(Xb, g, h), jnp.asarray(slot),
+            jnp.ones((L,), bool), L, n_buf)
+        tr = np.asarray(tile_run)
+        # columns: slots 4, 1, none, 5 (with the buffer's trailing tiles), 0
+        cols = [4, 1, None, L - 1, 0]
+        seg_first = [0 if s is None else int(np.nonzero(tr == s)[0][0])
+                     for s in cols]
+        seg_nt = [0 if s is None else int((tr == s).sum()) for s in cols]
+        assert seg_nt[3] > -(-int((slot == L - 1).sum()) // T), seg_nt
+        P = len(cols)
+        got = np.asarray(leafperm.hist_from_layout(
+            rec_lay, jnp.asarray(seg_first, jnp.int32),
+            jnp.asarray(seg_nt, jnp.int32), P, B, F, dtype,
+            sum(max(n, 1) for n in seg_nt)))
+        colof = np.full(L + 1, P, np.int32)
+        for j, s_ in enumerate(cols):
+            if s_ is not None:
+                colof[s_] = j
+        want = np.asarray(build_hist_segmented(
+            Xb, g, h, jnp.asarray(colof[slot]), P, B, backend="pallas"))
+        np.testing.assert_array_equal(
+            got, want, err_msg=f"{np.dtype(dtype).name} B={B} F={F}")
+        assert want[:, 2, 0].sum() == np.isin(
+            slot, [s_ for s_ in cols if s_ is not None]).sum()
+    print("in-place layout histogram: bitwise vs plan path on device, "
+          "3 shapes")
+
+
 def smoke_leafperm_wired_parity():
     """Wired levelwise grower (leaf-ordered layout carried through the
     level fori state, root-anchored since r10 so EVERY level is wired)
@@ -431,6 +485,7 @@ _ALL_SMOKES = [
     smoke_pallas_wide_segment_count,
     smoke_pallas_natural_order,
     smoke_leafperm_move_oracle,
+    smoke_hist_in_place_vs_plan,
     smoke_leafperm_wired_parity,
     smoke_leafwise_wired_parity,
     smoke_hist_reduce_parity,

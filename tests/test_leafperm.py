@@ -473,3 +473,171 @@ def test_hist_from_layout_bitwise_vs_plan():
         jnp.asarray(sel), 3, B, backend="pallas"))
     np.testing.assert_array_equal(got, want)
     assert not got[1].any()                       # empty slot zero-inited
+
+
+def _segment_layout(rec_nat, seg_rows, hole_after=None):
+    """A layout buffer whose segment s holds the records of ``seg_rows[s]``
+    in that order from its first tile on; ``hole_after[s] = k`` puts one
+    all-sentinel tile after the segment's first k tiles.  Returns (rec,
+    first tile of each segment, tiles of each segment)."""
+    tiles, first, nt = [], [], []
+    for s, rows in enumerate(seg_rows):
+        n = max(-(-len(rows) // T), 1)
+        seg = np.zeros((n * T, WB), np.uint8)
+        seg[:len(rows)] = rec_nat[rows]
+        seg = seg.reshape(n, T, WB)
+        k = (hole_after or {}).get(s)
+        if k is not None:
+            seg = np.concatenate([seg[:k], np.zeros((1, T, WB), np.uint8),
+                                  seg[k:]])
+        first.append(sum(t.shape[0] for t in tiles))
+        nt.append(seg.shape[0])
+        tiles.append(seg)
+    return np.concatenate(tiles).reshape(-1, WB), first, nt
+
+
+def _case_hist_u16(rng):
+    return dict(F=10, B=512, dtype=np.uint16, seg_counts=[700, 3, 1200],
+                cols=[2, 0, 1])
+
+
+def _case_hist_two_feature_chunks(rng):
+    # 40 features at 256 bins: Fc = 32, so the grid has two feature chunks
+    # and chunk 1 selects its own bytes of the same record block
+    return dict(F=40, B=256, dtype=np.uint8, seg_counts=[600, 520],
+                cols=[1, 0])
+
+
+def _case_hist_empty_between_live(rng):
+    # column 1 selects nothing (no tile: its plan slot is skipped from
+    # tile-sized data), column 3 an empty child's mandatory tile (live and
+    # all sentinels: the kernel's own branch zero-fills it)
+    return dict(F=12, B=64, dtype=np.uint8, seg_counts=[900, 0, 400, 30],
+                cols=[2, None, 0, 1, 3])
+
+
+def _case_hist_sentinel_tile_inside(rng):
+    # segment 1: one full tile, an ALL-SENTINEL tile, the rest; the plan
+    # path groups the same rows into the same two tiles
+    return dict(F=12, B=64, dtype=np.uint8, seg_counts=[300, T + 200, 40],
+                cols=[0, 1, 2], hole_after={1: 1})
+
+
+def _case_hist_out_of_bag(rng):
+    # flag-0 records with real g, h and bins between the in-bag rows: they
+    # regroup the tiles' partial sums, so g and h are dyadic (every sum
+    # exact in float32) and the comparison stays bitwise
+    return dict(F=12, B=64, dtype=np.uint8, seg_counts=[1300, 700],
+                cols=[1, 0], bag_rate=0.7, dyadic=True)
+
+
+def _case_hist_both_children(rng):
+    # the non-subtraction call: 2P columns, [left 0..P-1 | right P..2P-1],
+    # over every tile of the layout (no half bound)
+    return dict(F=12, B=64, dtype=np.uint8,
+                seg_counts=[500, 100, 0, 700, 650, 20],
+                cols=[0, 3, 1, 4, 2, 5], whole=True)
+
+
+@pytest.mark.parametrize("case", [
+    _case_hist_u16, _case_hist_two_feature_chunks,
+    _case_hist_empty_between_live, _case_hist_sentinel_tile_inside,
+    _case_hist_out_of_bag, _case_hist_both_children],
+    ids=lambda f: f.__name__[len("_case_hist_"):])
+def test_hist_from_layout_in_place_vs_plan(case):
+    """The in-place kernel (record tiles read where they lie, unpacked in
+    VMEM) BITWISE against the plan path, which stages natural-order rows
+    for the shared body: the shapes no cell runs."""
+    from dryad_tpu.engine.histogram import build_hist_segmented
+
+    rng = np.random.default_rng(131)
+    c = case(rng)
+    F, B, dtype, counts = c["F"], c["B"], c["dtype"], c["seg_counts"]
+    N, S = int(sum(counts)), len(counts)
+    Xb = rng.integers(0, B, size=(N, F)).astype(dtype)
+    if c.get("dyadic"):
+        g = (rng.integers(-1023, 1024, N) / 256).astype(np.float32)
+        h = (rng.integers(1, 256, N) / 256).astype(np.float32)
+    else:
+        g = rng.normal(size=N).astype(np.float32)
+        h = rng.uniform(0.1, 1, N).astype(np.float32)
+    seg_of = rng.permutation(np.repeat(np.arange(S), counts)).astype(np.int32)
+    bag = rng.random(N) < c.get("bag_rate", 1.0)
+    rec_nat = np.asarray(leafperm.make_layout_records(
+        jnp.asarray(Xb), jnp.asarray(g), jnp.asarray(h), jnp.asarray(bag)))
+    rec, first, nt = _segment_layout(
+        rec_nat, [np.nonzero(seg_of == s)[0] for s in range(S)],
+        c.get("hole_after"))
+
+    # cols[j] = the segment histogrammed into column j (None: an empty
+    # selection, whose mandatory plan slot only zero-fills its column)
+    cols = c["cols"]
+    seg_first = jnp.asarray([0 if s is None else first[s] for s in cols],
+                            jnp.int32)
+    seg_nt = jnp.asarray([0 if s is None else nt[s] for s in cols],
+                         jnp.int32)
+    P = len(cols)
+    bound = (leafperm.wired_sel_tiles_bound(0, rec.shape[0] // T, P, False)
+             if c.get("whole")
+             else int(np.maximum(np.asarray(seg_nt), 1).sum()))
+    got = np.asarray(leafperm.hist_from_layout(
+        jnp.asarray(rec), seg_first, seg_nt, P, B, F, dtype, bound))
+
+    colof = np.full(S, P, np.int32)
+    for j, s in enumerate(cols):
+        if s is not None:
+            colof[s] = j
+    sel = np.where(bag, colof[seg_of], P).astype(np.int32)
+    want = np.asarray(build_hist_segmented(
+        jnp.asarray(Xb), jnp.asarray(g), jnp.asarray(h), jnp.asarray(sel),
+        P, B, backend="pallas"))
+    assert want[:, 2, 0].sum() == bag[np.isin(
+        seg_of, [s for s in cols if s is not None])].sum()   # rows counted
+    np.testing.assert_array_equal(got, want)
+    for j, s in enumerate(cols):
+        if s is None or counts[s] == 0:
+            assert not got[j].any()
+
+
+def test_hist_from_layout_stages_nothing_row_sized():
+    """Outside the ``pallas_call``, ``hist_from_layout`` holds no equation
+    over ``n_sel_tiles * T`` rows: the kernel reads the layout buffer as
+    it lies (only its reshape to tiles, a bitcast, touches it), and the
+    plan is tile-sized."""
+    import jax
+
+    n_in, n_sel, P, F, B = 8, 6, 2, 8, 16
+
+    def fn(rec, sf, sn):
+        # the chip's program: the interpreter's alone is handed gathered
+        # tiles (see _hist_tiles_rec)
+        return leafperm.hist_from_layout(rec, sf, sn, P, B, F, np.uint8,
+                                         n_sel, platform="tpu")
+
+    closed = jax.make_jaxpr(fn)(
+        jax.ShapeDtypeStruct((n_in * T, WB), np.uint8),
+        jax.ShapeDtypeStruct((P,), np.int32),
+        jax.ShapeDtypeStruct((P,), np.int32))
+    seen, big = set(), []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            seen.add(name)
+            if name == "pallas_call":
+                continue
+            subs = [v for v in eqn.params.values()
+                    if hasattr(v, "jaxpr") or hasattr(v, "eqns")]
+            if subs:
+                for sub in subs:
+                    walk(getattr(sub, "jaxpr", sub))
+                continue
+            if name == "reshape":
+                continue
+            for v in list(eqn.invars) + list(eqn.outvars):
+                if int(np.prod(v.aval.shape)) >= n_sel * T:
+                    big.append((name, v.aval))
+
+    walk(closed.jaxpr)
+    assert "pallas_call" in seen
+    assert not big, big

@@ -114,6 +114,7 @@ def test_the_three_kernels_carry_their_names():
     """The benchmark finds kernel time by these names
     (``benchmark/layer_metrics/hist_time_share.py``, ``perm_time_share.py``);
     they come from ``name=``, not from what a Python function is called."""
+    from benchmark.layer_metrics import hist_time_share
     sds = jax.ShapeDtypeStruct
     T = pallas_hist._TILE_ROWS
     Xt = sds((1, 2, 8, T), np.uint8)
@@ -122,6 +123,18 @@ def test_the_three_kernels_carry_their_names():
             x, w, a, b, c, num_cols=1, total_bins=32, num_features=8, platform="cpu"),
         Xt, sds((2, 8, T), jax.numpy.bfloat16), sds((2,), np.int32),
         sds((2,), np.int32), sds((2,), np.int32)) == ["_hist_tiles"]
+    # the wired levels' in-place entry (PR 31) is a histogram kernel too:
+    # hist_time_share, hist_roofline and hist_glue_device_ms match the
+    # substring, so its name has to hold "_hist_tiles"
+    rec_names = _pallas_names(
+        lambda r, s, a, b, c: pallas_hist._hist_tiles_rec(
+            r, s, a, b, c, num_cols=1, total_bins=32, num_features=8,
+            bin_dtype=np.dtype(np.uint8), platform="cpu"),
+        sds((3 * T, leafperm._REC_WB), np.uint8), sds((2,), np.int32),
+        sds((2,), np.int32), sds((2,), np.int32), sds((2,), np.int32))
+    assert rec_names == ["_hist_tiles_rec"]
+    assert any(k in rec_names[0] for k in hist_time_share.KERNELS["hist"])
+    assert "_hist_tiles" in rec_names[0]
     assert _pallas_names(
         lambda x, g, h, s: pallas_hist.build_hist_nat(
             x, g, h, s, total_bins=32, num_features=8, platform="cpu"),
