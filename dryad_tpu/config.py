@@ -366,17 +366,43 @@ class Params:
 # ---- growth-policy helpers (jax-free: the CPU backend imports these) --------
 # Shared by engine/leafwise_fast.py (which re-exports ``supports``) and both
 # trainer entries, so the max_depth=-1 mapping can never diverge by backend.
-LEAFWISE_HIST_BYTES_BUDGET = 256 << 20   # pinned expansion hist buffer cap
 MAX_FAST_DEPTH = 14
-# Peak-residency envelope for the batched grower (VERDICT r3 #7): the
-# pinned (Pf, 3, F, B) expansion buffer transiently fans out ~6x at the
-# widest level (small/large/l/r + the 2P children concat feeding the
-# vmapped split finder), CO-RESIDENT with the N-scaled working set (binned
-# matrix, per-tree record table, grad/hess/score columns).  12 GiB leaves
-# headroom on a 16 GiB v5e HBM for the boosting loop's own buffers.  A
-# pure function of params + data shape — NEVER of backend — so the CPU
-# mirror routes identically and parity holds.
+# Peak-residency envelope of the batched grower, its ONE admission rule:
+#
+#   LEAFWISE_PEAK_FACTOR x pinned + rows x per_row <= LEAFWISE_TOTAL_BYTES_BUDGET
+#
+# ``pinned`` is the (2^(cap-1), 3, F, B) float32 expansion buffer, which the
+# widest level fans out (small/large/l/r, the 2P children concat for the
+# split finder, the kernels' chunk-padded output); ``per_row`` is what a row
+# stages beside it (the layout's records or the plan path's sort and
+# gathers, widened copies of the binned matrix, the g/h/score columns).
+# 12 GiB of temporaries leave a v5e's 15.75 GiB the room for what the job
+# holds live.  A pure function of params + GLOBAL shape, NEVER of backend,
+# so the CPU mirror routes identically and parity holds.
+#
+# The constants are an upper envelope of one grow iteration's
+# temp_size_in_bytes as the TPU compiler reckons it (v5e, compiled ahead of
+# time at the shapes tests/test_rank_plan.py lists; three of them run on
+# the chip, each within 8 % of its line; PERF.md section 6, PR 32):
+#   x pinned     5.8 at 28 features, 8.4 at 300, 9.9-10.9 at 136      -> 11
+#   bytes a row  670 at 28 features (wired layout), 250 at 136, 540 at
+#                300, 11,100-13,900 at 2000 (u32 copies of the whole
+#                matrix)                           -> 512 + 7 a feature
+# 2,270,296 x 136 at cap 12 (cell mslr2m_leaf255.job_rank): reckoned
+# 12.77 GB, compiled 9.72, the whole chunk program on the chip 10.50
+# (11.18 GB held, 66 %; 4.4 s an iteration where the sequential grower
+# takes 23.97).  10M x 28 at cap 12: reckoned 9.18, compiled 7.73, on the
+# chip 7.78.  The rule errs high between the widths it was fitted at (10M x
+# 136 at cap 12 compiles to 11.8 GB and is refused).  It must not err low:
+# a refused shape runs a slower grower, an admitted one that does not fit
+# dies.  Shape-only callers (num_rows None) are held to the expansion alone.
+LEAFWISE_PEAK_FACTOR = 11
+LEAFWISE_ROW_BYTES = 512          # + LEAFWISE_CELL_BYTES a feature
+LEAFWISE_CELL_BYTES = 6           # + the bin's own byte(s)
 LEAFWISE_TOTAL_BYTES_BUDGET = 12 << 30
+# max_depth=-1: caps tried under the documented one before the sequential
+# grower (each level down halves ``pinned``)
+LEAFWISE_CAP_STEPS = 2
 
 
 def hist_reduce_resolved(p: Params, num_features: int, total_bins: int,
@@ -403,10 +429,12 @@ def hist_reduce_resolved(p: Params, num_features: int, total_bins: int,
 def leafwise_fast_supported(p: Params, num_features: int,
                             total_bins: int,
                             num_rows: int | None = None) -> bool:
-    """Whether the batched leaf-wise grower can take this config (see
-    engine/leafwise_fast.supports for the budget rationale).  ``num_rows``
-    (GLOBAL rows — shard-count independent, or the 1-shard/N-shard
-    invariant would break) adds the peak-residency check; None skips it
+    """Whether the batched leaf-wise grower can take this config: a finite
+    depth, histogram subtraction, and a peak residency inside
+    ``LEAFWISE_TOTAL_BYTES_BUDGET`` (the comment at the constant has the
+    rationale, the compiler's numbers and the chip's).  ``num_rows`` (GLOBAL rows —
+    shard-count independent, or the 1-shard/N-shard invariant would break)
+    adds the rows' working set; None counts the expansion alone
     (shape-only callers)."""
     D = p.max_depth
     if not 0 < D <= MAX_FAST_DEPTH:
@@ -415,18 +443,11 @@ def leafwise_fast_supported(p: Params, num_features: int,
         return False
     Pf = 1 << max(D - 1, 0)
     pinned = Pf * 3 * num_features * total_bins * 4
-    if pinned > LEAFWISE_HIST_BYTES_BUDGET:
-        return False
-    if num_rows is not None:
-        bin_bytes = 1 if total_bins <= 256 else 2
-        rec_words = 2 + -(-num_features * bin_bytes // 4)
-        K = p.num_outputs
-        per_row = (num_features * bin_bytes      # binned matrix
-                   + 4 * rec_words               # per-tree record table
-                   + 16 * K + 8)                 # (N,K) g/h/score + slots
-        if 6 * pinned + num_rows * per_row > LEAFWISE_TOTAL_BYTES_BUDGET:
-            return False
-    return True
+    bin_bytes = 1 if total_bins <= 256 else 2
+    per_row = (LEAFWISE_ROW_BYTES + 16 * p.num_outputs
+               + num_features * (LEAFWISE_CELL_BYTES + bin_bytes))
+    return (LEAFWISE_PEAK_FACTOR * pinned + (num_rows or 0) * per_row
+            <= LEAFWISE_TOTAL_BYTES_BUDGET)
 
 
 def effective_depth_params(p: Params, num_features: int,
@@ -443,12 +464,15 @@ def effective_depth_params(p: Params, num_features: int,
 
     — four levels of headroom past a balanced tree, enough that a best-first
     tree constrained by the cap is almost always the unconstrained one —
-    whenever the resulting config rides the batched grower.  The SAME
-    mapping runs in ``cpu/trainer.py`` and ``engine/train.py``, so CPU↔TPU
-    tree parity is untouched (it is a pure function of params + data shape,
-    never of backend).  Configs the batched grower cannot take (budget,
-    subtraction disabled) keep true-unbounded sequential semantics, as does
-    ``unbounded_depth="exact"``.
+    whenever the resulting config rides the batched grower; where the
+    envelope refuses that cap, the cap one level and then two levels under
+    it (``LEAFWISE_CAP_STEPS``; the run's cap is in the gauge
+    ``dryad_leafwise_depth_cap``).  The SAME mapping runs in
+    ``cpu/trainer.py`` and ``engine/train.py``, so CPU↔TPU tree parity is
+    untouched (it is a pure function of params + data shape, never of
+    backend).  Configs the batched grower cannot take at any of the three
+    (budget, subtraction disabled) keep true-unbounded sequential
+    semantics, as does ``unbounded_depth="exact"``.
 
     What the cap did to the source's trees at 10M rows x 28, 255 leaves,
     cap 12 (benchmark configuration ``higgs10m_leaf255``; the plain
@@ -463,11 +487,14 @@ def effective_depth_params(p: Params, num_features: int,
         return p
     L = p.effective_num_leaves
     eff = min(max((L - 1).bit_length(), 1) + 4, MAX_FAST_DEPTH)
-    if L > (1 << eff):
-        return p                      # cap cannot express the leaf budget
-    cand = p.replace(max_depth=eff)
-    if leafwise_fast_supported(cand, num_features, total_bins, num_rows):
-        return cand
+    # the documented cap, then one and two levels under it (each halves the
+    # pinned buffer) before the sequential grower
+    for cap in range(eff, eff - LEAFWISE_CAP_STEPS - 1, -1):
+        if L > (1 << cap):
+            break                     # cap cannot express the leaf budget
+        cand = p.replace(max_depth=cap)
+        if leafwise_fast_supported(cand, num_features, total_bins, num_rows):
+            return cand
     return p
 
 

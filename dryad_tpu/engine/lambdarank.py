@@ -21,13 +21,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dryad_tpu.obs.registry import default_registry
+
 
 class PaddingPlan:
     """Loop-invariant scatter plan for ragged query groups — build it once
     per dataset (train.py hoists it out of the boosting loop) since it
     depends only on the query offsets."""
 
-    def __init__(self, query_offsets: np.ndarray, pad_multiple: int = 8):
+    def __init__(self, query_offsets: np.ndarray, pad_multiple: int = 8,
+                 truncation: int | None = None):
         sizes = np.diff(query_offsets)
         self.Q = int(sizes.size)
         self.S = int(max(8, -(-int(sizes.max()) // pad_multiple) * pad_multiple))
@@ -35,8 +38,36 @@ class PaddingPlan:
         col_np = np.concatenate([np.arange(int(s), dtype=np.int32) for s in sizes])
         self.row_ids = jnp.asarray(row_np)
         self.col_ids = jnp.asarray(col_np)
+        self.pair_cells = pair_cells(sizes, self.S, truncation)
+        _note_plan(self)
 
 
+def pair_cells(sizes: np.ndarray, S: int, truncation: int | None) -> dict:
+    """Pair cells a λ-pass over these queries has to do with, three ways:
+    ``padded`` is what the one-width plan walks (Q x S^2), ``own`` the
+    queries' own grids (sum L^2), ``kept`` the most pairs the truncation can
+    keep (sum min(T, L) x L; with no truncation given, ``own``)."""
+    sizes = np.asarray(sizes, np.int64)
+    own = int((sizes * sizes).sum())
+    kept = own if truncation is None else int(
+        (np.minimum(int(truncation), sizes) * sizes).sum())
+    return {"padded": int(sizes.size) * S * S, "own": own, "kept": kept}
+
+
+def _note_plan(plan: PaddingPlan) -> None:
+    """The plan's size as gauges, set once where it is built: what the next
+    plan (length buckets, say) is sized from."""
+    reg = default_registry()
+    if not reg.enabled:
+        return
+    reg.gauge("dryad_rank_queries", "Query groups of the λ-plan").set(plan.Q)
+    reg.gauge("dryad_rank_plan_width",
+              "Documents a query is padded to in the λ-plan (S)").set(plan.S)
+    cells = reg.gauge("dryad_rank_pair_cells",
+                      "Pair cells of one λ-pass: padded (Q x S^2), own "
+                      "(sum L^2), kept (sum min(truncation, L) x L)")
+    for kind, value in plan.pair_cells.items():
+        cells.labels(kind=kind).set(value)
 
 
 @partial(jax.jit, static_argnames=("Q", "S", "sigma", "truncation"))
@@ -96,7 +127,7 @@ def grad_hess_ranking(obj, score, y, weight, query_offsets, use_device: bool = T
         raise ValueError("lambdarank requires query groups (Dataset(group=...))")
     if use_device:
         if plan is None:
-            plan = PaddingPlan(np.asarray(query_offsets))
+            plan = PaddingPlan(np.asarray(query_offsets), truncation=int(obj.truncation))
         g, h = _lambda_grad_padded(
             jnp.asarray(score, jnp.float32), jnp.asarray(y, jnp.float32),
             plan.row_ids, plan.col_ids,

@@ -29,10 +29,10 @@ Therefore leaf-wise growth with a depth cap D factorizes into:
 The selected tree is identical to the sequential grower's, node ids and
 all, whenever both compute identical gains (they histogram with different
 programs, so near-tie fp flips fall under the documented CPU↔TPU
-tolerance class).  The equivalence needs a finite depth cap: with
-``max_depth`` unset the sequential path remains (an unbounded-depth tree
-cannot be pre-expanded), so ``grow_any`` routes here only for
-``0 < max_depth`` within the expansion memory budget.
+tolerance class).  The equivalence needs a finite depth cap: an unbounded
+tree cannot be pre-expanded, so ``grow_any`` routes here only for
+``0 < max_depth`` (set, or unbounded_depth=auto's cap) inside the peak-
+residency envelope (config.py: 11 x pinned + rows x per_row <= 12 GiB).
 
 Distribution contract matches levelwise.py: call under ``shard_map`` with
 rows sharded; the fused psum inside the histogram builders is the only
@@ -75,7 +75,6 @@ from dryad_tpu.engine.split import NEG_INF, find_best_split
 from dryad_tpu.policy.gates import gate_value
 
 from dryad_tpu.config import (  # noqa: F401  (re-exported API)
-    LEAFWISE_HIST_BYTES_BUDGET as _HIST_BYTES_BUDGET,
     MAX_FAST_DEPTH as _MAX_FAST_DEPTH,
     effective_depth_params,
     leafwise_fast_supported,
@@ -86,17 +85,17 @@ def supports(p: Params, num_features: int, total_bins: int,
              num_rows: int | None = None) -> bool:
     """Fast leaf-wise needs a finite, memory-feasible expansion depth.
 
-    The budget is checked against the PINNED (Pf, 3, F, B) buffer, but the
-    widest level transiently holds ~5-6x that (hist_small/large/l/r plus
-    the 2P-wide children concat for the vmapped split finder), so the cap
-    is set to keep peak transients under ~1.5 GB.  Configs beyond it keep
-    the sequential grower.  (The shape logic lives jax-free in
-    ``config.leafwise_fast_supported`` so the CPU backend's max_depth=-1
-    policy — config.effective_depth_params — can consult it without
-    touching jax; a config that disables hist_subtraction is rejected
-    there too, because the expansion derives every larger sibling by
-    subtraction.)  ``num_rows`` must be the GLOBAL row count (see
-    config.leafwise_fast_supported)."""
+    One rule, by peak residency: LEAFWISE_PEAK_FACTOR x the PINNED
+    (Pf, 3, F, B) float32 buffer (hist_small/large/l/r, the 2P-wide children
+    concat for the split finder, the kernels' padded output) plus what the
+    rows stage, within LEAFWISE_TOTAL_BYTES_BUDGET; config.py has the
+    constants and the compiler's numbers they envelop.  Configs beyond keep
+    the sequential grower.  (The shape logic lives jax-free in config's
+    ``leafwise_fast_supported`` so the CPU backend's max_depth=-1 policy,
+    config.effective_depth_params, can consult it without touching jax; a
+    config that disables hist_subtraction is rejected there too, because the
+    expansion derives every larger sibling by subtraction.)  ``num_rows``
+    must be the GLOBAL row count (see config.leafwise_fast_supported)."""
     return leafwise_fast_supported(p, num_features, total_bins, num_rows)
 
 

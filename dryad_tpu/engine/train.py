@@ -834,7 +834,7 @@ def train_device(
     if p.objective == "lambdarank":
         from dryad_tpu.engine.lambdarank import PaddingPlan
 
-        rank_plan = PaddingPlan(np.asarray(qoff))  # loop-invariant scatter plan
+        rank_plan = PaddingPlan(np.asarray(qoff), truncation=p.lambdarank_truncation)
         rank_row, rank_col = rank_plan.row_ids, rank_plan.col_ids
         rank_Q, rank_S = rank_plan.Q, rank_plan.S
         qoff_j = jnp.asarray(qoff)
