@@ -413,6 +413,40 @@ def _arm_packed_predict():
                             "expected_table_gathers": 3 * 6 * 1}
 
 
+def _arm_train_eval():
+    import jax
+    import jax.numpy as jnp
+
+    from dryad_tpu.booster import CAT_WORDS
+    from dryad_tpu.engine.train import _apply_valid_jit, _empty_out_device
+
+    N, F, B, M, depth = 2048, 8, 32, 63, 6
+    sds = jax.ShapeDtypeStruct
+    out = jax.eval_shape(lambda: _empty_out_device(1, M, CAT_WORDS))
+
+    # the body per-iteration dispatch runs after every tree (the chunk
+    # program's eval stage is the same two calls): the fresh tree's fields
+    # packed on the device, then the packed walk.  The jitted entry takes
+    # the depth traced (a while loop, counted once); the body under it
+    # with a static depth makes the walk a scan the census weights by trips
+    def fn(out, t, vXb, vs_col):
+        return _apply_valid_jit.__wrapped__(out, t, vXb, vs_col, depth, B,
+                                            False)
+
+    args = (out, sds((), jnp.int32), sds((N, F), jnp.uint8),
+            sds((N,), jnp.float32))
+    # rows_threshold above N: the valid rows are the walk's INDEX, so every
+    # per-node lookup lands in table_gathers, as in the predict arms (where
+    # vmap's leading K axis does it)
+    meta = {"rows_threshold": N + 1, "expected_psums": 0,
+            "comm": {"psum_calls_per_iter": 0}}
+    # ONE node-word gather a level + the value lookup; the structure of
+    # arrays it replaced read 8 a level (6 fields, the bitset, the bin)
+    return fn, args, meta, {"expected_row_sorts": 0,
+                            "collective_free": True,
+                            "expected_table_gathers": depth * 1 + 1}
+
+
 ARMS: dict[str, Arm] = {
     "levelwise_wired": Arm(
         "levelwise_wired",
@@ -456,6 +490,11 @@ ARMS: dict[str, Arm] = {
         "packed_predict",
         "shard_map packed node-word predict: one table gather per level",
         _arm_packed_predict),
+    "train_eval": Arm(
+        "train_eval",
+        "training eval of a fresh tree: device-side node-word packing + "
+        "the packed walk, one table gather per level",
+        _arm_train_eval),
 }
 
 

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dryad_tpu.engine.levelwise import select_bins
+
 # ---- packed node-word layout (r21) ----------------------------------------
 # Gather cost on TPU is per-ACCESS, not per-byte (CLAUDE.md measured
 # lowering facts), so the traversal fields of one node are packed into a
@@ -107,6 +109,36 @@ def pack_node_words(feature, threshold, left, right, default_left,
                     axis=-1)
 
 
+def packed_shapes_fit(num_features: int, num_bins: int,
+                      max_nodes: int) -> bool:
+    """True when every tree of these STATIC shapes fits the packed widths:
+    column ids below ``num_features``, thresholds below ``num_bins``, node
+    ids below ``max_nodes``.  The trainer's test for a tree it has only as
+    traced arrays (``packed_fields_fit`` reads actual values on the host)."""
+    return (num_features <= 1 << PACKED_FEATURE_BITS
+            and num_bins <= 1 << PACKED_THRESHOLD_BITS
+            and max_nodes <= 1 << PACKED_CHILD_BITS)
+
+
+def pack_node_words_device(feature, threshold, left, right, default_left,
+                           is_cat) -> jnp.ndarray:
+    """``pack_node_words`` on device arrays (..., M), traced: the same two
+    limbs bit for bit, for a tree that has not left the device.  Widths are
+    the caller's to check (``packed_shapes_fit``): traced values cannot be
+    asserted, and a field past its width would spill into its neighbour."""
+    internal = feature >= 0
+
+    def field(a):
+        return jnp.where(internal, a, 0).astype(jnp.uint32)
+
+    limb0 = field(left) | (field(right) << PACKED_CHILD_BITS)
+    limb1 = (field(threshold)
+             | (field(feature) << 16)
+             | (field(default_left) << 28) | (field(is_cat) << 29)
+             | (internal.astype(jnp.uint32) << 30))
+    return jnp.stack([limb0, limb1], axis=-1)
+
+
 def unpack_node_words(words: np.ndarray) -> dict:
     """Inverse of ``pack_node_words`` back to the canonical (leaf-zeroed)
     field dict — the round-trip anchor for the pack/unpack property test."""
@@ -139,7 +171,9 @@ def tree_leaves(tree: dict, Xb: jnp.ndarray, depth_bound) -> jnp.ndarray:
 
     Two table layouts (r21), dispatched on dict-key presence (static):
     ``node_word`` selects the packed arm — one (M, 2)-uint32 table gather
-    per level plus the unavoidable per-row ``Xb`` column read; otherwise
+    per level plus the row's bin through ``levelwise.select_bins`` (a masked
+    reduce over the contiguous row where the ``partition`` gate admits, so
+    no per-row gather into ``Xb``: the walk route does); otherwise
     the legacy structure-of-arrays arm runs, itself issuing the
     ``cat_bitset`` gather only when the staged dict carries one (numeric
     models no longer pay the bitset gather).  Both arms compare the SAME
@@ -156,7 +190,7 @@ def tree_leaves(tree: dict, Xb: jnp.ndarray, depth_bound) -> jnp.ndarray:
         w0, w1 = w[..., 0], w[..., 1]
         internal = (w1 >> jnp.uint32(30)) > 0          # bit 31 never set
         fc = ((w1 >> jnp.uint32(16)) & jnp.uint32(0xFFF)).astype(jnp.int32)
-        bins = jnp.take_along_axis(Xb, fc[:, None], axis=1)[:, 0].astype(jnp.int32)
+        bins = select_bins(Xb, fc)
         num_left = bins <= (w1 & jnp.uint32(0xFFFF)).astype(jnp.int32)
         num_left &= (((w1 >> jnp.uint32(28)) & 1) > 0) | (bins != 0)
         if "cat_bitset" in tree:                       # static: model has cats

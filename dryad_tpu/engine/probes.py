@@ -524,7 +524,7 @@ def _build_predict_traversal_packed(rows, F, B, P, seed, depth: int = 6):
     """The r21 packed node-word twin of ``predict_traversal``: the SAME
     synthetic tree packed into the (M, 2)-uint32 limb table, numeric
     program (no cat_bitset key), so the per-level body is one node-word
-    gather + the Xb column read.  The perturbation bumps limb1's
+    gather + the row's bin through ``select_bins``.  The perturbation bumps limb1's
     threshold field (low 16 bits) by the carried period-8 parity — the
     synthetic thresholds top out at 3B/4, so +7 can never carry into the
     feature bits, and the liveness signal is the legacy probe's exactly."""

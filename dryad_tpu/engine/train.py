@@ -45,7 +45,8 @@ from dryad_tpu.dataset import Dataset
 # programs are untouched (the analysis goldens are the proof)
 from dryad_tpu.engine import introspect
 from dryad_tpu.engine.grower import grow_any
-from dryad_tpu.engine.predict import _accumulate, tree_leaves
+from dryad_tpu.engine.predict import (_accumulate, pack_node_words_device,
+                                      packed_shapes_fit, tree_leaves)
 from dryad_tpu.objectives import get_objective
 
 # per-stage span series (dryad_tpu/obs): host wall around work this loop
@@ -65,6 +66,9 @@ from dryad_tpu.obs.watchdog import watch_fetch
 
 _TREE_KEYS = ("feature", "threshold", "left", "right", "value", "is_cat",
               "cat_bitset", "gain", "default_left", "cover")
+# what a tree's traversal reads, in ``pack_node_words``'s argument order
+_WALK_KEYS = ("feature", "threshold", "left", "right", "default_left",
+              "is_cat")
 # per-tree statistics only the batched leaf-wise grower returns; kept in
 # ``out`` beside the trees where that grower runs (see _count_grow_stats)
 _GROW_STATS = ("expanded_splits", "selected_splits")
@@ -248,6 +252,40 @@ def _grow_iteration(p, B, has_cat, mesh, platform, learn_missing, out, score,
     return out, score
 
 
+def _fresh_tree(out, t, num_features: int, B: int, has_cat: bool) -> dict:
+    """Tree slot ``t`` of the output tables as ``tree_leaves`` walks it
+    fastest (traced; ``t`` may be a traced scalar), with its ``value``.
+
+    Where every tree of these static shapes fits the packed widths, the
+    traversal fields are packed on the device into ``predict``'s (M, 2)
+    node-word table (a few integer operations over M entries), and
+    ``cat_bitset`` rides along only where the model has categorical
+    splits, as ``stage_trees`` stages it: one table gather a level and
+    ``select_bins``, the walk route does over the train rows.  A shape past
+    a packed width keeps the structure-of-arrays tables.  Both arms compare
+    the same integers, so the leaves are bitwise the same.  The arm taken
+    is the gauge ``dryad_eval_walk{arm}``, set here, at trace time."""
+    if packed_shapes_fit(num_features, B, out["feature"].shape[1]):
+        arm = "packed"
+        tree = {"node_word": pack_node_words_device(
+                    *(out[key][t] for key in _WALK_KEYS)),
+                "value": out["value"][t]}
+        if has_cat:
+            tree["cat_bitset"] = out["cat_bitset"][t]
+    else:
+        arm = "legacy"
+        tree = {key: out[key][t] for key in _TREE_KEYS}
+    reg = default_registry()
+    if reg.enabled:
+        walk = reg.gauge("dryad_eval_walk",
+                         "Table layout the training eval's tree walk "
+                         "takes (1 = active): packed node words, or the "
+                         "legacy structure of arrays past a packed width")
+        for name in ("packed", "legacy"):
+            walk.labels(arm=name).set(float(name == arm))
+    return tree
+
+
 @partial(jax.jit,
          static_argnames=("p", "B", "has_cat", "mesh", "platform",
                           "learn_missing", "N", "K", "pad", "rank_Q",
@@ -311,12 +349,13 @@ def _chunk_jit(p, B, has_cat, mesh, platform, learn_missing, N, K, pad,
 
         if n_valid:
             with jax.named_scope("dryad.eval"):
+                slots = [(it0 + i) * K + k for k in range(K)]
+                trees_k = [_fresh_tree(out, t, Xb.shape[1], B, has_cat)
+                           for t in slots]
                 new_vs = []
                 for vi in range(n_valid):
                     vs = vscores[vi]
-                    for k in range(K):
-                        t = (it0 + i) * K + k
-                        tree = {key: out[key][t] for key in _TREE_KEYS}
+                    for k, (t, tree) in enumerate(zip(slots, trees_k)):
                         lv = tree_leaves(tree, vXbs[vi], out["max_depth"][t])
                         vs = vs.at[:, k].set(vs[:, k] + tree["value"][lv])
                     new_vs.append(vs)
@@ -635,8 +674,9 @@ def _rf_avg_jit(vs, init, inv):
     return initf + (vs - initf) * inv
 
 
-@partial(jax.jit, static_argnames=("depth_bound",))
-def _dart_drop_jit(out, score, tids, tcls, Xb, factor_drop, depth_bound):
+@partial(jax.jit, static_argnames=("depth_bound", "B", "has_cat"))
+def _dart_drop_jit(out, score, tids, tcls, Xb, factor_drop, depth_bound, B,
+                   has_cat):
     """DART drop bookkeeping in ONE dispatch: ``tids`` (max_drop*K,)
     padded with -1 names the dropped tree slots, ``tcls`` their class
     columns, ``factor_drop`` = f32(k/(k+1)) computed HOST-side (the same
@@ -651,7 +691,7 @@ def _dart_drop_jit(out, score, tids, tcls, Xb, factor_drop, depth_bound):
 
     def body(i, acc):
         t = jnp.maximum(tids[i], 0)
-        tree = {key: out[key][t] for key in _TREE_KEYS}
+        tree = _fresh_tree(out, t, Xb.shape[1], B, has_cat)
         lv = tree_leaves(tree, Xb, depth_bound)
         c = tree["value"][lv] * (tids[i] >= 0).astype(jnp.float32)
         return acc.at[:, tcls[i]].add(c)
@@ -665,10 +705,10 @@ def _dart_drop_jit(out, score, tids, tcls, Xb, factor_drop, depth_bound):
 
 
 @partial(introspect.whole_program, "dryad.eval")
-@jax.jit
+@partial(jax.jit, static_argnames=("B", "has_cat"))
 @jax.named_scope("dryad.eval")
-def _apply_valid_jit(out, t, vXb, vs_col, depth_bound):
-    tree = {key: out[key][t] for key in _TREE_KEYS}
+def _apply_valid_jit(out, t, vXb, vs_col, depth_bound, B, has_cat):
+    tree = _fresh_tree(out, t, vXb.shape[1], B, has_cat)
     leaves = tree_leaves(tree, vXb, depth_bound)
     return vs_col + tree["value"][leaves]
 
@@ -1577,7 +1617,7 @@ def train_device(
                 db = (p.max_depth if p.max_depth > 0
                       else max(p.effective_num_leaves - 1, 1))
                 score_eff, newval = _dart_drop_jit(
-                    out, score, tids, tcls, Xb, fdrop, db)
+                    out, score, tids, tcls, Xb, fdrop, db, B, has_cat)
                 out = dict(out)
                 out["value"] = newval
                 value_scale = inv
@@ -1628,7 +1668,7 @@ def train_device(
                 for vi, vXb in enumerate(vXbs):
                     vscores[vi] = vscores[vi].at[:, k].set(
                         _apply_valid_jit(out, t, vXb, vscores[vi][:, k],
-                                         out["max_depth"][t])
+                                         out["max_depth"][t], B, has_cat)
                     )
         if p.boosting != "dart":
             # idempotent per-iteration arm (key-less families stay inert —

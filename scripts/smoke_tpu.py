@@ -427,6 +427,81 @@ def smoke_predict_packed_parity():
           "packed ≡ legacy bitwise (one node-word gather per level)")
 
 
+def smoke_eval_walk_parity():
+    """The training eval's walk (``train._fresh_tree``: the fresh tree's
+    fields packed ON THE DEVICE, one node-word gather a level and
+    ``select_bins``) vs the structure-of-arrays walk it replaced, on the
+    real device, bitwise, at the cells' own sizes: 500,000 x 28 to depth
+    12 and 100,000 x 2000 to depth 6.  Interpret-mode CI holds the same
+    identity on the CPU at small sizes; what only the chip can vouch for
+    is the lowering of the traced shifts, the (M, 2) gather and the masked
+    reduce at these widths.  Both walks take the tree slot and the depth
+    traced, as the trainer passes them; the times are one warm walk each
+    on the host's clock (informational)."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dryad_tpu.booster import CAT_WORDS
+    from dryad_tpu.engine import train as engine_train
+    from dryad_tpu.engine.predict import tree_leaves
+
+    def random_tree(rng, M, F, B, depth_cap):
+        """One random tree in the trainer's (1, M) tables, no deeper than
+        ``depth_cap``, every splittable node split while nodes last."""
+        out = jax.tree.map(np.array,
+                           engine_train._empty_out_device(1, M, CAT_WORDS))
+        open_nodes, used, depth = [(0, 0)], 1, 0
+        while open_nodes and used + 2 <= M:
+            node, d = open_nodes.pop(int(rng.integers(len(open_nodes))))
+            out["feature"][0, node] = rng.integers(F)
+            out["threshold"][0, node] = rng.integers(B)
+            out["default_left"][0, node] = rng.random() < 0.5
+            out["left"][0, node], out["right"][0, node] = used, used + 1
+            if d + 1 < depth_cap:
+                open_nodes += [(used, d + 1), (used + 1, d + 1)]
+            used, depth = used + 2, max(depth, d + 1)
+        out["value"][0] = rng.standard_normal(M)
+        return out, depth
+
+    @jax.jit
+    def walk_packed(out, t, Xb, depth):
+        tree = engine_train._fresh_tree(out, t, Xb.shape[1], 256, False)
+        assert "node_word" in tree
+        return tree_leaves(tree, Xb, depth)
+
+    @jax.jit
+    def walk_soa(out, t, Xb, depth):
+        tree = {key: out[key][t] for key in engine_train._TREE_KEYS}
+        return tree_leaves(tree, Xb, depth)
+
+    def timed(fn, *args):
+        fn(*args).block_until_ready()           # compiles
+        t0 = time.perf_counter()
+        got = fn(*args).block_until_ready()
+        return got, (time.perf_counter() - t0) * 1e3
+
+    rng = np.random.default_rng(33)
+    for N, F, M, cap in ((500_000, 28, 511, 12), (100_000, 2000, 127, 6)):
+        out_np, depth = random_tree(rng, M, F, 256, cap)
+        assert depth == cap, (depth, cap)
+        Xb = rng.integers(0, 256, (N, F), dtype=np.uint8)
+        Xb[rng.random((N, F)) < 0.1] = 0        # the missing bin
+        out = {k: jnp.asarray(v) for k, v in out_np.items()}
+        args = (out, jnp.int32(0), jnp.asarray(Xb), jnp.int32(depth))
+        packed, ms_p = timed(walk_packed, *args)
+        soa, ms_s = timed(walk_soa, *args)
+        np.testing.assert_array_equal(
+            np.asarray(packed), np.asarray(soa),
+            err_msg=f"eval walk {N} x {F} to depth {depth}")
+        assert (out_np["feature"][0][np.asarray(packed)] < 0).all()
+        print(f"eval walk {N} x {F} to depth {depth}: packed == "
+              f"structure of arrays bitwise ({len(np.unique(packed))} "
+              f"leaves reached); one walk {ms_p:.2f} ms against {ms_s:.2f}")
+
+
 def smoke_stage_profiler():
     """First per-stage device breakdown (r13): run the cheap tier of the
     stage-probe registry (engine/probes) on the attached device, each
@@ -490,6 +565,7 @@ _ALL_SMOKES = [
     smoke_leafwise_wired_parity,
     smoke_hist_reduce_parity,
     smoke_predict_packed_parity,
+    smoke_eval_walk_parity,
     smoke_stage_profiler,
 ]
 

@@ -177,6 +177,32 @@ def test_sharded_predict_collective_free(audit_report):
     assert c.global_row_sorts == 0 and c.local_row_sorts == 0
 
 
+def test_train_eval_walks_one_table_gather_a_level(audit_report):
+    """The training eval of a fresh tree (PR 33): six levels of ONE
+    node-word gather and the value lookup; the bin comes from the masked
+    reduce, so nothing else gathers."""
+    c = _arm(audit_report, "train_eval").census
+    assert (c.table_gathers, c.row_gathers) == (6 * 1 + 1, 0)
+    assert not c.collectives and not c.pallas_kernels
+
+
+def test_soa_eval_walk_is_caught():
+    """Mutation direction: the structure-of-arrays walk the trainer ran
+    before (every tree key handed to ``tree_leaves``, the bitset's among
+    them) reads eight gathers a level in the same census."""
+    from dryad_tpu.engine.predict import tree_leaves
+    from dryad_tpu.engine.train import _TREE_KEYS
+
+    _, (out, t, vXb, _), meta, _ = ARMS["train_eval"].build()
+
+    def soa(out, t, vXb):
+        return tree_leaves({key: out[key][t] for key in _TREE_KEYS}, vXb, 6)
+
+    c = census_jaxpr(jax.make_jaxpr(soa)(out, t, vXb),
+                     meta["rows_threshold"])
+    assert c.table_gathers + c.row_gathers == 6 * 8
+
+
 def test_only_documented_collectives_anywhere(audit_report):
     """fused arms: psum only.  feature arms (r16): psum (root) +
     reduce_scatter + all_gather (+ the communication-free axis_index the

@@ -507,12 +507,15 @@ def test_http_structured_request_logging(model):
         server.stop()
     lines = [json.loads(l) for l in stream.getvalue().splitlines()]
     assert len(lines) == 3
-    ok = lines[0]
-    assert ok["path"] == "/predict" and ok["status"] == 200
+    # a line is written after its response is sent, on the handler's own
+    # thread, so the next request's line can overtake it: match by content
+    by_key = {(l["path"], l["status"]): l for l in lines}
+    assert set(by_key) == {("/predict", 200), ("/predict", 400),
+                           ("/stats", 200)}
+    ok = by_key["/predict", 200]
     assert ok["version"] == 1 and ok["rows"] == 3
     assert ok["latency_ms"] >= 0
-    assert lines[1]["status"] == 400 and lines[1]["version"] is None
-    assert lines[2]["path"] == "/stats" and lines[2]["status"] == 200
+    assert by_key["/predict", 400]["version"] is None
 
 
 def test_bench_serve_zero_recompiles_after_warmup(model):
