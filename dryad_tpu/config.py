@@ -377,8 +377,12 @@ MAX_FAST_DEPTH = 14
 # stages beside it (the layout's records or the plan path's sort and
 # gathers, widened copies of the binned matrix, the g/h/score columns).
 # 12 GiB of temporaries leave a v5e's 15.75 GiB the room for what the job
-# holds live.  A pure function of params + GLOBAL shape, NEVER of backend,
-# so the CPU mirror routes identically and parity holds.
+# holds live.  The rule reckons what ONE DEVICE holds: every shard of a
+# mesh holds the whole expansion (``pinned`` is as it is on one chip) and
+# its own share of the rows, ceil(global rows / shards).  A pure function of
+# params + GLOBAL shape + shard count (as ``hist_reduce_resolved``), NEVER
+# of backend nor of the local row count a traced function sees, so the CPU
+# mirror (one shard) routes as one chip does and parity holds there.
 #
 # The constants are an upper envelope of one grow iteration's
 # temp_size_in_bytes as the TPU compiler reckons it (v5e, compiled ahead of
@@ -428,14 +432,29 @@ def hist_reduce_resolved(p: Params, num_features: int, total_bins: int,
 
 def leafwise_fast_supported(p: Params, num_features: int,
                             total_bins: int,
-                            num_rows: int | None = None) -> bool:
+                            num_rows: int | None = None,
+                            n_shards: int = 1) -> bool:
     """Whether the batched leaf-wise grower can take this config: a finite
-    depth, histogram subtraction, and a peak residency inside
+    depth, histogram subtraction, and a peak residency ON ONE DEVICE inside
     ``LEAFWISE_TOTAL_BYTES_BUDGET`` (the comment at the constant has the
-    rationale, the compiler's numbers and the chip's).  ``num_rows`` (GLOBAL rows —
-    shard-count independent, or the 1-shard/N-shard invariant would break)
-    adds the rows' working set; None counts the expansion alone
-    (shape-only callers)."""
+    rationale, the compiler's numbers and the chip's).  ``num_rows`` is the
+    GLOBAL, unpadded row count and ``n_shards`` the mesh's size (1 without a
+    mesh, and in ``cpu/trainer.py``): the rows' working set is a shard's,
+    ``ceil(num_rows / n_shards)``, beside the whole expansion, which every
+    shard holds.  None counts the expansion alone (shape-only callers).
+    Never the local row count of a traced shard: every caller of one job
+    (``train_device``, ``grow_any`` under ``shard_map``, ``_comm_stats``)
+    hands over the same two numbers and gets the same verdict.
+
+    The invariant: N shards and one grow bit-identical trees wherever both
+    are admitted at the same cap, which is every shape the parity tests run.
+    More shards only ever admit more (the rows' term falls, nothing rises):
+    12M x 67 at 255 leaves is refused on one chip at caps 12, 11 and 10
+    (4.64 GB of expansion + 12.0 GB of rows) and admitted at 12 on four
+    (4.64 + 2.99), since a mesh exists to hold what one chip cannot.  There
+    the one-chip job would grow another tree (the sequential grower's
+    uncapped one); gauge ``dryad_leafwise_depth_cap`` says which cap a run
+    had."""
     D = p.max_depth
     if not 0 < D <= MAX_FAST_DEPTH:
         return False
@@ -446,13 +465,15 @@ def leafwise_fast_supported(p: Params, num_features: int,
     bin_bytes = 1 if total_bins <= 256 else 2
     per_row = (LEAFWISE_ROW_BYTES + 16 * p.num_outputs
                + num_features * (LEAFWISE_CELL_BYTES + bin_bytes))
-    return (LEAFWISE_PEAK_FACTOR * pinned + (num_rows or 0) * per_row
+    shard_rows = -(-(num_rows or 0) // max(int(n_shards), 1))
+    return (LEAFWISE_PEAK_FACTOR * pinned + shard_rows * per_row
             <= LEAFWISE_TOTAL_BYTES_BUDGET)
 
 
 def effective_depth_params(p: Params, num_features: int,
                            total_bins: int,
-                           num_rows: int | None = None) -> Params:
+                           num_rows: int | None = None,
+                           n_shards: int = 1) -> Params:
     """The documented ``max_depth=-1`` policy for leaf-wise growth at scale.
 
     Unbounded-depth leaf-wise growth cannot be pre-expanded, so it takes the
@@ -469,8 +490,10 @@ def effective_depth_params(p: Params, num_features: int,
     it (``LEAFWISE_CAP_STEPS``; the run's cap is in the gauge
     ``dryad_leafwise_depth_cap``).  The SAME mapping runs in
     ``cpu/trainer.py`` and ``engine/train.py``, so CPU↔TPU tree parity is
-    untouched (it is a pure function of params + data shape, never of
-    backend).  Configs the batched grower cannot take at any of the three
+    untouched (it is a pure function of params + data shape + the mesh's
+    size, never of backend; ``n_shards`` as in ``leafwise_fast_supported``:
+    the envelope counts a device's share of the rows, so a mesh may be
+    given the documented cap where one chip is refused it).  Configs the batched grower cannot take at any of the three
     (budget, subtraction disabled) keep true-unbounded sequential
     semantics, as does ``unbounded_depth="exact"``.
 
@@ -493,7 +516,8 @@ def effective_depth_params(p: Params, num_features: int,
         if L > (1 << cap):
             break                     # cap cannot express the leaf budget
         cand = p.replace(max_depth=cap)
-        if leafwise_fast_supported(cand, num_features, total_bins, num_rows):
+        if leafwise_fast_supported(cand, num_features, total_bins, num_rows,
+                                   n_shards):
             return cand
     return p
 

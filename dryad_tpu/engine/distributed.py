@@ -111,12 +111,16 @@ def reduce_hist(hist: jnp.ndarray, axis_name, hist_reduce: str = "fused"):
     """The one histogram cross-shard reduction every builder tail calls:
     the fused psum (default — the classic single collective) or the
     feature-arm reduce-scatter.  No-op outside a mesh (the degenerate
-    single-device "feature" program keeps the full slice)."""
+    single-device "feature" program keeps the full slice).  The exchange
+    (this reduction on either arm, and the feature arm's all-gather of
+    best-split records) has a scope of its own, ``dryad.reduce``: innermost,
+    so a trace reads it apart from the ``dryad.hist`` glue around it."""
     if axis_name is None:
         return hist
-    if hist_reduce == "feature":
-        return reduce_scatter_hist(hist, axis_name)
-    return jax.lax.psum(hist, axis_name)
+    with jax.named_scope("dryad.reduce"):
+        if hist_reduce == "feature":
+            return reduce_scatter_hist(hist, axis_name)
+        return jax.lax.psum(hist, axis_name)
 
 
 def feature_shard_slice(arr: jnp.ndarray, axis_name, axis: int = 0):
@@ -162,9 +166,10 @@ def combine_best_splits(rec, axis_name, *, allow, min_split_gain: float,
     words = pack_local_split(rec)
     cat = rec.cat_mask if has_cat else None
     if axis_name is not None:
-        words = jax.lax.all_gather(words, axis_name, axis=0)
-        if cat is not None:
-            cat = jax.lax.all_gather(cat, axis_name, axis=0)
+        with jax.named_scope("dryad.reduce"):
+            words = jax.lax.all_gather(words, axis_name, axis=0)
+            if cat is not None:
+                cat = jax.lax.all_gather(cat, axis_name, axis=0)
     else:
         words = words[None]
         cat = cat[None] if cat is not None else None
@@ -224,18 +229,30 @@ def grow_sharded(params: Params, total_bins: int, has_cat: bool,
 
         level_sync = leafwise_fast.supports(
             params, Xb.shape[1], int(total_bins),
-            global_rows if global_rows is not None else Xb.shape[0])
+            global_rows if global_rows is not None else Xb.shape[0],
+            mesh.devices.size)
     mode = (hist_reduce_resolved(params, Xb.shape[1], int(total_bins),
                                  mesh.devices.size)
             if level_sync else "fused")
     if level_sync and params.growth == "leafwise":
         # the batched grower's two statistics (train._GROW_STATS)
         tree_specs.update(expanded_splits=rep, selected_splits=rep)
+    # jax 0.9's Pallas HLO interpreter evaluates a kernel's index maps
+    # without the casts the vma checker wants between a shard-varying
+    # scalar-prefetch operand and the unvarying grid index (dynamic_slice:
+    # "varying manual axes to match"), so the interpreted kernels of a CPU
+    # mesh cannot be traced with the checker on; the chip's program, whose
+    # kernels are Mosaic calls, keeps it.
+    from dryad_tpu.engine.histogram import resolve_backend
+
+    plat = platform or mesh.devices.flat[0].platform
+    interpreted = (plat == "cpu" and resolve_backend(
+        params.hist_backend, platform=plat) == "pallas")
     return jax.shard_map(
         run, mesh=mesh,
         in_specs=(row2, row, row, row, rep, rep) + (rep,) * len(extra),
         out_specs=(tree_specs, row),
-        check_vma=mode != "feature",
+        check_vma=mode != "feature" and not interpreted,
     )(Xb, g, h, bag_mask, feat_mask, is_cat_feat, *extra)
 
 

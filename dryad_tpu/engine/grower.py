@@ -69,17 +69,19 @@ def grow_any(params, total_bins, Xb, g, h, bag_mask, feat_mask, is_cat_feat,
     if params.growth == "leafwise":
         from dryad_tpu.engine import leafwise_fast
 
-        # GLOBAL rows (static at trace time): the batched-vs-sequential
-        # choice must not depend on the shard count, or N-shard ≡ 1-shard
-        # breaks — under shard_map Xb is the local shard.  Sharded callers
-        # pass the UNPADDED global N (local*n_shards counts the mesh pad,
-        # which varies with shard count and could flip the envelope at the
-        # boundary); single-device direct callers carry no pad.
+        # GLOBAL rows and the shard count (both static at trace time): the
+        # batched-vs-sequential choice is the envelope's, which reckons a
+        # device's share of the global rows — the same two numbers
+        # train_device resolved the cap with, never the local shape (under
+        # shard_map Xb is the local shard).  Sharded callers pass the
+        # UNPADDED global N (local*n_shards counts the mesh pad, which could
+        # flip the envelope at the boundary); single-device direct callers
+        # carry no pad.
+        n_shards = int(jax.lax.psum(1, axis_name)) if axis_name else 1
         if global_rows is None:
-            n_shards = int(jax.lax.psum(1, axis_name)) if axis_name else 1
             global_rows = Xb.shape[0] * n_shards
         if leafwise_fast.supports(params, Xb.shape[1], int(total_bins),
-                                  global_rows):
+                                  global_rows, n_shards):
             # depth-capped leaf-wise: exact best-first selection over a
             # level-synchronous full expansion — O(N·depth) instead of the
             # sequential grower's O(N·leaves) (gains are order-independent,

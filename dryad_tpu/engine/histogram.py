@@ -153,7 +153,9 @@ def build_hist(
     acc, _ = jax.lax.scan(body, acc0, (Xc, w))
     hist = acc.reshape(3, F, B)
     if axis_name is not None:
-        hist = jax.lax.psum(hist, axis_name)  # the NCCL-allreduce equivalent
+        from dryad_tpu.engine.distributed import reduce_hist
+
+        hist = reduce_hist(hist, axis_name)  # the NCCL-allreduce equivalent
     return hist
 
 
@@ -236,7 +238,9 @@ def build_hist_classes(
     cnt = jnp.broadcast_to(acc[2 * K].reshape(1, 1, F, B), (K, 1, F, B))
     hist = jnp.concatenate([gs, hs, cnt], axis=1)  # (K, 3, F, B)
     if axis_name is not None:
-        hist = jax.lax.psum(hist, axis_name)  # the NCCL-allreduce equivalent
+        from dryad_tpu.engine.distributed import reduce_hist
+
+        hist = reduce_hist(hist, axis_name)  # the NCCL-allreduce equivalent
     return hist
 
 

@@ -82,10 +82,10 @@ from dryad_tpu.config import (  # noqa: F401  (re-exported API)
 
 
 def supports(p: Params, num_features: int, total_bins: int,
-             num_rows: int | None = None) -> bool:
+             num_rows: int | None = None, n_shards: int = 1) -> bool:
     """Fast leaf-wise needs a finite, memory-feasible expansion depth.
 
-    One rule, by peak residency: LEAFWISE_PEAK_FACTOR x the PINNED
+    One rule, by peak residency ON ONE DEVICE: LEAFWISE_PEAK_FACTOR x the PINNED
     (Pf, 3, F, B) float32 buffer (hist_small/large/l/r, the 2P-wide children
     concat for the split finder, the kernels' padded output) plus what the
     rows stage, within LEAFWISE_TOTAL_BYTES_BUDGET; config.py has the
@@ -95,8 +95,11 @@ def supports(p: Params, num_features: int, total_bins: int,
     config.effective_depth_params, can consult it without touching jax; a
     config that disables hist_subtraction is rejected there too, because the
     expansion derives every larger sibling by subtraction.)  ``num_rows``
-    must be the GLOBAL row count (see config.leafwise_fast_supported)."""
-    return leafwise_fast_supported(p, num_features, total_bins, num_rows)
+    is the GLOBAL row count and ``n_shards`` the mesh's size: the rows
+    counted are a shard's, ``ceil(num_rows / n_shards)``, never the local
+    shape a traced shard sees (see config.leafwise_fast_supported)."""
+    return leafwise_fast_supported(p, num_features, total_bins, num_rows,
+                                   n_shards)
 
 
 def phase_plan(depth_cap: int):

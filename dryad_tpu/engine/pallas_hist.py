@@ -516,7 +516,9 @@ def build_hist_pallas(
         platform=platform,
     )[0]
     if axis_name is not None:
-        hist = jax.lax.psum(hist, axis_name)
+        from dryad_tpu.engine.distributed import reduce_hist
+
+        hist = reduce_hist(hist, axis_name)
     return hist
 
 

@@ -475,7 +475,7 @@ def _comm_stats(p, F: int, B: int, K: int, n_shards: int,
         from dryad_tpu.engine import leafwise_fast
 
         if (p.growth == "leafwise"
-                and leafwise_fast.supports(p, F, B, num_rows)):
+                and leafwise_fast.supports(p, F, B, num_rows, n_shards)):
             D = p.max_depth
             d_switch, P_narrow, Pf = leafwise_fast.phase_plan(D)
             scan_widths = [P_narrow] * d_switch + [Pf] * (D - d_switch)
@@ -812,8 +812,10 @@ def train_device(
     N, F = data.num_rows, data.num_features
     B = data.mapper.total_bins
     # documented max_depth=-1 policy (identical mapping on the CPU backend,
-    # so cross-backend parity is untouched)
-    p = effective_depth_params(p, F, B, N)
+    # so cross-backend parity is untouched); the envelope counts what one
+    # device holds, so a mesh's size enters beside the global rows
+    n_shards = mesh.devices.size if mesh is not None else 1
+    p = effective_depth_params(p, F, B, N, n_shards)
     obj = get_objective(p)
     K = p.num_outputs
     is_cat_np = data.mapper.is_categorical
@@ -840,8 +842,10 @@ def train_device(
             y_np = np.pad(y_np, (0, pad))
             if w_np is not None:
                 w_np = np.pad(w_np, (0, pad))
-        Xb, y = shard_rows(mesh, jnp.asarray(Xb_np), jnp.asarray(y_np))
-        weight = shard_rows(mesh, jnp.asarray(w_np))[0] if w_np is not None else None
+        # straight from the host: each device is sent its own rows, and no
+        # chip ever holds the table a mesh exists to spread
+        Xb, y = shard_rows(mesh, Xb_np, y_np)
+        weight = shard_rows(mesh, w_np)[0] if w_np is not None else None
     else:
         # memoized on the Dataset: repeated train calls (bench arms, warm
         # restarts, parameter sweeps) skip the X upload entirely.  On a
@@ -969,7 +973,7 @@ def train_device(
     from dryad_tpu.engine import leafwise_fast
 
     batched_leafwise = (p.growth == "leafwise"
-                        and leafwise_fast.supports(p, F, B, N))
+                        and leafwise_fast.supports(p, F, B, N, n_shards))
     out = _empty_out_device(T, p.max_nodes, CAT_WORDS,
                             grow_stats=batched_leafwise)
     if batched_leafwise and default_registry().enabled:
@@ -1043,6 +1047,16 @@ def train_device(
         jnp.broadcast_to(jnp.asarray(init), (v.num_rows, K)).astype(jnp.float32)
         for _, v in valids
     ]
+    if mesh is not None:
+        # a valid set is REPLICATED over the mesh, placed once: every device
+        # walks all its rows inside the chunk program and holds the same
+        # metric (the rank metrics sort the whole set, so a row-sharded eval
+        # would need a gather of the scores anyway; the walk is a few
+        # percent of an iteration).  Left uncommitted on one device, each
+        # dispatch would broadcast the matrix again.
+        from dryad_tpu.engine.distributed import replicate
+
+        vXbs, vscores = replicate(mesh, (vXbs, vscores))
     if init_booster is not None:
         vscores = [
             _accumulate(prev_trees, vXb, jnp.asarray(init),
@@ -1142,8 +1156,8 @@ def train_device(
                  and p.boosting != "dart")
     if chunkable:
         # chunk length is budgeted in SECONDS of device time per program,
-        # from a measured iteration-cost model calibrated at 10M rows x 28
-        # features x 256 bins (1.6e-7 s/row/class/pass) and scaled by F·B,
+        # from a measured iteration-cost model calibrated at 10M rows x 28 (a
+        # device's rows: NP // n_shards) x 256 bins (1.6e-7 s/row/class/pass), F·B-scaled,
         # since histogram work is O(N·F·B) per pass (Epsilon's 2000
         # features once packed a chunk ~70x past the budget).  Depthwise
         # pays one batched pass per level; leaf-wise one full-N masked pass
@@ -1161,7 +1175,7 @@ def train_device(
                 passes_est = p.max_depth
             else:
                 passes_est = max(8, p.effective_num_leaves - 1)
-        est_iter_s = (1.6e-7 * NP * K * passes_est
+        est_iter_s = (1.6e-7 * (NP // n_shards) * K * passes_est
                       * max(F / 28.0, 1.0) * max(B / 256.0, 1.0))
         # per-MAC model (round 4): histogram work is N·K·passes·F·B MACs and
         # 5e-15 s/MAC sits mid-range of the measured configs (10M Higgs
@@ -1170,7 +1184,7 @@ def train_device(
         # over-estimates up to 8x off its calibration point.  LambdaMART
         # keeps the over-estimating per-row model for chunk sizing: its λ
         # pass scales with query sizes the MAC model cannot see.
-        est_iter_mac = 0.05 + 5e-15 * NP * K * passes_est * F * B
+        est_iter_mac = 0.05 + 5e-15 * (NP // n_shards) * K * passes_est * F * B
         est_for_ch = (est_iter_s if p.objective == "lambdarank"
                       else est_iter_mac)
         # 25 s budget on the tighter model (was 40 s on the loose one),

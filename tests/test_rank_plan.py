@@ -175,3 +175,70 @@ def test_a_ranking_job_leaves_the_plans_gauges_in_the_registry():
              for k, v in gauges["dryad_rank_pair_cells"].items()}
     assert cells == {"padded": 30.0 * S * S, "own": float((group * group).sum()),
                      "kept": float((np.minimum(10, group) * group).sum())}
+
+
+# ---- the envelope counts what ONE DEVICE holds (PR 34) -----------------------
+
+CRITEO = dict(objective="binary", min_data_in_leaf=20, **LEAF255)
+
+
+@pytest.mark.parametrize("rows,features,shards,cap", [
+    (12_000_000, 67, 1, -1),    # criteo12m_leaf255 on one chip: 4.64 GB pinned + 12.0 GB of rows
+    (12_000_000, 67, 2, 12),    # 4.64 + 5.98
+    (12_000_000, 67, 4, 12),    # the cell: 4.64 + 2.99 = 7.63 GB reckoned, 5.18 compiled
+    (32_000_000, 67, 4, 12),    # 8M rows a chip: the most the rule gives four chips at cap 12
+    (34_000_000, 67, 4, 11),    # one level down
+    (10_000_000, 136, 1, -1),   # PR 32's refused shape ...
+    (10_000_000, 136, 4, 11),   # ... a mesh holds one level down (9.4 GB pinned at cap 12)
+    (2_270_296, 136, 4, 12),    # mslr2m_leaf255's shape: as on one chip
+    (12_000_000, 67, 5, 12),    # rows that do not divide: a shard's share is rounded up
+])
+def test_the_envelope_reckons_a_devices_share_of_the_rows(rows, features, shards, cap):
+    p = make_params(CRITEO)
+    got = effective_depth_params(p, features, 256, rows, shards)
+    assert got.max_depth == cap and (got is p) == (cap == -1)
+    share = -(-rows // shards)
+    for depth in (12, 11, 10):
+        fits = reckoned(share, features, depth) <= LEAFWISE_TOTAL_BYTES_BUDGET
+        assert fits == (cap >= depth)
+        assert fits == leafwise_fast_supported(p.replace(max_depth=depth), features, 256, rows,
+                                               shards)
+        # one device holding a shard's rows is given what the mesh's device is
+        assert fits == leafwise_fast_supported(p.replace(max_depth=depth), features, 256, share)
+
+
+def test_one_chip_refuses_the_data_parallel_cell_at_every_cap_and_four_admit_it():
+    p = make_params(CRITEO)
+    for depth in (12, 11, 10):
+        assert not leafwise_fast_supported(p.replace(max_depth=depth), 67, 256, 12_000_000)
+        assert not leafwise_fast_supported(p.replace(max_depth=depth), 67, 256, 12_000_000, 1)
+    assert leafwise_fast_supported(p.replace(max_depth=12), 67, 256, 12_000_000, 4)
+    assert effective_depth_params(p, 67, 256, 12_000_000) is p            # the sequential grower
+    assert effective_depth_params(p, 67, 256, 12_000_000, 4).max_depth == 12
+    # more shards never refuse what fewer admit
+    verdicts = [leafwise_fast_supported(p.replace(max_depth=12), 67, 256, 40_000_000, n)
+                for n in (1, 2, 4, 8, 16)]
+    assert verdicts == sorted(verdicts)
+    # shape-only callers and every shape the parity tests run: the shard count changes nothing
+    assert effective_depth_params(p, 67, 256, None, 4).max_depth == 12
+    for n in (1, 4, 8):
+        assert effective_depth_params(p, 28, 32, 4096, n).max_depth == 12
+
+
+# The sharded iteration (``scripts/envelope_aot.py rows,features,depth,leaves,shards``),
+# compiled ahead of time for four chips of a v5e:2x2 (PR 34): global rows, features,
+# max_depth, num_leaves, shards, one device's temp_size_in_bytes and argument_size_in_bytes.
+COMPILED_SHARDED = [
+    (12_000_000, 67, 12, 255, 4, 5_182_831_616, 243_042_304),
+    (2_000_000, 67, 12, 255, 4, 4_457_256_960, 40_546_816),
+    (500_000, 67, 12, 255, 4, 4_396_321_280, 10_169_856),
+]
+
+
+@pytest.mark.parametrize("rows,features,depth,leaves,shards,temp,args", COMPILED_SHARDED)
+def test_the_envelope_envelops_the_sharded_iteration_too(rows, features, depth, leaves, shards,
+                                                         temp, args):
+    p = make_params(dict(growth="leafwise", num_leaves=leaves, max_depth=depth))
+    assert leafwise_fast_supported(p, features, 256, rows, shards)
+    assert reckoned(-(-rows // shards), features, depth) >= temp
+    assert temp + args <= HBM_BYTES

@@ -21,6 +21,8 @@ EIGHT = {"dryad.grad", "dryad.hist", "dryad.route", "dryad.layout",
          "dryad.split_scan", "dryad.select", "dryad.score", "dryad.eval"}
 # what a program of the level-wise grower carries: it selects nothing
 SEVEN = EIGHT - {"dryad.select"}
+# the ninth (PR 34): the cross-shard exchange, in a program that runs on a mesh
+NINE = EIGHT | {"dryad.reduce"}
 BASE = dict(objective="binary", num_trees=4, num_leaves=7, max_depth=3,
             max_bins=32, seed=3, min_data_in_leaf=5, growth="depthwise")
 
@@ -44,7 +46,7 @@ class _Lowered(Exception):
     pass
 
 
-def _lowered_text(monkeypatch, sets, program, params):
+def _lowered_text(monkeypatch, sets, program, params, mesh=None):
     """The MLIR text, locations included, of the first ``program`` that a
     tiny job with a valid set would dispatch; the job is stopped there."""
     monkeypatch.setenv("DRYAD_CHUNK", "1" if program == "_chunk_jit" else "0")
@@ -56,7 +58,7 @@ def _lowered_text(monkeypatch, sets, program, params):
     monkeypatch.setattr(train, program, lower_and_stop)
     ds, vds = sets
     with pytest.raises(_Lowered) as caught:
-        dryad.train(params, ds, valid_sets=[vds], backend="tpu")
+        dryad.train(params, ds, valid_sets=[vds], backend="tpu", mesh=mesh)
     return str(caught.value)
 
 
@@ -81,16 +83,37 @@ def test_lowered_program_names_its_stages(monkeypatch, sets, program, extra, exp
     assert found <= EIGHT
 
 
-def test_engine_sources_name_the_eight_and_no_ninth():
+@pytest.mark.parametrize("extra,expected", [
+    (dict(growth="leafwise", max_depth=-1), NINE - {"dryad.layout"}),       # psum, fused arm
+    (dict(hist_reduce="feature"), SEVEN - {"dryad.layout"} | {"dryad.reduce"}),
+], ids=["mesh-leafwise-fused", "mesh-levelwise-feature"])
+def test_a_sharded_program_names_the_exchange(monkeypatch, sets, extra, expected):
+    """On a mesh the histogram all-reduce (either arm, and the feature arm's
+    all-gather of best splits) is the innermost scope of its operations."""
+    from dryad_tpu.engine.distributed import make_mesh
+
+    text = _lowered_text(monkeypatch, sets, "_chunk_jit", dict(BASE, **extra),
+                         mesh=make_mesh(jax.devices()[:4]))
+    assert set(re.findall(r"dryad\.[A-Za-z_0-9]+", text)) == expected
+    # the collectives themselves sit directly in the scope, and nowhere else
+    inside = set(re.findall(r"dryad\.reduce/(\w+)", text))
+    wanted = {"reduce_scatter", "all_gather"} if "hist_reduce" in extra else {"psum"}
+    assert all(any(op.startswith(w) for op in inside) for w in wanted), inside
+    outside = re.findall(r'"(?:(?!dryad\.reduce)[^"])*/(?:psum|reduce_scatter|all_gather)\w*"',
+                         text)
+    assert not [name for name in outside if "dryad.hist" in name or "dryad.split_scan" in name]
+
+
+def test_engine_sources_name_the_nine_and_no_tenth():
     """What the acceptance grep reads: every ``named_scope`` under
-    ``dryad_tpu/engine`` takes one of the eight names, and each is used."""
+    ``dryad_tpu/engine`` takes one of the nine names, and each is used."""
     root = os.path.dirname(os.path.abspath(train.__file__))
     used = set()
     for name in os.listdir(root):
         if name.endswith(".py"):
             with open(os.path.join(root, name), encoding="utf-8") as f:
                 used |= set(re.findall(r'named_scope\("([^"]*)"\)', f.read()))
-    assert used == EIGHT
+    assert used == NINE
 
 
 def _pallas_names(fn, *args, **kw):
@@ -178,7 +201,7 @@ def test_scopes_add_no_equation(arm):
 
     walk(closed.jaxpr)
     scoped = {s for stack in stacks for s in re.findall(r"dryad\.[a-z_]+", stack)}
-    assert {"dryad.grad", "dryad.hist", "dryad.split_scan", "dryad.score"} <= scoped <= EIGHT
+    assert {"dryad.grad", "dryad.hist", "dryad.split_scan", "dryad.score"} <= scoped <= NINE
     digest = jaxpr_audit.canonical_digest(closed)
     assert digest.startswith(SEED_DIGESTS[arm])
     assert load_goldens()["arms"][arm]["digest"] == digest
