@@ -90,6 +90,15 @@ class Census:
     # is this count: 1 per level vs the legacy structure-of-arrays 7.
     # Trip-weighted like everything else.
     table_gathers: int = 0
+    # the score update's per-row look-ups (PR 35): gathers inside scope
+    # ``dryad.score`` whose INDEX is row-sized — one a tree, from the
+    # composed (keys, 2) record table (train._row_records); two where leaf
+    # renewal needs the leaf before the value.  ``score_flat_gathers`` are
+    # those among them whose table has no minor dimension of at least two
+    # words: the 1-D form, which costs four times as much an index on the
+    # chip (PERF.md) and which no arm may hold.
+    score_row_gathers: int = 0
+    score_flat_gathers: int = 0
     pallas_kernels: dict = field(default_factory=dict)  # name -> set of sigs
     dynamic_loop: bool = False
     branch_mismatch: bool = False
@@ -98,6 +107,7 @@ class Census:
         out = Census(Counter({p: n * k for p, n in self.collectives.items()}),
                      self.global_row_sorts * k, self.local_row_sorts * k,
                      self.row_gathers * k, self.table_gathers * k,
+                     self.score_row_gathers * k, self.score_flat_gathers * k,
                      {n: set(s) for n, s in self.pallas_kernels.items()},
                      self.dynamic_loop, self.branch_mismatch)
         return out
@@ -108,6 +118,8 @@ class Census:
         self.local_row_sorts += other.local_row_sorts
         self.row_gathers += other.row_gathers
         self.table_gathers += other.table_gathers
+        self.score_row_gathers += other.score_row_gathers
+        self.score_flat_gathers += other.score_flat_gathers
         for name, sigs in other.pallas_kernels.items():
             self.pallas_kernels.setdefault(name, set()).update(sigs)
         self.dynamic_loop |= other.dynamic_loop
@@ -126,22 +138,26 @@ def _aval_sig(v) -> str:
     return f"{getattr(aval, 'dtype', '?')}{tuple(getattr(aval, 'shape', ()))}"
 
 
+def _shape(v) -> tuple:
+    return tuple(getattr(getattr(v, "aval", None), "shape", ()) or ())
+
+
 def _max_rows(eqn) -> int:
-    best = 0
-    for v in list(eqn.invars) + list(eqn.outvars):
-        shape = tuple(getattr(getattr(v, "aval", None), "shape", ()) or ())
-        if shape:
-            best = max(best, int(shape[0]))
-    return best
+    return max((int(_shape(v)[0])
+                for v in list(eqn.invars) + list(eqn.outvars) if _shape(v)),
+               default=0)
 
 
-def census_jaxpr(jaxpr, row_threshold: int,
-                 in_shard_map: bool = False) -> Census:
-    """Trip-weighted census of one (possibly closed) jaxpr."""
+def census_jaxpr(jaxpr, row_threshold: int, in_shard_map: bool = False,
+                 stack: str = "") -> Census:
+    """Trip-weighted census of one (possibly closed) jaxpr.  ``stack`` is
+    the name stack of the equations that enclose it (a sub-jaxpr's own
+    stacks start afresh)."""
     j = jaxpr.jaxpr if hasattr(jaxpr, "jaxpr") else jaxpr
     out = Census()
     for eqn in j.eqns:
         name = eqn.primitive.name
+        here = stack + "/" + str(eqn.source_info.name_stack)
         if name in _COLLECTIVES:
             out.collectives[_CANONICAL.get(name, name)] += 1
         elif name == "sort" and _max_rows(eqn) >= row_threshold:
@@ -154,6 +170,11 @@ def census_jaxpr(jaxpr, row_threshold: int,
                 out.row_gathers += 1
             else:
                 out.table_gathers += 1
+            table, index = _shape(eqn.invars[0]), _shape(eqn.invars[1])
+            if ("dryad.score" in here and index
+                    and index[0] >= row_threshold):
+                out.score_row_gathers += 1
+                out.score_flat_gathers += len(table) < 2 or table[-1] < 2
         elif name == "pallas_call":
             # the call's explicit name, else the kernel body's function
             kname = (eqn.params.get("name")
@@ -167,16 +188,16 @@ def census_jaxpr(jaxpr, row_threshold: int,
         if name == "scan":
             length = int(eqn.params.get("length", 1))
             for _, sub, _ in subs:
-                out.add(census_jaxpr(sub, row_threshold,
-                                     sub_in_sm).scaled(length))
+                out.add(census_jaxpr(sub, row_threshold, sub_in_sm,
+                                     here).scaled(length))
         elif name == "while":
             inner = Census()
             for _, sub, _ in subs:
-                inner.add(census_jaxpr(sub, row_threshold, sub_in_sm))
+                inner.add(census_jaxpr(sub, row_threshold, sub_in_sm, here))
             inner.dynamic_loop |= inner.interesting
             out.add(inner)
         elif name == "cond":
-            branches = [census_jaxpr(sub, row_threshold, sub_in_sm)
+            branches = [census_jaxpr(sub, row_threshold, sub_in_sm, here)
                         for _, sub, _ in subs]
             if branches:
                 merged = branches[0]
@@ -195,6 +216,10 @@ def census_jaxpr(jaxpr, row_threshold: int,
                     merged.row_gathers = max(merged.row_gathers, b.row_gathers)
                     merged.table_gathers = max(merged.table_gathers,
                                                b.table_gathers)
+                    merged.score_row_gathers = max(merged.score_row_gathers,
+                                                   b.score_row_gathers)
+                    merged.score_flat_gathers = max(merged.score_flat_gathers,
+                                                    b.score_flat_gathers)
                     for n, s in b.pallas_kernels.items():
                         merged.pallas_kernels.setdefault(n, set()).update(s)
                     merged.dynamic_loop |= b.dynamic_loop
@@ -202,7 +227,7 @@ def census_jaxpr(jaxpr, row_threshold: int,
                 out.add(merged)
         else:
             for _, sub, _ in subs:
-                out.add(census_jaxpr(sub, row_threshold, sub_in_sm))
+                out.add(census_jaxpr(sub, row_threshold, sub_in_sm, here))
     return out
 
 
@@ -282,6 +307,9 @@ def _train_arm(params: dict, *, N=2048, F=8, platform="tpu", K=1,
         "rows_threshold": N // N_SHARDS,
         "expected_psums": comm["psum_calls_per_iter"],
         "comm": comm,
+        # one record gather a tree; renewal reads the leaf from one and
+        # the renewed value from a second
+        "expected_score_gathers": K * (2 if renewal else 1),
     }
     return fn, audit_iteration_args(p, N, F, K), meta
 
@@ -521,6 +549,7 @@ class ArmReport:
             "local_row_sorts": self.census.local_row_sorts,
             "row_gathers": self.census.row_gathers,
             "table_gathers": self.census.table_gathers,
+            "score_row_gathers": self.census.score_row_gathers,
             "pallas_kernels": {k: sorted(v) for k, v in
                                sorted(self.census.pallas_kernels.items())},
         }
@@ -616,6 +645,15 @@ def trace_arm(name: str) -> ArmReport:
             "per-level lookup budget drifted (packed arm: exactly 1 "
             "node-word gather/level; gather cost is per-ACCESS, so every "
             "extra lookup is a real per-level cost)")
+    exp_score = meta.get("expected_score_gathers", 0)
+    if (census.score_row_gathers, census.score_flat_gathers) != (exp_score, 0):
+        rep.failures.append(
+            f"scope dryad.score holds {census.score_row_gathers} row-sized "
+            f"gather(s), {census.score_flat_gathers} of them from a table "
+            f"with no two-word minor dimension; expected {exp_score} and 0 "
+            "— the score update looks each row up once, in the composed "
+            "(keys, 2) record table (train._row_records); a 1-D table "
+            "gather costs four times as much an index on the chip")
     if expect.get("wired") and census.local_row_sorts:
         rep.failures.append(
             f"{census.local_row_sorts} row-scale sort(s) inside the wired "
@@ -677,7 +715,7 @@ def run_audit(arm_names=None, goldens_path: Optional[str] = None,
             continue
         for key in ("digest", "collectives", "global_row_sorts",
                     "local_row_sorts", "row_gathers", "table_gathers",
-                    "pallas_kernels"):
+                    "score_row_gathers", "pallas_kernels"):
             if stored[name].get(key) != payloads[name][key]:
                 report.drift.append(
                     f"{name}: {key} drifted from golden "

@@ -182,28 +182,26 @@ def grow_sharded(params: Params, total_bins: int, has_cat: bool,
                  mesh: Mesh, Xb, g, h, bag_mask, feat_mask, is_cat_feat,
                  platform=None, learn_missing=False, root_hist=None,
                  bundled_mask=None, global_rows=None):
-    """One sharded tree grow; returns (replicated tree, row-sharded leaves).
+    """One sharded tree grow; returns the grower's tree dict.
 
-    Called inside the device train step's jit: the tree arrays come back
-    replicated, the per-row leaf assignment keeps the row sharding so the
-    caller's score update stays shard-local.  ``root_hist`` (replicated)
-    carries the class's slice of the shared-plan multiclass root pass.
+    Called inside the device train step's jit: the tree arrays and the
+    key -> leaf table come back replicated, each row's partition key
+    (``row_key``) keeps the row sharding so the caller's score update stays
+    shard-local.  ``root_hist`` (replicated) carries the class's slice of
+    the shared-plan multiclass root pass.
     """
     from dryad_tpu.engine.grower import grow_any  # lazy: builders import us
 
     def run(Xb_l, g_l, h_l, bag_l, fmask, iscat, *extras):
         extras = list(extras)
         bmask_l = extras.pop(0) if bundled_mask is not None else None
-        tree = grow_any(
+        return grow_any(
             params, total_bins, Xb_l, g_l, h_l, bag_l, fmask, iscat,
             has_cat=has_cat, axis_name=AXIS, platform=platform,
             learn_missing=learn_missing,
             root_hist=extras[0] if extras else None,
             bundled_mask=bmask_l, global_rows=global_rows,
         )
-        # per-shard leaf ids straight from the grower's partition state
-        leaves = tree.pop("row_leaf")
-        return tree, leaves
 
     row = P(AXIS)
     row2 = P(AXIS, None)
@@ -212,6 +210,7 @@ def grow_sharded(params: Params, total_bins: int, has_cat: bool,
         "feature": rep, "threshold": rep, "left": rep, "right": rep,
         "value": rep, "gain": rep, "is_cat": rep, "cat_bitset": rep,
         "default_left": rep, "cover": rep, "max_depth": rep,
+        "row_key": row, "key_leaf": rep,
     }
     extra = () if bundled_mask is None else (bundled_mask,)
     extra += () if root_hist is None else (root_hist,)
@@ -251,7 +250,7 @@ def grow_sharded(params: Params, total_bins: int, has_cat: bool,
     return jax.shard_map(
         run, mesh=mesh,
         in_specs=(row2, row, row, row, rep, rep) + (rep,) * len(extra),
-        out_specs=(tree_specs, row),
+        out_specs=tree_specs,
         check_vma=mode != "feature" and not interpreted,
     )(Xb, g, h, bag_mask, feat_mask, is_cat_feat, *extra)
 

@@ -432,11 +432,6 @@ def grow_tree(
     )
     cat_bitset = pack_cat_bitset(st["cat_mask_nodes"], M)
 
-    # per-row leaf node id, straight from the partition state — the
-    # train step's score update uses this instead of re-traversing
-    with jax.named_scope("dryad.score"):
-        row_leaf = jnp.maximum(st["slot_node"], 0)[
-            jnp.minimum(st["row_slot"], L - 1)]
     return {
         "feature": st["feature"],
         "threshold": st["threshold"],
@@ -449,5 +444,8 @@ def grow_tree(
         "cat_bitset": cat_bitset,
         "default_left": st["node_dleft"],
         "max_depth": st["max_depth"],
-        "row_leaf": row_leaf,
+        # each row's leaf is key_leaf[row_key], straight from the partition
+        # state; the train step gathers once a row (train._row_records)
+        "row_key": st["row_slot"],
+        "key_leaf": jnp.maximum(st["slot_node"], 0),
     }

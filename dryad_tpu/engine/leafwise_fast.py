@@ -722,8 +722,6 @@ def grow_tree_leafwise_batched(
                                           leaf_of[par]),
                                 leaf_of)
 
-    with jax.named_scope("dryad.score"):
-        row_leaf = leaf_of[jnp.clip(exp_st["row_node"], 0, HN - 1)]
     return {
         "feature": sel_st["feature"],
         "threshold": sel_st["threshold"],
@@ -736,7 +734,10 @@ def grow_tree_leafwise_batched(
         "default_left": sel_st["node_dleft"],
         "cover": sel_st["cover"],
         "max_depth": sel_st["max_depth"],
-        "row_leaf": row_leaf,
+        # each row's leaf is key_leaf[row_key], the heap node it was routed
+        # to; the train step gathers once a row (train._row_records)
+        "row_key": exp_st["row_node"],
+        "key_leaf": leaf_of,
         # what the expansion grew against what the selection kept (obs
         # counters dryad_leafwise_{expanded,selected}_splits_total)
         "expanded_splits": jnp.sum(nd_gain > NEG_INF, dtype=jnp.int32),

@@ -825,7 +825,7 @@ def grow_tree_levelwise(
         # sentinel-flagged records and are dropped by level 0's move.
         # The shallow->deep handoff sort+gather per tree is GONE — the
         # natural-order row_slot (still maintained above for the final
-        # row_leaf) keeps routing out-of-bag rows.
+        # ``row_key``) keeps routing out-of-bag rows.
         rec_nat = leafperm.make_layout_records(Xb, g, h, valid=bag_mask)
         lay_rec, lay_tr, lay_rs = leafperm.natural_root_layout(
             rec_nat, L, n_buf_tiles, axis_name=axis_name)
@@ -857,11 +857,6 @@ def grow_tree_levelwise(
     )
     cat_bitset = pack_cat_bitset(st["cat_nodes"], M)
 
-    # per-row leaf node id from the partition state (no re-traversal); only
-    # the train step's score update reads it
-    with jax.named_scope("dryad.score"):
-        row_leaf = jnp.maximum(st["slot_node"], 0)[
-            jnp.minimum(st["row_slot"], L - 1)]
     return {
         "feature": st["feature"],
         "threshold": st["threshold"],
@@ -874,5 +869,9 @@ def grow_tree_levelwise(
         "default_left": st["node_dleft"],
         "cover": st["cover"],
         "max_depth": st["max_depth"],
-        "row_leaf": row_leaf,
+        # each row's leaf is key_leaf[row_key], from the partition state (no
+        # re-traversal); the train step composes that look-up with the
+        # leaf's value and gathers once a row (train._row_records)
+        "row_key": st["row_slot"],
+        "key_leaf": jnp.maximum(st["slot_node"], 0),
     }

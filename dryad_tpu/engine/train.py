@@ -107,6 +107,32 @@ def _renew_values(value, feature, leaves, y, score_k, bag, alpha, lr, M):
     return jnp.where((feature < 0) & (cnt > 0), stat, value)
 
 
+def _row_records(key_leaf, value, row_key):
+    """Each row's leaf value and leaf id, ``value[key_leaf[row_key]]`` and
+    ``key_leaf[row_key]``, from ONE look-up a row.
+
+    The two tables are tiny (leaf slots or heap nodes -> tree node ->
+    value), so they are composed first, table-sized, into one two-word
+    record a key — word 0 the f32 value's own bits, word 1 the leaf id —
+    and the rows gather that.  A row-sized gather from a table of two-word
+    rows costs a quarter of one from a 1-D table on the chip (21.0 against
+    86.7 ms for 10M indices, PERF.md), and the value is bitwise the one the
+    two look-ups in sequence give."""
+    rec = jnp.stack(
+        [jax.lax.bitcast_convert_type(value[key_leaf], jnp.uint32),
+         key_leaf.astype(jnp.uint32)], axis=1)
+    got = rec[jnp.clip(row_key, 0, key_leaf.shape[0] - 1)]
+    # each word by a masked reduce over the record's two, not by a slice:
+    # the result is rank 1, where a sliced (N, 1) column (and the score
+    # column added to it) was kept in the gather's padded two-word layout,
+    # 512 B a row (the compiler's account at 10M x 28: 2.5 GB of temporaries)
+    bits, leaf = (jnp.bitwise_or.reduce(got & jnp.array(mask, jnp.uint32),
+                                        axis=1)
+                  for mask in ((0xFFFFFFFF, 0), (0, 0xFFFFFFFF)))
+    return (jax.lax.bitcast_convert_type(bits, jnp.float32),
+            leaf.astype(jnp.int32))
+
+
 def _step_body(p, B, has_cat, mesh, platform, learn_missing, out, score, Xb,
                g_all, h_all, bag, fmask, is_cat_feat, t, k, root_hist=None,
                bmask=None, n_rows=None, value_scale=None, y=None,
@@ -127,7 +153,7 @@ def _step_body(p, B, has_cat, mesh, platform, learn_missing, out, score, Xb,
     if mesh is not None:
         from dryad_tpu.engine.distributed import grow_sharded
 
-        tree, leaves = grow_sharded(
+        tree = grow_sharded(
             p, B, has_cat, mesh, Xb, g, h, bag, fmask, is_cat_feat,
             platform=platform, learn_missing=learn_missing,
             root_hist=root_hist, bundled_mask=bmask,
@@ -140,23 +166,28 @@ def _step_body(p, B, has_cat, mesh, platform, learn_missing, out, score, Xb,
                         has_cat=has_cat, platform=platform,
                         learn_missing=learn_missing, root_hist=root_hist,
                         bundled_mask=bmask)
-        # each row's leaf comes straight out of the grower's partition
-        # state — re-traversing 10M rows cost ~5 s/tree (gather-bound)
-        leaves = tree.pop("row_leaf")
+    # each row's leaf comes straight out of the grower's partition state
+    # (key_leaf[row_key]) — re-traversing 10M rows cost ~5 s/tree
+    row_key, key_leaf = tree.pop("row_key"), tree.pop("key_leaf")
     for key in _GROW_STATS:
         if key in tree and key in out:
             out[key] = out[key].at[t].set(tree[key])
     with jax.named_scope("dryad.score"):
+        value = tree["value"]
         if renew_alpha is not None:
-            tree = dict(tree, value=_renew_values(
-                tree["value"], tree["feature"], leaves, y,
+            # renewal needs each row's leaf before the leaf has its value
+            _, leaves = _row_records(key_leaf, value, row_key)
+            value = _renew_values(
+                value, tree["feature"], leaves, y,
                 jnp.take(score, k, axis=1), bag, renew_alpha,
-                p.effective_learning_rate, p.max_nodes))
+                p.effective_learning_rate, p.max_nodes)
         if value_scale is not None:
             # DART: the new tree lands pre-scaled by 1/(k+1) — same f32
             # multiply order as the CPU mirror (finalize with lr, then scale)
-            tree = dict(tree, value=tree["value"] * value_scale)
-        col = jnp.take(score, k, axis=1) + tree["value"][leaves]
+            value = value * value_scale
+        tree = dict(tree, value=value)
+        row_value, _ = _row_records(key_leaf, value, row_key)
+        col = jnp.take(score, k, axis=1) + row_value
         score = jax.lax.dynamic_update_index_in_dim(score, col, k, axis=1)
         for key in _TREE_KEYS:
             out[key] = out[key].at[t].set(tree[key])

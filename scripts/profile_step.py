@@ -24,6 +24,7 @@ from dryad_tpu.config import make_params
 from dryad_tpu.engine.grower import grow_any
 from dryad_tpu.engine.predict import tree_leaves
 from dryad_tpu.engine.probes import timed_fori
+from dryad_tpu.engine.train import _row_records
 from dryad_tpu.objectives import get_objective
 
 
@@ -96,11 +97,12 @@ def main():
 
     show("score update:", upd_step, leaves, tree["value"], sc)
 
-    # full step: grow + score update via the grower's row_leaf
+    # full step: grow + score update via the grower's row_key/key_leaf
     def full_step(s, X, gg, hh, bb, fmask, iscat, sc):
         tr = grow_any(p, B, X, gg + s, hh, bb, fmask, iscat,
                       has_cat=False, platform=plat)
-        col = jnp.take(sc, 0, axis=1) + tr["value"][tr["row_leaf"]]
+        col = jnp.take(sc, 0, axis=1) + _row_records(
+            tr["key_leaf"], tr["value"], tr["row_key"])[0]
         return s + 1.0, jnp.sum(col) * jnp.float32(1.0 / N)
 
     t_full = show("grow+update(rowleaf):", full_step, Xb, g, h, bag,

@@ -427,6 +427,19 @@ def smoke_predict_packed_parity():
           "packed ≡ legacy bitwise (one node-word gather per level)")
 
 
+def _warm_ms(fn, *args):
+    """``fn(*args)`` once to compile, then once more on the host's clock:
+    (result, milliseconds)."""
+    import time
+
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    got = jax.block_until_ready(fn(*args))
+    return got, (time.perf_counter() - t0) * 1e3
+
+
 def smoke_eval_walk_parity():
     """The training eval's walk (``train._fresh_tree``: the fresh tree's
     fields packed ON THE DEVICE, one node-word gather a level and
@@ -438,8 +451,6 @@ def smoke_eval_walk_parity():
     reduce at these widths.  Both walks take the tree slot and the depth
     traced, as the trainer passes them; the times are one warm walk each
     on the host's clock (informational)."""
-    import time
-
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -477,12 +488,6 @@ def smoke_eval_walk_parity():
         tree = {key: out[key][t] for key in engine_train._TREE_KEYS}
         return tree_leaves(tree, Xb, depth)
 
-    def timed(fn, *args):
-        fn(*args).block_until_ready()           # compiles
-        t0 = time.perf_counter()
-        got = fn(*args).block_until_ready()
-        return got, (time.perf_counter() - t0) * 1e3
-
     rng = np.random.default_rng(33)
     for N, F, M, cap in ((500_000, 28, 511, 12), (100_000, 2000, 127, 6)):
         out_np, depth = random_tree(rng, M, F, 256, cap)
@@ -491,8 +496,8 @@ def smoke_eval_walk_parity():
         Xb[rng.random((N, F)) < 0.1] = 0        # the missing bin
         out = {k: jnp.asarray(v) for k, v in out_np.items()}
         args = (out, jnp.int32(0), jnp.asarray(Xb), jnp.int32(depth))
-        packed, ms_p = timed(walk_packed, *args)
-        soa, ms_s = timed(walk_soa, *args)
+        packed, ms_p = _warm_ms(walk_packed, *args)
+        soa, ms_s = _warm_ms(walk_soa, *args)
         np.testing.assert_array_equal(
             np.asarray(packed), np.asarray(soa),
             err_msg=f"eval walk {N} x {F} to depth {depth}")
@@ -500,6 +505,50 @@ def smoke_eval_walk_parity():
         print(f"eval walk {N} x {F} to depth {depth}: packed == "
               f"structure of arrays bitwise ({len(np.unique(packed))} "
               f"leaves reached); one walk {ms_p:.2f} ms against {ms_s:.2f}")
+
+
+def smoke_score_update_parity():
+    """The score update's record gather (``train._row_records``: one
+    look-up a row in the composed ``(keys, 2)`` u32 table) vs the two 1-D
+    gathers it replaced, ``value[key_leaf[row_key]]``, on the real device,
+    bitwise, at the Higgs cells' own sizes: 10,000,000 keys into the 256
+    leaf slots of the depth-wise grower and into the 8192 heap nodes of the
+    batched leaf-wise one at cap 12, 511 tree nodes either way.  The values
+    are arbitrary bit patterns (NaN payloads, signed zeros, denormals), so
+    what the chip vouches for is that the bitcast round trip and the
+    two-word gather hand back the f32's own bits.  The times are one warm
+    look-up each on the host's clock (informational)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dryad_tpu.engine.train import _row_records
+
+    @jax.jit
+    def two_lookups(key_leaf, value, row_key):
+        leaves = key_leaf[jnp.clip(row_key, 0, key_leaf.shape[0] - 1)]
+        return value[leaves], leaves
+
+    rng = np.random.default_rng(35)
+    N, M = 10_000_000, 511
+    for keys in (256, 8192):
+        key_leaf = jnp.asarray(rng.integers(0, M, keys).astype(np.int32))
+        value = jnp.asarray(rng.integers(0, 1 << 32, M, dtype=np.uint32)
+                            .view(np.float32))
+        # a few keys past the table's end: the clip is part of the look-up
+        row_key = jnp.asarray(rng.integers(0, keys + 2, N).astype(np.int32))
+        (v_rec, l_rec), ms_r = _warm_ms(jax.jit(_row_records), key_leaf,
+                                        value, row_key)
+        (v_two, l_two), ms_t = _warm_ms(two_lookups, key_leaf, value,
+                                        row_key)
+        np.testing.assert_array_equal(
+            np.asarray(v_rec).view(np.uint32), np.asarray(v_two).view(np.uint32),
+            err_msg=f"score update values, {keys} keys")
+        np.testing.assert_array_equal(
+            np.asarray(l_rec), np.asarray(l_two),
+            err_msg=f"score update leaves, {keys} keys")
+        print(f"score update {N} keys into {keys}: record gather == two 1-D "
+              f"gathers bitwise; one look-up {ms_r:.2f} ms against {ms_t:.2f}")
 
 
 def smoke_stage_profiler():
@@ -566,6 +615,7 @@ _ALL_SMOKES = [
     smoke_hist_reduce_parity,
     smoke_predict_packed_parity,
     smoke_eval_walk_parity,
+    smoke_score_update_parity,
     smoke_stage_profiler,
 ]
 

@@ -203,6 +203,51 @@ def test_soa_eval_walk_is_caught():
     assert c.table_gathers + c.row_gathers == 6 * 8
 
 
+@pytest.mark.parametrize("name,trees", [
+    ("levelwise_wired", 1), ("levelwise_legacy", 1), ("leafwise_wired", 1),
+    ("goss_iteration", 1), ("multiclass_shared_roots", 3),
+    ("levelwise_feature", 1), ("leafwise_feature", 1)])
+def test_score_update_gathers_once_a_tree_from_records(audit_report, name,
+                                                       trees):
+    """The static form of "the score update's mechanism engaged" (PR 35):
+    scope ``dryad.score`` holds ONE row-sized gather a tree, from the
+    composed ``(keys, 2)`` record table, and none from a 1-D table."""
+    c = _arm(audit_report, name).census
+    assert (c.score_row_gathers, c.score_flat_gathers) == (trees, 0)
+
+
+def test_renewal_pays_a_second_record_gather(audit_report):
+    """Leaf renewal needs each row's leaf before the leaf has its value:
+    the leaf from one record gather, the renewed value from a second, both
+    of the two-word form."""
+    c = _arm(audit_report, "renewal_iteration").census
+    assert (c.score_row_gathers, c.score_flat_gathers) == (2, 0)
+
+
+def test_the_pair_of_flat_score_gathers_is_caught():
+    """Mutation direction: the score update as it was, each row's leaf from
+    a 1-D table and its value from another, reads two row-sized gathers in
+    ``dryad.score``, both flat; ``_row_records`` in its place reads one and
+    none."""
+    from dryad_tpu.engine.train import _row_records
+
+    def flat(key_leaf, value, row_key):
+        with jax.named_scope("dryad.score"):
+            return value[key_leaf[jnp.minimum(row_key, 254)]]
+
+    def records(key_leaf, value, row_key):
+        with jax.named_scope("dryad.score"):
+            return _row_records(key_leaf, value, row_key)[0]
+
+    sds = jax.ShapeDtypeStruct
+    args = (sds((255,), jnp.int32), sds((511,), jnp.float32),
+            sds((2048,), jnp.int32))
+    c = census_jaxpr(jax.make_jaxpr(flat)(*args), 256)
+    assert (c.score_row_gathers, c.score_flat_gathers) == (2, 2)
+    c = census_jaxpr(jax.make_jaxpr(records)(*args), 256)
+    assert (c.score_row_gathers, c.score_flat_gathers) == (1, 0)
+
+
 def test_only_documented_collectives_anywhere(audit_report):
     """fused arms: psum only.  feature arms (r16): psum (root) +
     reduce_scatter + all_gather (+ the communication-free axis_index the

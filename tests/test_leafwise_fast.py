@@ -39,6 +39,13 @@ def _fixture(n=20_000, f=8, b=32, seed=3, cat=False):
     return Xb, g, h, bag, fmask, iscat
 
 
+def _row_leaf(tree):
+    """Each row's leaf as the train step reads it: the grower's key -> leaf
+    table at the row's partition key."""
+    table = np.asarray(tree["key_leaf"])
+    return table[np.clip(np.asarray(tree["row_key"]), 0, len(table) - 1)]
+
+
 def _assert_same_tree(seq, bat):
     for key in ("feature", "threshold", "left", "right", "default_left",
                 "is_cat", "cat_bitset"):
@@ -49,8 +56,7 @@ def _assert_same_tree(seq, bat):
     np.testing.assert_allclose(np.asarray(seq["value"]),
                                np.asarray(bat["value"]), rtol=1e-4,
                                atol=2e-6)
-    np.testing.assert_array_equal(np.asarray(seq["row_leaf"]),
-                                  np.asarray(bat["row_leaf"]))
+    np.testing.assert_array_equal(_row_leaf(seq), _row_leaf(bat))
     assert int(seq["max_depth"]) == int(bat["max_depth"])
 
 
@@ -168,8 +174,9 @@ def test_wired_batched_equals_sequential(leaves, depth, lm):
 
 def test_wired_batched_equals_legacy_batched():
     """Wired vs legacy batched expansion on the tie-free fixture: bitwise
-    tree structures AND row_leaf (both derive sides from the same packed
-    arithmetic; only the histogram/movement programs differ)."""
+    tree structures AND each row's key and key -> leaf table (both derive
+    sides from the same packed arithmetic; only the histogram/movement
+    programs differ)."""
     Xb, g, h, bag, fmask, iscat = _fixture()
     p_w = _wired_params()
     bat_w = grow_tree_leafwise_batched(p_w, 32, Xb, g, h, bag, fmask, iscat,
@@ -178,7 +185,7 @@ def test_wired_batched_equals_legacy_batched():
                                        32, Xb, g, h, bag, fmask, iscat,
                                        platform="cpu")
     for key in ("feature", "threshold", "left", "right", "default_left",
-                "is_cat", "cat_bitset", "row_leaf"):
+                "is_cat", "cat_bitset", "row_key", "key_leaf"):
         np.testing.assert_array_equal(np.asarray(bat_w[key]),
                                       np.asarray(bat_l[key]), err_msg=key)
     np.testing.assert_allclose(np.asarray(bat_w["value"]),
@@ -285,7 +292,6 @@ def test_grow_any_routes_by_depth():
     Xb, g, h, bag, fmask, iscat = _fixture(n=5000)
     seq = grow_tree(p_fast, 32, Xb, g, h, bag, fmask, iscat)
     routed = grow_any(p_fast, 32, Xb, g, h, bag, fmask, iscat)
-    routed.pop("row_leaf")
     for key in ("feature", "threshold", "left", "right"):
         np.testing.assert_array_equal(np.asarray(seq[key]),
                                       np.asarray(routed[key]))

@@ -179,9 +179,12 @@ def test_the_three_kernels_carry_their_names():
 # ``renewal_iteration`` grows with leafwise_fast, which since PR 27 also
 # returns its two statistics (expanded_splits, selected_splits): two
 # equations, so 4de6ad398110 became da449f98fd88; ``dryad.select`` itself
-# moved nothing
-SEED_DIGESTS = {"renewal_iteration": "da449f98fd88",
-                "multiclass_shared_roots": "57de8b33dee0"}
+# moved nothing.  PR 35 rewrote the equations inside ``dryad.score`` (one
+# record gather a row where two 1-D look-ups stood, train._row_records), so
+# da449f98fd88 became 7707dd180638 and 57de8b33dee0 became 2086de9ea573; no
+# scope was added, moved or renamed
+SEED_DIGESTS = {"renewal_iteration": "7707dd180638",
+                "multiclass_shared_roots": "2086de9ea573"}
 
 
 @pytest.mark.parametrize("arm", sorted(SEED_DIGESTS))
