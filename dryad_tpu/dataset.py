@@ -19,6 +19,7 @@ import numpy as np
 
 from dryad_tpu.data.binning import bin_csr, bin_matrix
 from dryad_tpu.data.sketch import BinMapper, sketch_features
+from dryad_tpu.obs.spans import span
 
 
 class Dataset:
@@ -43,34 +44,44 @@ class Dataset:
 
             indptr, indices, values, num_features = csr
             if mapper is None:
-                base = _sketch_csr(indptr, indices, values, num_features,
-                                   max_bins, self.categorical_features)
-                Xb0 = bin_csr(indptr, indices, values, num_features, base)
-                plan = plan_bundles(Xb0, base, max_bins) if bundle else []
+                with span("data.sketch"):
+                    base = _sketch_csr(indptr, indices, values, num_features,
+                                       max_bins, self.categorical_features)
+                with span("data.bin"):
+                    Xb0 = bin_csr(indptr, indices, values, num_features, base)
+                with span("data.sketch"):   # the plan reads the base bins
+                    plan = plan_bundles(Xb0, base, max_bins) if bundle else []
                 if plan:
                     # exclusive feature bundling: fold strictly-exclusive
                     # sparse columns (deterministic plan, stored in the
                     # mapper) — the grower sees fewer, denser features
                     mapper = BundledMapper(base, plan)
                     self.mapper = mapper
-                    self.X_binned = mapper.fold(Xb0)
+                    with span("data.bin"):
+                        self.X_binned = mapper.fold(Xb0)
                 else:
                     self.mapper = base
                     self.X_binned = Xb0
             elif isinstance(mapper, BundledMapper):
                 self.mapper = mapper
-                self.X_binned = mapper.fold(
-                    bin_csr(indptr, indices, values, num_features, mapper.base))
+                with span("data.bin"):
+                    self.X_binned = mapper.fold(bin_csr(
+                        indptr, indices, values, num_features, mapper.base))
             else:
                 self.mapper = mapper
-                self.X_binned = bin_csr(indptr, indices, values, num_features,
-                                        mapper)
+                with span("data.bin"):
+                    self.X_binned = bin_csr(indptr, indices, values,
+                                            num_features, mapper)
         else:
             X = np.asarray(X, np.float32)
             if mapper is None:
-                mapper = sketch_features(X, max_bins=max_bins, categorical_features=self.categorical_features)
+                with span("data.sketch"):
+                    mapper = sketch_features(
+                        X, max_bins=max_bins,
+                        categorical_features=self.categorical_features)
             self.mapper = mapper
-            self.X_binned = bin_matrix(X, mapper)
+            with span("data.bin"):
+                self.X_binned = bin_matrix(X, mapper)
 
         self.num_rows, self.num_features = self.X_binned.shape
         self._attach_targets(y, weight, group)

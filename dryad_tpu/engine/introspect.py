@@ -14,15 +14,22 @@ serve/cache.py right before the FIRST dispatch of a program):
 * ``dryad_prog_flops`` / ``dryad_prog_bytes_accessed`` gauges from
   ``jit_fn.lower(...).cost_analysis()`` — tracing + MLIR emission only,
   NO XLA compile, so the capture can never double the minutes a wide
-  chunk program takes to compile.  Whether an AOT ``lower().compile()``
-  shares the executable cache with the normal call path has differed
-  between jax releases, which is why introspection never compiles on the
-  dispatch path.
+  chunk program takes to compile.  On jax 0.9.0 and one device the jit
+  call that follows takes this lowering, and the executable where one was
+  compiled here, from jax's in-process caches: of the 7.945 s of a warm
+  10M-row chunk 0 dispatch, 7.924 s are the capture's and 0.02 s the
+  call's (PERF.md section 5, PR 36).  The lowering carries the capture's
+  call stack, so its persistent-cache entry is not the one a run with the
+  registry off (``DRYAD_OBS=0``: no capture, the call lowers itself)
+  writes and reads.  On a mesh the call shares nothing: it lowers again
+  and compiles or reads its own entry (four chips, 12M x 67: capture 91.9
+  of the cold dispatch's 180.0 s, two misses; 5.4 of 9.6 s warm, two hits).
 * ``dryad_prog_memory_bytes{kind=temp|argument|output}`` from
   ``compiled.memory_analysis()`` — this one NEEDS a real compile, so it
-  is opt-in (``DRYAD_PROG_MEMORY=1``): a second local compile is cheap
-  on the CPU backend (tests, the acceptance drill) and deliberate
-  anywhere else.  The same compile's HLO text gives ``scope_maps()``:
+  is opt-in (``DRYAD_PROG_MEMORY=1``): the compile (or the persistent
+  cache's read) then happens here, in the call's place on one device and
+  beside the call's own on a mesh.  The same compile's HLO text gives
+  ``scope_maps()``:
   which ``dryad.*`` stage each instruction of the program belongs to, by
   the instruction's name, for whoever reads a device trace of it.
 * ``dryad_prog_compiles_total{program=...}`` via the recompile tripwire
@@ -37,7 +44,19 @@ serve/cache.py right before the FIRST dispatch of a program):
   that was active on the compiling thread (best-effort sticky label;
   compiles outside any declared boundary land on ``program="other"``).
   ``attributed(family)`` lends the label to a block of host code that
-  compiles on its own account (``train.materialize``) and restores it.
+  compiles on its own account (``train.materialize``) and restores it;
+  ``attribute(family)`` sets it for good (a job's entry) and
+  ``attribute(None)`` clears it (the job's leave, by return or by raise).
+* ``dryad_prog_jit_seconds_total{program, phase}`` from the same listener:
+  what jit spent by family on ``trace`` (jaxpr tracing), ``lower`` (jaxpr to
+  MLIR), ``backend_compile`` (jax's event around ``compile_or_get_cached``:
+  the cache key, then the persistent cache's read on a hit, or XLA's
+  compile and the cache's write on a miss) and ``cache_read`` (the read
+  alone, which jax reports only for a hit and which lies inside that
+  hit's ``backend_compile``).  ``dryad_prog_cache_total{program, result}``
+  counts the persistent cache's ``hit`` and ``miss`` events (jax counts a
+  miss where it writes an entry: a compile under the cache's thresholds
+  of size and seconds is neither).
 
 Cost model: captures are memoized per (family, key) process-wide, so a
 warm re-run (bench arms, repeated serve traffic) pays NOTHING — exactly
@@ -59,10 +78,10 @@ import math
 import os
 import re
 import threading
-import time
 from typing import Optional
 
 from dryad_tpu.obs.registry import default_registry
+from dryad_tpu.obs.spans import span
 from dryad_tpu.obs.tripwire import default_tripwire
 
 _seen: set = set()               # (family, key) already introspected
@@ -71,8 +90,14 @@ _tls = threading.local()         # .program — sticky compile attribution
 _listener_lock = threading.Lock()
 _listener_installed = False
 
-#: the jax.monitoring event real XLA compiles emit
-_COMPILE_EVENT_SUFFIX = "backend_compile_duration"
+#: the jax.monitoring duration events of a jit call, by the last component
+#: of the installed jax's event name -> the ``phase`` each is booked under
+_PHASES = {"jaxpr_trace_duration": "trace",
+           "jaxpr_to_mlir_module_duration": "lower",
+           "backend_compile_duration": "backend_compile",
+           "cache_retrieval_time_sec": "cache_read"}
+#: the persistent cache's events, likewise -> ``result``
+_CACHE_RESULTS = {"cache_hits": "hit", "cache_misses": "miss"}
 
 #: the stages of a boosting iteration, as ``jax.named_scope`` names them
 #: (README "Device truth"): a component of every operation's ``op_name``
@@ -100,19 +125,39 @@ def memory_capture_enabled() -> bool:
     return os.environ.get("DRYAD_PROG_MEMORY", "0") == "1"
 
 
-def _on_compile_duration(name: str, secs: float, **kw) -> None:
-    if not name.endswith(_COMPILE_EVENT_SUFFIX):
+def _on_duration(name: str, secs: float, **kw) -> None:
+    phase = _PHASES.get(name.rpartition("/")[2])
+    if phase is None:
         return
     reg = default_registry()
     if not reg.enabled:
         return
     program = getattr(_tls, "program", None) or "other"
+    reg.counter("dryad_prog_jit_seconds_total",
+                "Wall jit spent by boundary family and phase").labels(
+        program=program, phase=phase).inc(float(secs))
+    if phase != "backend_compile":
+        return
     reg.counter("dryad_prog_backend_compiles_total",
                 "Real XLA backend compiles by boundary family").labels(
         program=program).inc()
     reg.counter("dryad_prog_compile_seconds_total",
                 "XLA backend compile wall by boundary family").labels(
         program=program).inc(float(secs))
+
+
+def _on_event(name: str, **kw) -> None:
+    result = _CACHE_RESULTS.get(name.rpartition("/")[2])
+    if result is None:
+        return
+    reg = default_registry()
+    if not reg.enabled:
+        return
+    reg.counter("dryad_prog_cache_total",
+                "Persistent compile cache hits and misses by boundary "
+                "family").labels(
+        program=getattr(_tls, "program", None) or "other",
+        result=result).inc()
 
 
 def _install_listener() -> None:
@@ -124,9 +169,23 @@ def _install_listener() -> None:
             return
         import jax.monitoring
 
-        jax.monitoring.register_event_duration_secs_listener(
-            _on_compile_duration)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
         _listener_installed = True
+
+
+def attribute(family: Optional[str]) -> None:
+    """Set this thread's sticky compile label to ``family`` until the next
+    boundary (``capture``) or the next call changes it; ``None`` clears it,
+    so that what the thread compiles next counts under ``other``.  A job
+    sets its own name at its entry and clears the label in a ``finally``
+    when it leaves: what runs jax on the thread after the job (the next
+    job's set-up, a caller's own programs) is not the job's last family's."""
+    if family is not None:
+        if not default_registry().enabled:
+            return
+        _install_listener()
+    _tls.program = family
 
 
 @contextlib.contextmanager
@@ -136,12 +195,8 @@ def attributed(family: str):
     it.  For host code that compiles small programs of its own between two
     boundaries (a checkpoint's slices): the listener's half of ``capture``
     with no key, no ``lower()`` and no tripwire note."""
-    if not default_registry().enabled:
-        yield
-        return
-    _install_listener()
     prev = getattr(_tls, "program", None)
-    _tls.program = family
+    attribute(family)
     try:
         yield
     finally:
@@ -266,9 +321,8 @@ def capture(family: str, key, jit_fn, *args,
         # program's TRACE cost (never its compile) — skippable where even
         # that matters, without disabling the rest of the registry
         return False
-    _install_listener()
     # sticky attribution for the compile the caller is about to trigger
-    _tls.program = family
+    attribute(family)
     if note_tripwire:
         default_tripwire().note_compile(family, key)
     with _seen_lock:
@@ -278,36 +332,38 @@ def capture(family: str, key, jit_fn, *args,
     lbl = dict(labels or {})
     lbl["program"] = family
     try:
-        t0 = time.perf_counter()
-        lowered = jit_fn.lower(*args, **kwargs)
-        cost = lowered.cost_analysis()
-        d = cost[0] if isinstance(cost, (list, tuple)) else (cost or {})
-        if "flops" in d:
-            reg.gauge("dryad_prog_flops",
-                      "Compiler flops estimate per program").labels(
-                **lbl).set(float(d["flops"]))
-        if "bytes accessed" in d:
-            reg.gauge("dryad_prog_bytes_accessed",
-                      "Compiler bytes-accessed estimate per program").labels(
-                **lbl).set(float(d["bytes accessed"]))
-        if memory_capture_enabled():
-            compiled = lowered.compile()
-            ma = compiled.memory_analysis()
-            mem = reg.gauge("dryad_prog_memory_bytes",
-                            "Compiled-program memory estimate by kind")
-            for kind, attr in (("temp", "temp_size_in_bytes"),
-                               ("argument", "argument_size_in_bytes"),
-                               ("output", "output_size_in_bytes")):
-                val = getattr(ma, attr, None)
-                if val is not None:
-                    mem.labels(kind=kind, **lbl).set(float(val))
-            _note_scopes(compiled.as_text())
+        # the boundary's own wall (the lowering and, opted in, the compile
+        # or cache read and the HLO text's scope map; on one device the
+        # call that follows finds them done) on the spans' clock, under
+        # whatever span is open: train.chunk_dispatch/capture
+        with span("capture"):
+            lowered = jit_fn.lower(*args, **kwargs)
+            cost = lowered.cost_analysis()
+            d = cost[0] if isinstance(cost, (list, tuple)) else (cost or {})
+            if "flops" in d:
+                reg.gauge("dryad_prog_flops",
+                          "Compiler flops estimate per program").labels(
+                    **lbl).set(float(d["flops"]))
+            if "bytes accessed" in d:
+                reg.gauge("dryad_prog_bytes_accessed",
+                          "Compiler bytes-accessed estimate per "
+                          "program").labels(
+                    **lbl).set(float(d["bytes accessed"]))
+            if memory_capture_enabled():
+                compiled = lowered.compile()
+                ma = compiled.memory_analysis()
+                mem = reg.gauge("dryad_prog_memory_bytes",
+                                "Compiled-program memory estimate by kind")
+                for kind, attr in (("temp", "temp_size_in_bytes"),
+                                   ("argument", "argument_size_in_bytes"),
+                                   ("output", "output_size_in_bytes")):
+                    val = getattr(ma, attr, None)
+                    if val is not None:
+                        mem.labels(kind=kind, **lbl).set(float(val))
+                _note_scopes(compiled.as_text())
         reg.counter("dryad_prog_captures_total",
                     "Successful compile-boundary introspections").labels(
             program=family).inc()
-        reg.gauge("dryad_prog_capture_seconds",
-                  "Wall of the last introspection per family").labels(
-            program=family).set(round(time.perf_counter() - t0, 4))
     except Exception:   # noqa: BLE001 — introspection must never break
         reg.counter("dryad_prog_capture_errors_total",   # the dispatch
                     "Compile-boundary introspections that raised").labels(

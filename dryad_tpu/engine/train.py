@@ -21,6 +21,7 @@ break cross-backend parity.
 
 from __future__ import annotations
 
+import contextlib
 import os
 from functools import partial
 from typing import Callable, Optional
@@ -838,7 +839,33 @@ def train_device(
     AFTER path selection and calibration, so the supervisor's mid-run
     degradation can never flip the compiled program — only shorten chunks
     (resume bit-identity is preserved by construction; chunk length is a
-    traced scalar of one shared executable)."""
+    traced scalar of one shared executable).
+
+    Set-up is on the obs clock: span ``train.setup`` runs from here to the
+    boosting loop, with children ``upload`` (placing the tables on the
+    device or devices: the host's wall of handing them over, which waits for
+    no transfer) and ``plan`` (host work over the rows); its compiles count
+    under ``program="train.setup"`` until the loop's first compile boundary
+    takes the sticky label, and the label is cleared when the job leaves, by
+    return or by raise."""
+    try:
+        with contextlib.ExitStack() as setup:
+            setup.enter_context(span("train.setup"))
+            introspect.attribute("train.setup")
+            return _train_device(
+                setup, params, data, valid, num_trees=num_trees,
+                init_booster=init_booster, callback=callback, mesh=mesh,
+                checkpointer=checkpointer, chunk_hook=chunk_hook,
+                chunk_policy=chunk_policy)
+    finally:
+        introspect.attribute(None)
+
+
+def _train_device(setup, params, data, valid, *, num_trees, init_booster,
+                  callback, mesh, checkpointer, chunk_hook, chunk_policy):
+    """``train_device``'s body.  ``setup`` holds the open ``train.setup``
+    span: closed here right before the boosting loop (either one), and by
+    the caller if set-up raises."""
     p = params.validate()
     N, F = data.num_rows, data.num_features
     B = data.mapper.total_bins
@@ -855,41 +882,46 @@ def train_device(
 
     pad = 0
     shard_rows = None
-    if mesh is not None:
-        if getattr(data, "is_streamed", False):
-            raise ValueError(
-                "streamed datasets cannot train with mesh=...: the sharded "
-                "arm pads and shards the resident matrix host-side — "
-                "materialize() the dataset or train unsharded (on-device "
-                "streaming past HBM is the staged follow-up)")
-        from dryad_tpu.engine.distributed import padded_rows, shard_rows
+    with span("upload"):
+        if mesh is not None:
+            if getattr(data, "is_streamed", False):
+                raise ValueError(
+                    "streamed datasets cannot train with mesh=...: the "
+                    "sharded arm pads and shards the resident matrix "
+                    "host-side — materialize() the dataset or train "
+                    "unsharded (on-device streaming past HBM is the staged "
+                    "follow-up)")
+            from dryad_tpu.engine.distributed import padded_rows, shard_rows
 
-        Xb_np, y_np = data.X_binned, data.y
-        w_np = data.weight
-        Np = padded_rows(N, mesh.devices.size)
-        pad = Np - N
-        if pad:
-            Xb_np = np.pad(Xb_np, ((0, pad), (0, 0)))
-            y_np = np.pad(y_np, (0, pad))
-            if w_np is not None:
-                w_np = np.pad(w_np, (0, pad))
-        # straight from the host: each device is sent its own rows, and no
-        # chip ever holds the table a mesh exists to spread
-        Xb, y = shard_rows(mesh, Xb_np, y_np)
-        weight = shard_rows(mesh, w_np)[0] if w_np is not None else None
-    else:
-        # memoized on the Dataset: repeated train calls (bench arms, warm
-        # restarts, parameter sweeps) skip the X upload entirely.  On a
-        # StreamedDataset this is the overlapped chunk-by-chunk assembly
-        # (prefetch read i+1 vs async device_put of i) — the jitted
-        # programs downstream are IDENTICAL to the resident path, so the
-        # audit goldens and _comm_stats are untouched by streaming.
-        Xb, y, weight = data.device_arrays()
+            Xb_np, y_np = data.X_binned, data.y
+            w_np = data.weight
+            Np = padded_rows(N, mesh.devices.size)
+            pad = Np - N
+            if pad:
+                Xb_np = np.pad(Xb_np, ((0, pad), (0, 0)))
+                y_np = np.pad(y_np, (0, pad))
+                if w_np is not None:
+                    w_np = np.pad(w_np, (0, pad))
+            # straight from the host: each device is sent its own rows, and
+            # no chip ever holds the table a mesh exists to spread
+            Xb, y = shard_rows(mesh, Xb_np, y_np)
+            weight = shard_rows(mesh, w_np)[0] if w_np is not None else None
+        else:
+            # memoized on the Dataset: repeated train calls (bench arms,
+            # warm restarts, parameter sweeps) skip the X upload entirely.
+            # On a StreamedDataset this is the overlapped chunk-by-chunk
+            # assembly (prefetch read i+1 vs async device_put of i) — the
+            # jitted programs downstream are IDENTICAL to the resident path,
+            # so the audit goldens and _comm_stats are untouched by
+            # streaming.
+            Xb, y, weight = data.device_arrays()
     NP = N + pad
     is_cat_feat = jnp.asarray(is_cat_np)
     qoff = data.query_offsets
 
-    init = np.asarray(obj.init_score(data.y, data.weight), np.float32).reshape(-1)
+    with span("plan"):
+        init = np.asarray(obj.init_score(data.y, data.weight),
+                          np.float32).reshape(-1)
     if init_booster is not None:
         # the carried base score is part of the model: a continuation (and
         # especially an r19 warm-start append on FRESH rows) must not
@@ -899,9 +931,11 @@ def train_device(
         # runs BEFORE the rf constant-gradient capture below for the same
         # reason.
         init = np.asarray(init_booster.init_score, np.float32).reshape(-1)
-    score = jnp.broadcast_to(jnp.asarray(init), (NP, K)).astype(jnp.float32)
-    if mesh is not None:
-        score = shard_rows(mesh, score)[0]
+    with span("upload"):
+        score = jnp.broadcast_to(jnp.asarray(init),
+                                 (NP, K)).astype(jnp.float32)
+        if mesh is not None:
+            score = shard_rows(mesh, score)[0]
 
     rank_row = rank_col = None
     rank_Q = rank_S = 0
@@ -909,7 +943,9 @@ def train_device(
     if p.objective == "lambdarank":
         from dryad_tpu.engine.lambdarank import PaddingPlan
 
-        rank_plan = PaddingPlan(np.asarray(qoff), truncation=p.lambdarank_truncation)
+        with span("plan"):
+            rank_plan = PaddingPlan(np.asarray(qoff),
+                                    truncation=p.lambdarank_truncation)
         rank_row, rank_col = rank_plan.row_ids, rank_plan.col_ids
         rank_Q, rank_S = rank_plan.Q, rank_plan.S
         qoff_j = jnp.asarray(qoff)
@@ -952,7 +988,8 @@ def train_device(
     else:
         init_dev = jnp.asarray(init)
 
-    learn_missing = data.has_missing
+    with span("plan"):      # the scan of the binned table for bin 0
+        learn_missing = data.has_missing
     if jax.process_count() > 1:
         # multi-host: the flag is a static jit arg and rows are sharded per
         # process — agree globally (any host has missing => all scan both
@@ -963,11 +1000,12 @@ def train_device(
         learn_missing = bool(
             multihost_utils.process_allgather(np.int32(learn_missing)).max())
 
-    comm = (_comm_stats(p_key, F, B, K, mesh.devices.size,
-                        shared_roots=K > 1 and _shared_roots_ok(p, plat),
-                        num_rows=N, padded_rows=NP, platform=plat,
-                        has_cat=has_cat)
-            if mesh is not None else None)
+    with span("plan"):
+        comm = (_comm_stats(p_key, F, B, K, mesh.devices.size,
+                            shared_roots=K > 1 and _shared_roots_ok(p, plat),
+                            num_rows=N, padded_rows=NP, platform=plat,
+                            has_cat=has_cat)
+                if mesh is not None else None)
     if comm is not None:
         # comm-payload observability (r16): the static accounting becomes
         # dryad_comm_* gauges at this compile boundary, so a reduce-payload
@@ -1060,8 +1098,9 @@ def train_device(
                 f"valid set {vname!r} is streamed: device eval scores the "
                 "resident matrix — materialize() it (valid sets are small "
                 "relative to the training corpus)")
-    evaluators = [make_evaluator(p.objective, p.metric, vds, p.ndcg_at)
-                  for _, vds in valids]
+    with span("plan"):      # a rank metric pads its queries; labels go up
+        evaluators = [make_evaluator(p.objective, p.metric, vds, p.ndcg_at)
+                      for _, vds in valids]
     # a checkpointer does NOT force per-eval syncs: deferred evals are
     # flushed (bulk fetch + replay) right before each due checkpoint so the
     # saved best_iteration/stale state is exact
@@ -1073,21 +1112,23 @@ def train_device(
     if init_booster is not None and init_booster.train_state.get("eval_history"):
         eval_history = {k: list(v) for k, v in
                         init_booster.train_state["eval_history"].items()}
-    vXbs = [jnp.asarray(v.X_binned) for _, v in valids]
-    vscores = [
-        jnp.broadcast_to(jnp.asarray(init), (v.num_rows, K)).astype(jnp.float32)
-        for _, v in valids
-    ]
-    if mesh is not None:
-        # a valid set is REPLICATED over the mesh, placed once: every device
-        # walks all its rows inside the chunk program and holds the same
-        # metric (the rank metrics sort the whole set, so a row-sharded eval
-        # would need a gather of the scores anyway; the walk is a few
-        # percent of an iteration).  Left uncommitted on one device, each
-        # dispatch would broadcast the matrix again.
-        from dryad_tpu.engine.distributed import replicate
+    with span("upload"):
+        vXbs = [jnp.asarray(v.X_binned) for _, v in valids]
+        vscores = [
+            jnp.broadcast_to(jnp.asarray(init),
+                             (v.num_rows, K)).astype(jnp.float32)
+            for _, v in valids
+        ]
+        if mesh is not None:
+            # a valid set is REPLICATED over the mesh, placed once: every
+            # device walks all its rows inside the chunk program and holds
+            # the same metric (the rank metrics sort the whole set, so a
+            # row-sharded eval would need a gather of the scores anyway; the
+            # walk is a few percent of an iteration).  Left uncommitted on
+            # one device, each dispatch would broadcast the matrix again.
+            from dryad_tpu.engine.distributed import replicate
 
-        vXbs, vscores = replicate(mesh, (vXbs, vscores))
+            vXbs, vscores = replicate(mesh, (vXbs, vscores))
     if init_booster is not None:
         vscores = [
             _accumulate(prev_trees, vXb, jnp.asarray(init),
@@ -1353,6 +1394,7 @@ def train_device(
         _tw.begin_program("train.chunk")
         _shards_lbl = mesh.devices.size if mesh is not None else 1
 
+        setup.close()
         it = start_iter
         while it < total_iters:
             n = min(CH, total_iters - it)
@@ -1461,13 +1503,14 @@ def train_device(
                 with watch_fetch("calibrate", it):
                     if chunk_hook is not None:
                         chunk_hook("fetch", it)
-                    # deliberately NOT timed as a fetch span: this wait
-                    # moves no bytes to the host, and chunk 0's wait is
-                    # mostly compile — the real-fetch sites below carry
-                    # that series.  (The watchdog wrap is different: it
-                    # times only the in-flight AGE, and an injected stall
-                    # in the hook must be visible.)
-                    jax.block_until_ready(out["max_depth"])
+                    # no fetch span: this wait moves no bytes to the host.
+                    # It is the job's start on the device: chunk 0's run
+                    # (its compile was the dispatch's) and chunk 1's, the
+                    # one that is timed.  (The watchdog wrap is different:
+                    # it times only the in-flight AGE, and an injected
+                    # stall in the hook must be visible.)
+                    with span("train.calibrate"):
+                        jax.block_until_ready(out["max_depth"])
                 now = _time.perf_counter()
                 if chunk_idx == 1 and t_mark is not None:
                     per_iter = max((now - t_mark) / n, 1e-4)
@@ -1619,6 +1662,7 @@ def train_device(
     _tw = default_tripwire()
     _tw.begin_program("train.step")
     _shards_lbl = mesh.devices.size if mesh is not None else 1
+    setup.close()
     for it in range(start_iter, T // K):
         # a checkpoint taken AT the early-stop boundary restores stale >=
         # rounds; growing anything past it would diverge from the stopped run

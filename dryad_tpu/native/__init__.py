@@ -26,6 +26,8 @@ from typing import Optional
 
 import numpy as np
 
+from dryad_tpu.obs.spans import span
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_HERE, "libdryad_native.so")
 
@@ -49,16 +51,19 @@ _u32p = np.ctypeslib.ndpointer(np.uint32, flags="C_CONTIGUOUS")
 
 
 def _build() -> bool:
-    try:
-        # -B: the rule is the ABI check, not make's file times
-        res = subprocess.run(
-            ["make", "-B", "-C", _HERE],
-            capture_output=True,
-            timeout=120,
-        )
-        return res.returncode == 0 and os.path.exists(_SO)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+    # on the obs clock where it happens: a fresh checkout's first sketch
+    # waits for the compiler (the span nests under ``data.sketch`` there)
+    with span("data.native_build"):
+        try:
+            # -B: the rule is the ABI check, not make's file times
+            res = subprocess.run(
+                ["make", "-B", "-C", _HERE],
+                capture_output=True,
+                timeout=120,
+            )
+            return res.returncode == 0 and os.path.exists(_SO)
+        except (OSError, subprocess.TimeoutExpired):
+            return False
 
 
 def _open() -> Optional[ctypes.CDLL]:

@@ -329,23 +329,30 @@ def test_what_a_checkpoint_compiles_counts_under_its_own_family(monkeypatch, tmp
                                                                 fresh_registry):
     """The slices ``_materialize`` takes compile new programs at every
     checkpoint (``T`` grows); they count under ``train.materialize`` and the
-    boundary that was active before gets its label back."""
+    boundary that was active before gets its label back: the second chunk is
+    dispatched under ``train.chunk`` again.  The job's own leave clears the
+    label (``tests/test_setup_spans.py``)."""
     monkeypatch.setenv("DRYAD_PROG", "1")
     monkeypatch.setenv("DRYAD_CHUNK", "1")
     introspect.reset_seen()
     jax.clear_caches()
     ds, vds = sets
+    at_dispatch = []
     dryad.train(dict(BASE, num_trees=2), ds, valid_sets=[vds], backend="tpu",
                 callbacks=[lambda i, info: None],
+                chunk_hook=lambda site, it: site == "dispatch"
+                and at_dispatch.append(introspect._tls.program),
                 checkpoint_dir=str(tmp_path), checkpoint_every=1)
     compiles = fresh_registry.snapshot()["counters"]["dryad_prog_backend_compiles_total"]
     by_family = {lbl: n for lbl, n in compiles.items()}
     assert by_family.get('program="train.materialize"', 0) >= 1
     assert by_family.get('program="train.chunk"', 0) >= 1
-    assert introspect._tls.program == "train.chunk"
+    assert at_dispatch == ["train.setup", "train.chunk"]
+    introspect.attribute("train.chunk")
     with introspect.attributed("train.materialize"):
         assert introspect._tls.program == "train.materialize"
     assert introspect._tls.program == "train.chunk"
+    introspect.attribute(None)
 
 
 def test_compiled_text_gives_the_scope_of_each_instruction():
