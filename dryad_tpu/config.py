@@ -400,10 +400,27 @@ MAX_FAST_DEPTH = 14
 # 136 at cap 12 compiles to 11.8 GB and is refused).  It must not err low:
 # a refused shape runs a slower grower, an admitted one that does not fit
 # dies.  Shape-only callers (num_rows None) are held to the expansion alone.
+#
+# Narrow tables: where a row's record fits the leaf-ordered layout
+# (engine/leafperm.py: 9 bytes and one a feature, at most 128), the wired
+# expansion's buffers also hold the mandated tiles of every level, and a row
+# costs the compiler about 670 B whatever the width; at 8 features
+# 512 + 7 F fell short: 11M x 8 at cap 12 compiled to 8.11 GB (8.10 with no
+# categorical column) where the rule above reckoned 6.98.  So there the rows
+# are reckoned at least LEAFWISE_WIRED_ROW_FLOOR a row plus two buffers'
+# mandated tiles (ceil(8/7 (2 x 2^D + 2)) + 2 tiles of 64 KB each: 1.23 GB
+# at cap 12), which envelops the compiler's figures at 4 to 28 features and
+# 1M to 20M rows.  Of the compiled shapes it changes one verdict: 20M x 8
+# at cap 12, reckoned 12.23 GB before where the compiler gives 14.10, is now
+# reckoned 14.58 and refused by the budget, as 20M x 28 (14.45 compiled) is,
+# though it would fit the compiler's 15.75 GB; that shape never ran on a chip.
 LEAFWISE_PEAK_FACTOR = 11
 LEAFWISE_ROW_BYTES = 512          # + LEAFWISE_CELL_BYTES a feature
 LEAFWISE_CELL_BYTES = 6           # + the bin's own byte(s)
 LEAFWISE_TOTAL_BYTES_BUDGET = 12 << 30
+LEAFWISE_WIRED_RECORD_BYTES = 128     # the widest record the layout takes
+LEAFWISE_WIRED_ROW_FLOOR = 640
+LEAFWISE_WIRED_TILE_BYTES = 512 * 128
 # max_depth=-1: caps tried under the documented one before the sequential
 # grower (each level down halves ``pinned``)
 LEAFWISE_CAP_STEPS = 2
@@ -466,8 +483,23 @@ def leafwise_fast_supported(p: Params, num_features: int,
     per_row = (LEAFWISE_ROW_BYTES + 16 * p.num_outputs
                + num_features * (LEAFWISE_CELL_BYTES + bin_bytes))
     shard_rows = -(-(num_rows or 0) // max(int(n_shards), 1))
-    return (LEAFWISE_PEAK_FACTOR * pinned + shard_rows * per_row
+    return (LEAFWISE_PEAK_FACTOR * pinned + leafwise_rows_bytes(D, num_features, bin_bytes,
+                                                                shard_rows, per_row)
             <= LEAFWISE_TOTAL_BYTES_BUDGET)
+
+
+def leafwise_rows_bytes(D: int, num_features: int, bin_bytes: int, rows: int,
+                        per_row: int) -> int:
+    """What the rows of one device add to the expansion's peak at cap ``D``:
+    ``rows x per_row``, and where a row's record fits the leaf-ordered
+    layout at least ``LEAFWISE_WIRED_ROW_FLOOR`` a row plus two layout
+    buffers' mandated tiles (the comment at the constants)."""
+    held = rows * per_row
+    if rows and 9 + num_features * bin_bytes <= LEAFWISE_WIRED_RECORD_BYTES:
+        mandated = -(-8 * (2 * (1 << D) + 2) // 7) + 2
+        held = max(held, rows * LEAFWISE_WIRED_ROW_FLOOR
+                   + 2 * mandated * LEAFWISE_WIRED_TILE_BYTES)
+    return held
 
 
 def effective_depth_params(p: Params, num_features: int,

@@ -32,6 +32,8 @@ from typing import Sequence
 
 import numpy as np
 
+from dryad_tpu.obs.registry import default_registry
+
 MISSING_BIN = 0
 
 
@@ -89,12 +91,16 @@ def _sketch_numerical_np(col: np.ndarray, max_bins: int) -> FeatureBins:
     )
 
 
-def _sketch_categorical(col: np.ndarray, max_bins: int) -> FeatureBins:
+def _sketch_categorical(col: np.ndarray, max_bins: int, feature: int | None = None) -> FeatureBins:
+    """``feature`` given: gauge ``dryad_cat_levels{feature, kind}`` is set to
+    the categories kept (a bin each) and pooled into the overflow bin."""
     finite = col[np.isfinite(col)]
     vals, counts = np.unique(finite, return_counts=True)
     # rank by (count desc, value asc) — deterministic
     order = np.lexsort((vals, -counts))
     n_kept = int(min(vals.size, max_bins - 2))  # reserve missing(0) + overflow(last)
+    if feature is not None:
+        _note_levels(feature, n_kept, vals.size - n_kept)
     kept = vals[order[:n_kept]]
     bins = np.arange(1, n_kept + 1, dtype=np.int32)
     # store sorted by value for searchsorted lookup
@@ -106,6 +112,17 @@ def _sketch_categorical(col: np.ndarray, max_bins: int) -> FeatureBins:
         bins[sort_idx].astype(np.int32),
         n_kept + 2,
     )
+
+
+def _note_levels(feature: int, kept: int, pooled: int) -> None:
+    reg = default_registry()
+    if not reg.enabled:
+        return
+    gauge = reg.gauge("dryad_cat_levels",
+                      "Categories of a categorical column at the sketch: kept "
+                      "(a bin each) / pooled (into the overflow bin)")
+    gauge.labels(feature=str(feature), kind="kept").set(float(kept))
+    gauge.labels(feature=str(feature), kind="pooled").set(float(pooled))
 
 
 def sketch_features(
@@ -123,7 +140,8 @@ def sketch_features(
         raise ValueError(f"X must be 2-D, got shape {X.shape}")
     cats = frozenset(int(c) for c in categorical_features)
     feats = [
-        _sketch_categorical(X[:, f], max_bins) if f in cats else _sketch_numerical(X[:, f], max_bins)
+        _sketch_categorical(X[:, f], max_bins, f) if f in cats
+        else _sketch_numerical(X[:, f], max_bins)
         for f in range(X.shape[1])
     ]
     return BinMapper(feats, max_bins)

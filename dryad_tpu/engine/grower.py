@@ -340,8 +340,10 @@ def grow_tree(
             if learn_missing:
                 num_left &= dl | (bins_f > 0)
             if has_cat:
-                go_left = jnp.where(cat_split, catm[jnp.minimum(bins_f, catm.shape[0] - 1)],
-                                    num_left)
+                with jax.named_scope("dryad.cat"):
+                    go_left = jnp.where(cat_split,
+                                        catm[jnp.minimum(bins_f, catm.shape[0] - 1)],
+                                        num_left)
             else:
                 go_left = num_left
             in_slot = st["row_slot"] == s

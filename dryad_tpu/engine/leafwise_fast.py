@@ -423,11 +423,12 @@ def grow_tree_leafwise_batched(
                         if learn_missing:
                             gl &= ((w0r >> 30) & 1).astype(bool) | (bins_rf > 0)
                         if has_cat:
-                            cat_row = st["nd_catmask"][
-                                jnp.minimum(nodes, HN - 1),
-                                jnp.minimum(bins_rf, Bc - 1)]
-                            gl = jnp.where(((w0r >> 29) & 1).astype(bool),
-                                           cat_row, gl)
+                            with jax.named_scope("dryad.cat"):
+                                cat_row = st["nd_catmask"][
+                                    jnp.minimum(nodes, HN - 1),
+                                    jnp.minimum(bins_rf, Bc - 1)]
+                                gl = jnp.where(((w0r >> 29) & 1).astype(bool),
+                                               cat_row, gl)
                         return ((w0r >> 31) != 0), gl
 
                     row_do, go_left = packed_route(rn)
@@ -441,8 +442,9 @@ def grow_tree_leafwise_batched(
                     if learn_missing:
                         go_left &= st["nd_dleft"][rn] | (bins_rf > 0)
                     if has_cat:
-                        cat_row = st["nd_catmask"][rn, jnp.minimum(bins_rf, Bc - 1)]
-                        go_left = jnp.where(is_cat_feat[rf], cat_row, go_left)
+                        with jax.named_scope("dryad.cat"):
+                            cat_row = st["nd_catmask"][rn, jnp.minimum(bins_rf, Bc - 1)]
+                            go_left = jnp.where(is_cat_feat[rf], cat_row, go_left)
                 row_node = jnp.where(
                     row_do, 2 * rn + jnp.where(go_left, 0, 1), rn)
 

@@ -555,10 +555,11 @@ def grow_tree_levelwise(
                         if learn_missing:
                             gl &= ((w0r >> 30) & 1).astype(bool) | (bins_rf > 0)
                         if has_cat:
-                            cat_row = sp_catmask[jnp.minimum(slot_idx, L - 1),
-                                                 jnp.minimum(bins_rf, Bc - 1)]
-                            gl = jnp.where(((w0r >> 29) & 1).astype(bool),
-                                           cat_row, gl)
+                            with jax.named_scope("dryad.cat"):
+                                cat_row = sp_catmask[jnp.minimum(slot_idx, L - 1),
+                                                     jnp.minimum(bins_rf, Bc - 1)]
+                                gl = jnp.where(((w0r >> 29) & 1).astype(bool),
+                                               cat_row, gl)
                         return ((w0r >> 31) != 0), gl, w0r
 
                     do_n, left_n, w0r = packed_route(rs)
@@ -583,8 +584,9 @@ def grow_tree_levelwise(
                     if learn_missing:
                         go_left &= sp_dleft[rs] | (bins_rf > 0)
                     if has_cat:
-                        cat_row = sp_catmask[rs, jnp.minimum(bins_rf, Bc - 1)]
-                        go_left = jnp.where(is_cat_feat[rf], cat_row, go_left)
+                        with jax.named_scope("dryad.cat"):
+                            cat_row = sp_catmask[rs, jnp.minimum(bins_rf, Bc - 1)]
+                            go_left = jnp.where(is_cat_feat[rf], cat_row, go_left)
                     row_slot = jnp.where(row_do & ~go_left, slot_right[rs],
                                          row_slot)
 

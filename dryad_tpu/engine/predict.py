@@ -194,11 +194,12 @@ def tree_leaves(tree: dict, Xb: jnp.ndarray, depth_bound) -> jnp.ndarray:
         num_left = bins <= (w1 & jnp.uint32(0xFFFF)).astype(jnp.int32)
         num_left &= (((w1 >> jnp.uint32(28)) & 1) > 0) | (bins != 0)
         if "cat_bitset" in tree:                       # static: model has cats
-            bs = tree["cat_bitset"]
-            word = bs[node, jnp.minimum(bins >> 5, bs.shape[1] - 1)]
-            cat_left = ((word >> (bins & 31).astype(jnp.uint32)) & 1) > 0
-            go_left = jnp.where(((w1 >> jnp.uint32(29)) & 1) > 0,
-                                cat_left, num_left)
+            with jax.named_scope("dryad.cat"):
+                bs = tree["cat_bitset"]
+                word = bs[node, jnp.minimum(bins >> 5, bs.shape[1] - 1)]
+                cat_left = ((word >> (bins & 31).astype(jnp.uint32)) & 1) > 0
+                go_left = jnp.where(((w1 >> jnp.uint32(29)) & 1) > 0,
+                                    cat_left, num_left)
         else:
             go_left = num_left
         nxt = jnp.where(go_left,
@@ -214,10 +215,11 @@ def tree_leaves(tree: dict, Xb: jnp.ndarray, depth_bound) -> jnp.ndarray:
         num_left = bins <= tree["threshold"][node]
         num_left &= tree["default_left"][node] | (bins != 0)
         if "cat_bitset" in tree:                       # static: model has cats
-            bs = tree["cat_bitset"]
-            word = bs[node, jnp.minimum(bins >> 5, bs.shape[1] - 1)]
-            cat_left = ((word >> (bins & 31).astype(jnp.uint32)) & 1) > 0
-            go_left = jnp.where(tree["is_cat"][node], cat_left, num_left)
+            with jax.named_scope("dryad.cat"):
+                bs = tree["cat_bitset"]
+                word = bs[node, jnp.minimum(bins >> 5, bs.shape[1] - 1)]
+                cat_left = ((word >> (bins & 31).astype(jnp.uint32)) & 1) > 0
+                go_left = jnp.where(tree["is_cat"][node], cat_left, num_left)
         else:
             # satellite r21: a False is_cat mask selected num_left exactly,
             # so dropping the dead bitset/is_cat gathers is bitwise free

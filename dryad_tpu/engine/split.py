@@ -11,6 +11,9 @@ vs f64 accumulation (documented tolerance, SURVEY.md §7 hard part c).
 Categorical features use the LightGBM-style sorted-subset scan: bins ordered
 by g/(h + smooth), the best prefix of that order becomes the left membership
 set, returned as a (B,) bool mask (the host converts it to the node bitset).
+That work, the order and the membership row, has the scope ``dryad.cat``
+(traced only where the model has categorical features, so a numeric
+program is unchanged by it).
 
 Feature-parallel variant (r16, ``Params.hist_reduce="feature"``): under the
 reduce-scatter arm each shard owns a contiguous feature slice of the fully
@@ -76,12 +79,13 @@ def find_best_split(
 
     if has_cat:
         # categorical scan order: bins sorted by g/(h+smooth); empty bins last
-        ratio = jnp.where(hc > 0, hg / (hh + CAT_SMOOTH), jnp.inf)
-        cat_order = jnp.argsort(ratio, axis=1, stable=True).astype(jnp.int32)
-        order = jnp.where(is_cat_feat[:, None], cat_order, iota[None, :])
-        hg_o = jnp.take_along_axis(hg, order, axis=1)
-        hh_o = jnp.take_along_axis(hh, order, axis=1)
-        hc_o = jnp.take_along_axis(hc, order, axis=1)
+        with jax.named_scope("dryad.cat"):
+            ratio = jnp.where(hc > 0, hg / (hh + CAT_SMOOTH), jnp.inf)
+            cat_order = jnp.argsort(ratio, axis=1, stable=True).astype(jnp.int32)
+            order = jnp.where(is_cat_feat[:, None], cat_order, iota[None, :])
+            hg_o = jnp.take_along_axis(hg, order, axis=1)
+            hh_o = jnp.take_along_axis(hh, order, axis=1)
+            hc_o = jnp.take_along_axis(hc, order, axis=1)
     else:
         hg_o, hh_o, hc_o = hg, hh, hc
 
@@ -165,8 +169,9 @@ def find_best_split(
 
     if has_cat:
         # left membership for categorical: bins whose rank in `order` is <= t
-        inv_order = jnp.zeros((B,), jnp.int32).at[order[f]].set(iota)
-        cat_mask = (inv_order <= t) & is_cat_feat[f] & ok
+        with jax.named_scope("dryad.cat"):
+            inv_order = jnp.zeros((B,), jnp.int32).at[order[f]].set(iota)
+            cat_mask = (inv_order <= t) & is_cat_feat[f] & ok
     else:
         cat_mask = jnp.zeros((1,), bool)
 
@@ -239,12 +244,13 @@ def find_best_split_sliced(
     iota = jnp.arange(B, dtype=jnp.int32)
 
     if has_cat:
-        ratio = jnp.where(hc > 0, hg / (hh + CAT_SMOOTH), jnp.inf)
-        cat_order = jnp.argsort(ratio, axis=1, stable=True).astype(jnp.int32)
-        order = jnp.where(is_cat_feat[:, None], cat_order, iota[None, :])
-        hg_o = jnp.take_along_axis(hg, order, axis=1)
-        hh_o = jnp.take_along_axis(hh, order, axis=1)
-        hc_o = jnp.take_along_axis(hc, order, axis=1)
+        with jax.named_scope("dryad.cat"):
+            ratio = jnp.where(hc > 0, hg / (hh + CAT_SMOOTH), jnp.inf)
+            cat_order = jnp.argsort(ratio, axis=1, stable=True).astype(jnp.int32)
+            order = jnp.where(is_cat_feat[:, None], cat_order, iota[None, :])
+            hg_o = jnp.take_along_axis(hg, order, axis=1)
+            hh_o = jnp.take_along_axis(hh, order, axis=1)
+            hc_o = jnp.take_along_axis(hc, order, axis=1)
     else:
         hg_o, hh_o, hc_o = hg, hh, hc
 
@@ -301,8 +307,9 @@ def find_best_split_sliced(
     t = flat % B
 
     if has_cat:
-        inv_order = jnp.zeros((B,), jnp.int32).at[order[f]].set(iota)
-        cat_raw = (inv_order <= t) & is_cat_feat[f]
+        with jax.named_scope("dryad.cat"):
+            inv_order = jnp.zeros((B,), jnp.int32).at[order[f]].set(iota)
+            cat_raw = (inv_order <= t) & is_cat_feat[f]
     else:
         cat_raw = jnp.zeros((1,), bool)
 

@@ -76,6 +76,9 @@ _WALK_KEYS = ("feature", "threshold", "left", "right", "default_left",
 # visited (either level-synchronous grower)
 _GROW_STATS = ("expanded_splits", "selected_splits")
 _LAYOUT_STATS = ("layout_tiles_live", "layout_tiles_empty")
+# and, where the model has categorical features, every grown tree's split
+# nodes by kind (counter dryad_split_nodes_total{kind})
+_CAT_STATS = ("split_nodes_categorical", "split_nodes_numeric")
 
 # widest (features * bins) program the chunked fori wrapper may compile.
 # Round 2 measured Epsilon-shaped (2000 x 256) chunk programs failing
@@ -176,6 +179,11 @@ def _step_body(p, B, has_cat, mesh, platform, learn_missing, out, score, Xb,
     for key in _GROW_STATS + _LAYOUT_STATS:
         if key in tree and key in out:
             out[key] = out[key].at[t].set(tree[key])
+    if has_cat and _CAT_STATS[0] in out:
+        with jax.named_scope("dryad.cat"):
+            split = tree["feature"] >= 0
+            for key, kind in zip(_CAT_STATS, (tree["is_cat"], ~tree["is_cat"])):
+                out[key] = out[key].at[t].set(jnp.sum(split & kind, dtype=jnp.int32))
     with jax.named_scope("dryad.score"):
         value = tree["value"]
         if renew_alpha is not None:
@@ -781,18 +789,26 @@ def _grow_stats_of(out, lo: int, hi: int) -> dict:
     new, or nobody counts."""
     if lo >= hi or not default_registry().enabled:
         return {}
-    return {key: out[key][lo:hi] for key in _GROW_STATS + _LAYOUT_STATS
+    return {key: out[key][lo:hi] for key in _GROW_STATS + _LAYOUT_STATS + _CAT_STATS
             if key in out}
 
 
 def _count_grow_stats(fetched: dict) -> None:
     """Fetched ``_grow_stats_of`` slices into their counters: splits the
     expansion grew, and splits the best-first selection kept of them;
-    the layout's tiles the moves visited, live (holding a row) or empty."""
+    the layout's tiles the moves visited, live (holding a row) or empty;
+    the trees' split nodes, categorical or numeric."""
     reg = default_registry()
     for key, vals in fetched.items():
         n = int(np.asarray(vals).sum())
-        if key in _LAYOUT_STATS:
+        if key in _CAT_STATS:
+            reg.counter(
+                "dryad_split_nodes_total",
+                "Split nodes of the grown trees of a model with categorical "
+                "features: categorical (a subset of bins goes left) / "
+                "numeric (a threshold)",
+            ).labels(kind=key.rsplit("_", 1)[1]).inc(n)
+        elif key in _LAYOUT_STATS:
             reg.counter(
                 "dryad_layout_tiles_total",
                 "Tiles the wired layout's moves visited: live (holding a "
@@ -1070,7 +1086,8 @@ def _train_device(setup, params, data, valid, *, num_trees, init_booster,
         T, p.max_nodes, CAT_WORDS,
         grow_stats=(_GROW_STATS if batched_leafwise else ())
         + (_LAYOUT_STATS if wired_layout(p, F, B, Xb.dtype.itemsize, N,
-                                         n_shards, plat) else ()))
+                                         n_shards, plat) else ())
+        + (_CAT_STATS if has_cat else ()))
     if batched_leafwise and default_registry().enabled:
         default_registry().gauge(
             "dryad_leafwise_depth_cap",

@@ -23,6 +23,10 @@ EIGHT = {"dryad.grad", "dryad.hist", "dryad.route", "dryad.layout",
 SEVEN = EIGHT - {"dryad.select"}
 # the ninth (PR 34): the cross-shard exchange, in a program that runs on a mesh
 NINE = EIGHT | {"dryad.reduce"}
+# and the categorical work (the subset order, the membership row, the
+# membership look-ups of route and eval), traced only where a model has
+# categorical features: no program above carries it
+TEN = NINE | {"dryad.cat"}
 BASE = dict(objective="binary", num_trees=4, num_leaves=7, max_depth=3,
             max_bins=32, seed=3, min_data_in_leaf=5, growth="depthwise")
 
@@ -106,14 +110,15 @@ def test_a_sharded_program_names_the_exchange(monkeypatch, sets, extra, expected
 
 def test_engine_sources_name_the_nine_and_no_tenth():
     """What the acceptance grep reads: every ``named_scope`` under
-    ``dryad_tpu/engine`` takes one of the nine names, and each is used."""
+    ``dryad_tpu/engine`` takes one of the nine names or ``dryad.cat``, the
+    categorical programs' own, and each is used."""
     root = os.path.dirname(os.path.abspath(train.__file__))
     used = set()
     for name in os.listdir(root):
         if name.endswith(".py"):
             with open(os.path.join(root, name), encoding="utf-8") as f:
                 used |= set(re.findall(r'named_scope\("([^"]*)"\)', f.read()))
-    assert used == NINE
+    assert used == TEN
 
 
 def _pallas_names(fn, *args, **kw):
