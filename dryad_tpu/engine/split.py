@@ -9,8 +9,10 @@ one flat argmax with first-index tie-breaking — semantics identical to
 vs f64 accumulation (documented tolerance, SURVEY.md §7 hard part c).
 
 Categorical features use the LightGBM-style sorted-subset scan: bins ordered
-by g/(h + smooth), the best prefix of that order becomes the left membership
-set, returned as a (B,) bool mask (the host converts it to the node bitset).
+by g/(h + smooth) (one stable sort that carries G, H and C along, so the
+ordered sums need no gather), the best prefix of that order becomes the left
+membership set, returned as a (B,) bool mask (the host converts it to the
+node bitset).
 That work, the order and the membership row, has the scope ``dryad.cat``
 (traced only where the model has categorical features, so a numeric
 program is unchanged by it).
@@ -39,6 +41,33 @@ import jax.numpy as jnp
 
 NEG_INF = float("-inf")  # plain float: a jnp scalar here would init the backend at import
 CAT_SMOOTH = 10.0  # matches cpu/histogram.py find_best_split default
+
+
+def _cat_order(hg, hh, hc, is_cat_feat):
+    """The subset scan's order: one stable sort along the bins keyed on
+    ``g/(h + smooth)`` (empty bins last) for a categorical feature and on the
+    bin index for a numeric one, which the stable sort leaves in bin order.
+    G, H, C and the bin index ride the sort as operands, so the ordered
+    planes and ``order`` come out of it with no gather.  Returns
+    ``(hg_o, hh_o, hc_o, order)``, each ``(F, B)``."""
+    F, B = hg.shape
+    iota = jax.lax.broadcasted_iota(jnp.int32, (F, B), 1)
+    ratio = jnp.where(hc > 0, hg / (hh + CAT_SMOOTH), jnp.inf)
+    key = jnp.where(is_cat_feat[:, None], ratio, iota.astype(jnp.float32))
+    _, hg_o, hh_o, hc_o, order = jax.lax.sort(
+        (key, hg, hh, hc, iota), dimension=1, is_stable=True, num_keys=1)
+    return hg_o, hh_o, hc_o, order
+
+
+def _cat_left(order, is_cat_feat, f, t, iota):
+    """(B,) left membership of split (f, t): the bins whose rank in feature
+    f's order is <= t, and none where f is numeric.  Row f is picked by a
+    masked reduce, not an index, which under a level's vmap would gather."""
+    F, B = order.shape
+    on_f = jnp.arange(F) == f
+    order_f = jnp.where(on_f[:, None], order, 0).sum(axis=0)
+    inv_order = jnp.zeros((B,), jnp.int32).at[order_f].set(iota)
+    return (inv_order <= t) & jnp.any(on_f & is_cat_feat)
 
 
 class SplitResult(NamedTuple):
@@ -80,12 +109,7 @@ def find_best_split(
     if has_cat:
         # categorical scan order: bins sorted by g/(h+smooth); empty bins last
         with jax.named_scope("dryad.cat"):
-            ratio = jnp.where(hc > 0, hg / (hh + CAT_SMOOTH), jnp.inf)
-            cat_order = jnp.argsort(ratio, axis=1, stable=True).astype(jnp.int32)
-            order = jnp.where(is_cat_feat[:, None], cat_order, iota[None, :])
-            hg_o = jnp.take_along_axis(hg, order, axis=1)
-            hh_o = jnp.take_along_axis(hh, order, axis=1)
-            hc_o = jnp.take_along_axis(hc, order, axis=1)
+            hg_o, hh_o, hc_o, order = _cat_order(hg, hh, hc, is_cat_feat)
     else:
         hg_o, hh_o, hc_o = hg, hh, hc
 
@@ -170,8 +194,7 @@ def find_best_split(
     if has_cat:
         # left membership for categorical: bins whose rank in `order` is <= t
         with jax.named_scope("dryad.cat"):
-            inv_order = jnp.zeros((B,), jnp.int32).at[order[f]].set(iota)
-            cat_mask = (inv_order <= t) & is_cat_feat[f] & ok
+            cat_mask = _cat_left(order, is_cat_feat, f, t, iota) & ok
     else:
         cat_mask = jnp.zeros((1,), bool)
 
@@ -245,12 +268,7 @@ def find_best_split_sliced(
 
     if has_cat:
         with jax.named_scope("dryad.cat"):
-            ratio = jnp.where(hc > 0, hg / (hh + CAT_SMOOTH), jnp.inf)
-            cat_order = jnp.argsort(ratio, axis=1, stable=True).astype(jnp.int32)
-            order = jnp.where(is_cat_feat[:, None], cat_order, iota[None, :])
-            hg_o = jnp.take_along_axis(hg, order, axis=1)
-            hh_o = jnp.take_along_axis(hh, order, axis=1)
-            hc_o = jnp.take_along_axis(hc, order, axis=1)
+            hg_o, hh_o, hc_o, order = _cat_order(hg, hh, hc, is_cat_feat)
     else:
         hg_o, hh_o, hc_o = hg, hh, hc
 
@@ -308,8 +326,7 @@ def find_best_split_sliced(
 
     if has_cat:
         with jax.named_scope("dryad.cat"):
-            inv_order = jnp.zeros((B,), jnp.int32).at[order[f]].set(iota)
-            cat_raw = (inv_order <= t) & is_cat_feat[f]
+            cat_raw = _cat_left(order, is_cat_feat, f, t, iota)
     else:
         cat_raw = jnp.zeros((1,), bool)
 

@@ -2,14 +2,16 @@
 categorical columns (one of them past 254 values, so its rarest codes pool
 into the overflow bin) grown tree for tree against the plain best-first
 reference with subset splits (``benchmark/reference/gbdt_bestfirst_cat.py``),
-the split-node counter and the sketch's gauge, the ``dryad.cat`` scope, and
-the numeric programs' digests, which the scope must leave as committed."""
+the split-node counter and the sketch's gauge, the ``dryad.cat`` scope, the
+subset scan's one sort and no gather, and the numeric programs' digests,
+which the scope must leave as committed."""
 
 import os
 import re
 import sys
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -151,6 +153,69 @@ def test_only_a_categorical_program_carries_the_scope(has_cat):
     scopes = _scopes_of(jax.make_jaxpr(fn)(*audit_iteration_args(p, 2048, 8)))
     assert ("dryad.cat" in scopes) is has_cat
     assert {"dryad.split_scan", "dryad.route", "dryad.layout"} <= scopes
+
+
+def _subset_scan_census(monkeypatch):
+    """Trace the categorical program and return (scan call sites, the
+    equations inside both ``dryad.split_scan`` and ``dryad.cat``): the subset
+    scan's order and membership row, the root's and the level's vmapped one."""
+    from dryad_tpu.analysis.digests import iter_sub_jaxprs
+    from dryad_tpu.config import make_params
+    from dryad_tpu.engine import split
+    from dryad_tpu.engine.train import audit_iteration_args, audit_iteration_fn
+
+    sites = []
+    order_of = split._cat_order
+    monkeypatch.setattr(split, "_cat_order", lambda *a: (sites.append(1), order_of(*a))[1])
+    p = make_params(dict(objective="binary", num_trees=1, num_leaves=15, max_depth=4,
+                         growth="leafwise", max_bins=32, hist_backend="pallas"))
+    fn = audit_iteration_fn(p, 32, True, None, "tpu", 2048)
+    closed = jax.make_jaxpr(fn)(*audit_iteration_args(p, 2048, 8))
+    eqns = []
+
+    def walk(jaxpr, stack):
+        for eqn in jaxpr.eqns:
+            here = stack + "/" + str(eqn.source_info.name_stack)
+            if "dryad.split_scan" in here and "dryad.cat" in here:
+                eqns.append(eqn)
+            for _, sub, _ in iter_sub_jaxprs(eqn):
+                walk(sub, here)
+
+    walk(closed.jaxpr, "")
+    return len(sites), eqns
+
+
+def _sorts_and_gathers(eqns):
+    sorts = [(len(e.invars), e.params["num_keys"]) for e in eqns if e.primitive.name == "sort"]
+    return sorts, sum(e.primitive.name == "gather" for e in eqns)
+
+
+def test_the_subset_scan_sorts_once_and_gathers_nothing(monkeypatch):
+    """The static form of "the multi-operand sort engaged": at each scan
+    call site one stable sort of (key, G, H, C, bin) with one key, and no
+    gather in the categorical part of the scan (the ordered planes come out
+    of the sort, the winner's order row by a masked reduce)."""
+    sites, eqns = _subset_scan_census(monkeypatch)
+    assert sites == 2                       # the root's scan and the level's
+    assert _sorts_and_gathers(eqns) == ([(5, 1)] * sites, 0)
+
+
+def test_the_argsort_and_gathers_are_caught(monkeypatch):
+    """Mutation direction: the scan's order as it stood before, an
+    ``argsort`` and three ``take_along_axis``, reads a two-operand sort and
+    three gathers a call site in the same census."""
+    from dryad_tpu.engine import split
+
+    def gathered(hg, hh, hc, is_cat_feat):
+        iota = jnp.arange(hg.shape[1], dtype=jnp.int32)
+        ratio = jnp.where(hc > 0, hg / (hh + split.CAT_SMOOTH), jnp.inf)
+        order = jnp.where(is_cat_feat[:, None], jnp.argsort(ratio, axis=1, stable=True),
+                          iota[None, :]).astype(jnp.int32)
+        return (*(jnp.take_along_axis(x, order, axis=1) for x in (hg, hh, hc)), order)
+
+    monkeypatch.setattr(split, "_cat_order", gathered)
+    sites, eqns = _subset_scan_census(monkeypatch)
+    assert _sorts_and_gathers(eqns) == ([(2, 1)] * sites, 3 * sites)
 
 
 @pytest.mark.parametrize("arm", ["leafwise_wired", "train_eval"])
