@@ -39,8 +39,10 @@ when its parent is split), so a tree costs about ten passes, not 254.
 The floor ``min_child_weight`` binds here (100 where a leaf holds a few
 hundred rows).  The reference applies it to both children as the
 configuration states; only a candidate whose hessian sum lies within
-``FLOOR_ROUNDING`` of the floor counts as either: the reference's best takes
-it as refused, the job's own choice as allowed.
+``floor_rounding`` of the floor counts as either: the reference's best takes
+it as refused, the job's own choice as allowed.  The band is the rounding of
+the job's hessian sums against the reference's: ``FLOOR_ROUNDING`` where both
+take the same hessians, a subclass's own where the job computes them otherwise.
 """
 
 from __future__ import annotations
@@ -93,6 +95,8 @@ def split_order(tree: Tree, gain_of=None) -> list[int]:
 
 
 class BestFirst(Reference):
+    floor_rounding = FLOOR_ROUNDING
+
     def __init__(self, params: dict, train: Rows, valid: Rows | None, depth_cap: int):
         super().__init__(params, train, valid)
         self.depth_cap = int(depth_cap)
@@ -116,7 +120,8 @@ class BestFirst(Reference):
             return np.where(rows_ok & (HL >= floor) & (HR >= floor), gain, -np.inf)
 
         floor = float(p["min_child_weight"])
-        return kept(floor * (1 + FLOOR_ROUNDING)), kept(floor * (1 - FLOOR_ROUNDING)), G, H, C
+        band = self.floor_rounding
+        return kept(floor * (1 + band)), kept(floor * (1 - band)), G, H, C
 
     def may_split(self, depth, count, best):
         """The leaves that may still be split, but for the depth cap."""

@@ -23,6 +23,15 @@ ranking job adds, and imports nothing of the program:
   threads): the choice is the simpler of the two the issue allows, it needs no
   device blocks and no ``highest`` precision, and a pass costs about a second.
   Sums are then rounded to float32 once, as the histograms take them.
+* **the floor's band**, ``FLOOR_ROUNDING_RANK``: the job's λ-hessians are
+  float32 on the chip, whose ``log2`` reads the discounts' logarithms high by
+  up to 5.7e-5 of themselves, so its hessian sums lie under these by about
+  8e-6 of themselves and differ by up to 2.2e-5 (a child's sum, the job's
+  λ-pass on the same scores against this module's, summed alike); a candidate
+  whose child lies within the band of ``min_child_weight`` counts as either,
+  as in ``gbdt_bestfirst.py``.  ``tools/readings_rank.py --hessian-look``
+  reads the gaps and names the operation (readings and the choice: PERF.md
+  section 2).
 * **NDCG@k**, ``ndcg_at``: per query in float64, the same sort, a query whose
   ideal DCG is 0 counted as 1 (LightGBM's convention, and the program's).
 * **ties.**  After one tree the scores take at most ``num_leaves`` values, so
@@ -50,6 +59,7 @@ from benchmark.reference.gbdt import INNER, ROWS, Reference, Rows, leaf_value_ga
 from benchmark.reference.gbdt_bestfirst import BestFirst
 
 CELLS_A_BATCH = 1 << 21       # pair cells of one batch of queries
+FLOOR_ROUNDING_RANK = 1e-4    # relative: 4.5 times the largest gap of a child's hessian sum
 GRADIENT_FAULTS = ("no_delta_ndcg", "all_pairs", "shifted_boundaries")
 
 
@@ -235,6 +245,8 @@ class RankLevelwise(_RankJob, Reference):
 
 
 class RankBestFirst(_RankJob, BestFirst):
+    floor_rounding = FLOOR_ROUNDING_RANK
+
     def __init__(self, params: dict, train: RankRows, valid: RankRows | None, depth_cap: int,
                  fault: str | None = None):
         BestFirst.__init__(self, params, train, valid, depth_cap)

@@ -203,10 +203,9 @@ def test_level_passes_bounds_the_rows_a_tree_needs(grown):
     assert work["ops"] == 2 * 28 * 5 * 10_000_000 and work["bytes"] > 5 * 10_000_000 * 36
 
 
-def test_the_manifest_resolves_the_cell_and_its_files():
-    manifest = mf.load()
-    assert mf.problems(manifest) == []
-    cell = mf.Cell(manifest, CELL)
+def check_cell_resolves(manifest, root=mf.ROOT):
+    assert mf.problems(manifest, root) == []
+    cell = mf.Cell(manifest, CELL, root)
     assert (cell.chips, cell.config_name, cell.traffic_name) == (1, "higgs10m_leaf255", "job_bestfirst")
     runner = cell.runner()
     assert runner.__name__.endswith("train_job_bestfirst") and "order_gain_gap" in runner.NUMBERS
@@ -214,7 +213,32 @@ def test_the_manifest_resolves_the_cell_and_its_files():
     assert cell.config["depth_cap"] == 12 and cell.config["params"]["max_depth"] == -1
     names = {m["name"] for m in cell.per_layer}
     assert {"step_mfu", "hist_roofline", "split_scan_device_ms", "other_device_ms"} <= names
-    assert not {"perm_time_share", "layout_device_ms"} & names       # the wired layout is off
+    assert {"perm_time_share", "layout_device_ms"} <= names          # the wired layout runs
+    return cell
+
+
+def check_accepted_metrics(manifest, root=mf.ROOT):
+    from test_bench_scopes import accepted_of, check_readers_listed
+
+    check_readers_listed(manifest)
+    ours = accepted_of(manifest, CELL, root)
+    assert ours == accepted_of(manifest, "higgs10m_d8.job", root)
+    for entry in manifest["per_layer"]:
+        assert mf.metric_reader(entry["name"]) is not None
+        if entry["name"] in ours and "workloads" in entry:
+            assert CELL in entry["workloads"] and entry["moves"] == "iters_per_s"
+
+
+def check_manifest(manifest, root=mf.ROOT):
+    """This cell's part of the manifest, for the accepted one and for one
+    that later entries have grown (``test_bench_append.py``)."""
+    check_cell_resolves(manifest, root)
+    check_accepted_metrics(manifest, root)
+
+
+def test_the_manifest_resolves_the_cell_and_its_files():
+    cell = check_cell_resolves(mf.load())
+    runner = cell.runner()
     params, cap = runner.job_params(cell.config, rehearsal=False)
     assert (params, cap) == (cell.config["params"], 12)
     # the program's own policy gives the cap the file states
@@ -226,22 +250,11 @@ def test_the_manifest_resolves_the_cell_and_its_files():
 
 def test_the_cell_reads_the_accepted_metrics_and_adds_none():
     """The cell reports every accepted per-layer metric of the depth-wise
-    Higgs cell but the wired layout's two, through the accepted readers, and
-    the manifest's ``per_layer`` list still ends with PR 25's readers (an
-    accepted test pins that tail, so ``dryad.select`` and the two counters are
-    read from the traced run's printout and ``facts`` until a ``benchmark``
-    PR loosens it: PERF.md section 7)."""
-    from test_bench_scopes import NEW_READERS
-
-    manifest = mf.load()
-    assert [m["name"] for m in manifest["per_layer"]][-len(NEW_READERS):] == list(NEW_READERS)
-    higgs_names = {m["name"] for m in mf.Cell(manifest, "higgs10m_d8.job").per_layer}
-    ours = {m["name"] for m in mf.Cell(manifest, CELL).per_layer}
-    assert higgs_names - ours == {"perm_time_share", "layout_device_ms"} and ours <= higgs_names
-    for entry in manifest["per_layer"]:
-        assert mf.metric_reader(entry["name"]) is not None
-        if "workloads" in entry and CELL in entry["workloads"]:
-            assert entry["workloads"][-1] == CELL and entry["moves"] == "iters_per_s"
+    Higgs cell, the wired layout's two among them (it runs the layout),
+    through the accepted readers, and no metric of its own: ``dryad.select``
+    and the two counters are read from the traced run's printout and
+    ``facts`` (PERF.md section 7)."""
+    check_accepted_metrics(mf.load())
 
 
 def test_the_runner_takes_the_leafwise_counters_where_the_program_keeps_them():

@@ -165,13 +165,83 @@ def test_the_control_and_each_fault_of_the_mechanism_are_not_correct(data, ref, 
         assert not ok and over & {"leaf_value_gap", "split_flip_share"}, compared
 
 
+def test_the_floor_band_holds_the_rounding_of_the_chips_lambda_pass():
+    """A child whose hessian sum lies 1.2e-5 under ``min_child_weight`` (the
+    job's own split of a depth-9 node on a TPU v5e, where the job's λ-pass on
+    the same scores summed 99.99831) counts as either in the ranking reference
+    and as refused where both sides take the same hessians; a child past the
+    band is refused by both, and the reference's own best keeps the floor."""
+    import types
+
+    from benchmark.reference.gbdt_bestfirst import FLOOR_ROUNDING, BestFirst
+    from benchmark.reference.gbdt_rank import FLOOR_ROUNDING_RANK, RankBestFirst
+
+    params = {"lambda_l2": 1.0, "min_data_in_leaf": 1, "min_child_weight": 100.0}
+
+    def gains(cls, right_hessian):
+        hist = np.zeros((3, 1, 1, 256))
+        hist[:, 0, 0, 0] = (116.76, 144.898, 2822.0)
+        hist[:, 0, 0, 1] = (-8.32, right_hessian, 1446.0)
+        stub = types.SimpleNamespace(p=params, floor_rounding=cls.floor_rounding)
+        sure, maybe = cls.gains_either(stub, hist)[:2]
+        return float(sure[0, 0, 0]), float(maybe[0, 0, 0])
+
+    assert BestFirst.floor_rounding == FLOOR_ROUNDING
+    assert RankBestFirst.floor_rounding == FLOOR_ROUNDING_RANK > 4 * 2.2e-5
+    seen = 99.9987957989797
+    assert gains(RankBestFirst, seen)[0] == -np.inf and gains(RankBestFirst, seen)[1] > 0
+    assert gains(BestFirst, seen) == (-np.inf, -np.inf)
+    past = 100.0 * (1 - 2 * FLOOR_ROUNDING_RANK)
+    assert gains(RankBestFirst, past) == (-np.inf, -np.inf)
+    assert gains(RankBestFirst, 101.0)[0] > 0
+
+
+def test_the_hessian_look_reads_the_band_and_names_an_operation_planted_low(ref, monkeypatch):
+    """``readings_rank.py --hessian-look`` on the CPU: float32 keeps the
+    program's child hessian sums within 1e-6 of the reference's and the
+    bfloat16 control's lie over 1e-5 from them; then with ``exp`` planted
+    2**-8 low under the program's λ-pass and the look alike, the program's
+    shortfall shows, the look reads ``exp`` low and nothing else, and the
+    hessians rebuilt with the device's ``exp`` alone give the shortfall."""
+    import jax
+
+    from benchmark.tools.readings_rank import hessian_look
+
+    job = ref.grow(2)
+    looks = hessian_look(ref, job, 2, 7, 40)
+    band = [r for r in looks if r["kind"] == "band"]
+    assert len(band) == 2 and all(r["children"] > 0 for r in band)
+    assert max(r["program"]["largest"] for r in band) < 1e-6
+    assert min(r["control"]["largest"] for r in band) > 1e-5
+    for row in (r for r in looks if r["kind"] == "ops"):
+        assert row["pairs"] > 0
+        assert max(abs(v) for v in row["hessian_sum_gap"].values()) < 1e-6, row
+
+    real = jax.numpy.exp
+    monkeypatch.setattr(jax.numpy, "exp", lambda x: real(x) * (1 - 2.0 ** -8))
+    jax.clear_caches()
+    try:
+        planted = hessian_look(ref, job, 2, 7, 40)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    (row,) = [r for r in planted if r["kind"] == "ops" and r["iteration"] == 1]
+    ops, gap = row["device_against_float64"], row["hessian_sum_gap"]
+    assert ops["exp"]["mean"] == pytest.approx(-(2.0 ** -8), rel=1e-3)
+    assert max(abs(ops[k]["mean"]) for k in ("power", "log2", "discount", "inverse")) < 1e-6
+    assert abs(gap["program"]) > 1e-5
+    assert gap["exp"] == pytest.approx(gap["program"], rel=0.05)
+    assert max(abs(gap[k]) for k in ("power", "log2", "discount", "inverse")) < 1e-6
+
+
 # ---- the cell's files and the command -----------------------------------------
 
 
-def test_the_manifest_resolves_the_cell_and_its_files():
-    manifest = mf.load()
-    assert mf.problems(manifest) == []
-    cell = mf.Cell(manifest, CELL)
+def check_manifest(manifest, root=mf.ROOT):
+    """This cell's part of the manifest, for the accepted one and for one
+    that later entries have grown (``test_bench_append.py``)."""
+    assert mf.problems(manifest, root) == []
+    cell = mf.Cell(manifest, CELL, root)
     assert (cell.chips, cell.config_name, cell.traffic_name) == (1, "mslr2m_leaf255", "job_rank")
     assert CELL in [w["name"] for w in manifest["workloads"]]
     runner = cell.runner()
@@ -192,6 +262,12 @@ def test_the_manifest_resolves_the_cell_and_its_files():
             "device_idle_share"} <= ours
     assert not ours & {"perm_time_share", "layout_device_ms"}
     assert all(mf.metric_reader(name) is not None for name in ours)
+    return cell
+
+
+def test_the_manifest_resolves_the_cell_and_its_files():
+    cell = check_manifest(mf.load())
+    runner, config = cell.runner(), cell.config
     # the program's own policy gives the cap the file states, at the cell's size
     # and at the rehearsal's
     for rehearsal in (False, True):

@@ -22,6 +22,15 @@ NEW_READERS = ("grad_score_device_ms", "route_device_ms", "layout_device_ms",
                "hist_glue_device_ms", "split_scan_device_ms", "eval_device_ms",
                "unscoped_device_ms", "gap_unlabelled_ms", "ckpt_materialize_ms",
                "ckpt_save_ms", "ckpt_compiles")
+# the per-layer metrics of the manifest these tests were written against: a
+# cell's test judges what these say of its cell and leaves whatever later
+# entries list to their own tests, so that a metric appended for some cells
+# and not others breaks no accepted check
+ACCEPTED_PER_LAYER = frozenset({
+    "device_idle_share", "hbm_held_share", "step_mfu", "hist_roofline", "hist_time_share",
+    "perm_time_share", "other_device_ms", "chunk_gap_ms", "ckpt_stall_ms", "compile_s",
+    "data_prep_s", "window_compiles", *NEW_READERS, "cat_device_ms", "sketch_s", "bin_s",
+    "upload_s", "capture_s", "trace_lower_s", "cache_read_s", "backend_compile_s"})
 
 
 def op(name, start_us, dur_us, plane=DEV):
@@ -193,11 +202,31 @@ def test_ckpt_compiles_reads_the_programs_counters():
         set_default_registry(old)
 
 
-def test_manifest_is_sound_and_names_the_new_readers():
-    manifest = mf.load()
-    assert mf.problems(manifest) == []
+def check_readers_listed(manifest):
+    """The eleven readers each stand once in ``per_layer``, wherever later
+    entries put them, and each has its reader."""
     names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-len(NEW_READERS):] == list(NEW_READERS)
-    higgs = {m["name"] for m in mf.Cell(manifest, "higgs10m_d8.job").per_layer}
-    epsilon = {m["name"] for m in mf.Cell(manifest, "epsilon400k_d6.job").per_layer}
+    for name in NEW_READERS:
+        assert names.count(name) == 1, name
+        assert callable(mf.metric_reader(name).read)
+
+
+def accepted_of(manifest, cell, root=mf.ROOT):
+    """The metrics of ``ACCEPTED_PER_LAYER`` that ``cell`` reports."""
+    return {m["name"] for m in mf.Cell(manifest, cell, root).per_layer} & ACCEPTED_PER_LAYER
+
+
+def check_manifest(manifest, root=mf.ROOT):
+    """The two depth-wise cells' part of the manifest: sound, the eleven
+    readers listed, and the wired layout's two metrics reported in the Higgs
+    cell and not in Epsilon's, which runs no layout."""
+    assert mf.problems(manifest, root) == []
+    check_readers_listed(manifest)
+    assert {m["name"] for m in manifest["per_layer"]} >= ACCEPTED_PER_LAYER
+    higgs = accepted_of(manifest, "higgs10m_d8.job", root)
+    epsilon = accepted_of(manifest, "epsilon400k_d6.job", root)
     assert higgs - epsilon == {"perm_time_share", "layout_device_ms"}
+
+
+def test_manifest_is_sound_and_names_the_new_readers():
+    check_manifest(mf.load())

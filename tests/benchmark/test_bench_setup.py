@@ -1,6 +1,6 @@
 """The seven readers of set-up (PR 36): the program's spans and jit counters,
 read from the process's registry over the whole run, a number in every cell.
-``benchmark/tools/setup_table.py`` reports them; no manifest entry does yet."""
+``BENCHMARK.json`` lists them under ``per_layer``, moving ``setup_s``."""
 
 import os
 import sys
@@ -60,15 +60,10 @@ def test_an_absent_series_reads_zero_and_never_nothing(name, registry):
     assert value == 0.0 and isinstance(value, float)
 
 
-def test_the_manifest_would_take_the_seven_as_the_issue_words_them():
-    """No entry of ``BENCHMARK.json`` names the seven yet: three accepted tests
-    pin the ``per_layer`` list's last eleven names, and the driver's check reads
-    an entry put before that tail as a change to ``grad_score_device_ms`` (PR
-    36's first check was refused for it).  So a ``benchmark`` PR enters them;
-    here, the entries it would add make a sound manifest with the readers that
-    are there, and every cell would report all seven."""
-    manifest = mf.load()
-    assert mf.problems(manifest) == []
+def check_manifest(manifest, root=mf.ROOT):
+    """The seven stand in ``per_layer`` once each, entry for entry as below,
+    with their readers, and every cell reports all seven."""
+    assert mf.problems(manifest, root) == []
     spans = ("sketch_s", "bin_s", "upload_s", "capture_s")
     layers = {"sketch_s": "host data prep", "bin_s": "host data prep",
               "upload_s": "host data prep"}
@@ -76,15 +71,18 @@ def test_the_manifest_would_take_the_seven_as_the_issue_words_them():
                 "source": "program_span" if name in spans else "program_counter",
                 "layer": layers.get(name, "compile cache"), "moves": "setup_s"}
                for name in SETUP_READERS]
-    have = {m["name"] for m in manifest["per_layer"]}
-    grown = dict(manifest, per_layer=manifest["per_layer"]
-                 + [e for e in entries if e["name"] not in have])
-    assert mf.problems(grown) == []
-    assert {e["layer"] for e in entries} <= {m["layer"] for m in manifest["per_layer"]}
-    for name in SETUP_READERS:
-        assert callable(mf.metric_reader(name).read)
+    for want in entries:
+        assert [m for m in manifest["per_layer"] if m["name"] == want["name"]] == [want]
+        assert callable(mf.metric_reader(want["name"]).read)
     for cell in (w["name"] for w in manifest["workloads"]):
-        assert set(SETUP_READERS) <= {m["name"] for m in mf.Cell(grown, cell).per_layer}
+        assert set(SETUP_READERS) <= {m["name"] for m in mf.Cell(manifest, cell, root).per_layer}
+
+
+def test_the_manifest_would_take_the_seven_as_the_issue_words_them():
+    """The seven are in ``BENCHMARK.json`` entry for entry as
+    ``check_manifest`` words them, appended after the entries before them,
+    none of which changed."""
+    check_manifest(mf.load())
 
 
 def test_the_sums_the_issue_asks_of_the_readers_hold_on_a_real_job(registry, monkeypatch):
