@@ -396,7 +396,10 @@ MAX_FAST_DEPTH = 14
 # 12.77 GB, compiled 9.72, the whole chunk program on the chip 10.50
 # (11.18 GB held, 66 %; 4.4 s an iteration where the sequential grower
 # takes 23.97).  10M x 28 at cap 12: reckoned 9.18, compiled 7.73, on the
-# chip 7.78.  The rule errs high between the widths it was fitted at (10M x
+# chip 7.78.  1,000,000 x 968 at 64 bins (cell bosch1m_leaf255.job_missing,
+# missing values, caps 12 and 11 refused): at cap 10 reckoned 11.49,
+# compiled 8.16 for one iteration, the step program on the chip 8.16
+# (9.42 GB held, 56 %).  The rule errs high between the widths it was fitted at (10M x
 # 136 at cap 12 compiles to 11.8 GB and is refused).  It must not err low:
 # a refused shape runs a slower grower, an admitted one that does not fit
 # dies.  Shape-only callers (num_rows None) are held to the expansion alone.

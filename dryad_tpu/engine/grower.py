@@ -338,7 +338,8 @@ def grow_tree(
             num_left = bins_f <= thr
             dl = st["sp_dleft"][s]
             if learn_missing:
-                num_left &= dl | (bins_f > 0)
+                with jax.named_scope("dryad.missing"):
+                    num_left &= dl | (bins_f > 0)
             if has_cat:
                 with jax.named_scope("dryad.cat"):
                     go_left = jnp.where(cat_split,

@@ -421,7 +421,8 @@ def grow_tree_leafwise_batched(
                         gl = bins_rf <= ((w0r >> 16)
                                          & jnp.uint32(0x1FFF)).astype(jnp.int32)
                         if learn_missing:
-                            gl &= ((w0r >> 30) & 1).astype(bool) | (bins_rf > 0)
+                            with jax.named_scope("dryad.missing"):
+                                gl &= ((w0r >> 30) & 1).astype(bool) | (bins_rf > 0)
                         if has_cat:
                             with jax.named_scope("dryad.cat"):
                                 cat_row = st["nd_catmask"][
@@ -440,7 +441,8 @@ def grow_tree_leafwise_batched(
                     bins_rf = bins_rf.astype(jnp.int32)
                     go_left = bins_rf <= st["nd_thresh"][rn]
                     if learn_missing:
-                        go_left &= st["nd_dleft"][rn] | (bins_rf > 0)
+                        with jax.named_scope("dryad.missing"):
+                            go_left &= st["nd_dleft"][rn] | (bins_rf > 0)
                     if has_cat:
                         with jax.named_scope("dryad.cat"):
                             cat_row = st["nd_catmask"][rn, jnp.minimum(bins_rf, Bc - 1)]

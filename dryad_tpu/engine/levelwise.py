@@ -553,7 +553,8 @@ def grow_tree_levelwise(
                                  & jnp.uint32(0x1FFF)).astype(jnp.int32)
                         gl = bins_rf <= thr_r
                         if learn_missing:
-                            gl &= ((w0r >> 30) & 1).astype(bool) | (bins_rf > 0)
+                            with jax.named_scope("dryad.missing"):
+                                gl &= ((w0r >> 30) & 1).astype(bool) | (bins_rf > 0)
                         if has_cat:
                             with jax.named_scope("dryad.cat"):
                                 cat_row = sp_catmask[jnp.minimum(slot_idx, L - 1),
@@ -582,7 +583,8 @@ def grow_tree_levelwise(
                     bins_rf = bins_rf.astype(jnp.int32)
                     go_left = bins_rf <= sp_thresh[rs]
                     if learn_missing:
-                        go_left &= sp_dleft[rs] | (bins_rf > 0)
+                        with jax.named_scope("dryad.missing"):
+                            go_left &= sp_dleft[rs] | (bins_rf > 0)
                     if has_cat:
                         with jax.named_scope("dryad.cat"):
                             cat_row = sp_catmask[rs, jnp.minimum(bins_rf, Bc - 1)]

@@ -15,7 +15,11 @@ membership set, returned as a (B,) bool mask (the host converts it to the
 node bitset).
 That work, the order and the membership row, has the scope ``dryad.cat``
 (traced only where the model has categorical features, so a numeric
-program is unchanged by it).
+program is unchanged by it).  Where the data has missing values the scan
+learns their direction: the missing-right plane, the two planes' stacked
+argmax and the winner's left-sum correction have the scope
+``dryad.missing``, as have the growers' look-ups of the learned direction
+where rows are routed.
 
 Feature-parallel variant (r16, ``Params.hist_reduce="feature"``): under the
 reduce-scatter arm each shard owns a contiguous feature slice of the fully
@@ -155,34 +159,37 @@ def find_best_split(
 
     gain = gain_of(GL, HL, CL)
     if learn_missing:
-        # second scan with the missing bin (ordered position 0 for numerical
-        # features — the identity order keeps bin 0 first) moved to the RIGHT
-        # child: left = bins 1..t.  Categorical features learn the missing
-        # direction through subset membership already, so only the
-        # missing-left plane applies to them.  The missing-left plane comes
-        # FIRST in the flattened argmax, so on data with no missing values
-        # (bin-0 stats all zero → both planes identical) the tie-break picks
-        # missing-left and trees are unchanged.
-        g0, h0, c0 = hg_o[:, :1], hh_o[:, :1], hc_o[:, :1]
-        CL_r = CL - c0
-        gain_r = gain_of(GL - g0, HL - h0, CL_r)
-        # a right child holding ONLY missing rows mirrors the plane-0 t=0
-        # split (sides swapped, bitwise-equal gain only in exact arithmetic);
-        # exclude it so fp noise cannot flip the CPU/TPU argmax between the
-        # two representations of the same partition
-        gain_r = jnp.where((C - CL_r) > c0, gain_r, NEG_INF)
-        if has_cat:
-            gain_r = jnp.where(is_cat_feat[:, None], NEG_INF, gain_r)
-        if bundled_mask is not None:
-            # EFB bundle columns: bin 0 means "all members default", never
-            # "missing" — a learned missing-right direction there would be
-            # fiction (mirrors cpu/histogram.py exactly)
-            gain_r = jnp.where(bundled_mask[:, None], NEG_INF, gain_r)
-        flat2 = jnp.argmax(jnp.stack([gain.ravel(), gain_r.ravel()]).ravel())
-        flat2 = flat2.astype(jnp.int32)
-        dleft = flat2 < F * B
-        flat = flat2 % (F * B)
-        best_gain = jnp.where(dleft, gain.ravel()[flat], gain_r.ravel()[flat])
+        with jax.named_scope("dryad.missing"):
+            # second scan with the missing bin (ordered position 0 for
+            # numerical features — the identity order keeps bin 0 first)
+            # moved to the RIGHT child: left = bins 1..t.  Categorical
+            # features learn the missing direction through subset membership
+            # already, so only the missing-left plane applies to them.  The
+            # missing-left plane comes FIRST in the flattened argmax, so on
+            # data with no missing values (bin-0 stats all zero → both planes
+            # identical) the tie-break picks missing-left and trees are
+            # unchanged.  Scope dryad.missing: traced only where the data has
+            # missing values, so a NaN-free program is unchanged by it.
+            g0, h0, c0 = hg_o[:, :1], hh_o[:, :1], hc_o[:, :1]
+            CL_r = CL - c0
+            gain_r = gain_of(GL - g0, HL - h0, CL_r)
+            # a right child holding ONLY missing rows mirrors the plane-0
+            # t=0 split (sides swapped, bitwise-equal gain only in exact
+            # arithmetic); exclude it so fp noise cannot flip the CPU/TPU
+            # argmax between the two representations of the same partition
+            gain_r = jnp.where((C - CL_r) > c0, gain_r, NEG_INF)
+            if has_cat:
+                gain_r = jnp.where(is_cat_feat[:, None], NEG_INF, gain_r)
+            if bundled_mask is not None:
+                # EFB bundle columns: bin 0 means "all members default",
+                # never "missing" — a learned missing-right direction there
+                # would be fiction (mirrors cpu/histogram.py exactly)
+                gain_r = jnp.where(bundled_mask[:, None], NEG_INF, gain_r)
+            flat2 = jnp.argmax(jnp.stack([gain.ravel(), gain_r.ravel()]).ravel())
+            flat2 = flat2.astype(jnp.int32)
+            dleft = flat2 < F * B
+            flat = flat2 % (F * B)
+            best_gain = jnp.where(dleft, gain.ravel()[flat], gain_r.ravel()[flat])
     else:
         flat = jnp.argmax(gain.ravel()).astype(jnp.int32)  # first-max tie-break
         dleft = jnp.bool_(True)
@@ -200,9 +207,10 @@ def find_best_split(
 
     g_left, h_left, c_left = GL[f, t], HL[f, t], CL[f, t]
     if learn_missing:
-        g_left = jnp.where(dleft, g_left, g_left - hg_o[f, 0])
-        h_left = jnp.where(dleft, h_left, h_left - hh_o[f, 0])
-        c_left = jnp.where(dleft, c_left, c_left - hc_o[f, 0])
+        with jax.named_scope("dryad.missing"):
+            g_left = jnp.where(dleft, g_left, g_left - hg_o[f, 0])
+            h_left = jnp.where(dleft, h_left, h_left - hh_o[f, 0])
+            c_left = jnp.where(dleft, c_left, c_left - hc_o[f, 0])
 
     return SplitResult(
         gain=jnp.where(ok, best_gain, NEG_INF),
@@ -304,19 +312,20 @@ def find_best_split_sliced(
 
     gain = gain_of(GL, HL, CL)
     if learn_missing:
-        g0, h0, c0 = hg_o[:, :1], hh_o[:, :1], hc_o[:, :1]
-        CL_r = CL - c0
-        gain_r = gain_of(GL - g0, HL - h0, CL_r)
-        gain_r = jnp.where((C - CL_r) > c0, gain_r, NEG_INF)
-        if has_cat:
-            gain_r = jnp.where(is_cat_feat[:, None], NEG_INF, gain_r)
-        if bundled_mask is not None:
-            gain_r = jnp.where(bundled_mask[:, None], NEG_INF, gain_r)
-        flat2 = jnp.argmax(jnp.stack([gain.ravel(), gain_r.ravel()]).ravel())
-        flat2 = flat2.astype(jnp.int32)
-        dleft = flat2 < F * B
-        flat = flat2 % (F * B)
-        best_gain = jnp.where(dleft, gain.ravel()[flat], gain_r.ravel()[flat])
+        with jax.named_scope("dryad.missing"):
+            g0, h0, c0 = hg_o[:, :1], hh_o[:, :1], hc_o[:, :1]
+            CL_r = CL - c0
+            gain_r = gain_of(GL - g0, HL - h0, CL_r)
+            gain_r = jnp.where((C - CL_r) > c0, gain_r, NEG_INF)
+            if has_cat:
+                gain_r = jnp.where(is_cat_feat[:, None], NEG_INF, gain_r)
+            if bundled_mask is not None:
+                gain_r = jnp.where(bundled_mask[:, None], NEG_INF, gain_r)
+            flat2 = jnp.argmax(jnp.stack([gain.ravel(), gain_r.ravel()]).ravel())
+            flat2 = flat2.astype(jnp.int32)
+            dleft = flat2 < F * B
+            flat = flat2 % (F * B)
+            best_gain = jnp.where(dleft, gain.ravel()[flat], gain_r.ravel()[flat])
     else:
         flat = jnp.argmax(gain.ravel()).astype(jnp.int32)  # first-max
         dleft = jnp.bool_(True)
@@ -332,9 +341,10 @@ def find_best_split_sliced(
 
     g_left, h_left, c_left = GL[f, t], HL[f, t], CL[f, t]
     if learn_missing:
-        g_left = jnp.where(dleft, g_left, g_left - hg_o[f, 0])
-        h_left = jnp.where(dleft, h_left, h_left - hh_o[f, 0])
-        c_left = jnp.where(dleft, c_left, c_left - hc_o[f, 0])
+        with jax.named_scope("dryad.missing"):
+            g_left = jnp.where(dleft, g_left, g_left - hg_o[f, 0])
+            h_left = jnp.where(dleft, h_left, h_left - hh_o[f, 0])
+            c_left = jnp.where(dleft, c_left, c_left - hc_o[f, 0])
 
     f_global = f + feat_offset.astype(jnp.int32)
     # the GLOBAL flattened argmax index the fused scan would have picked:

@@ -79,6 +79,10 @@ _LAYOUT_STATS = ("layout_tiles_live", "layout_tiles_empty")
 # and, where the model has categorical features, every grown tree's split
 # nodes by kind (counter dryad_split_nodes_total{kind})
 _CAT_STATS = ("split_nodes_categorical", "split_nodes_numeric")
+# and, where the model learns where missing values go (the split scan's two
+# planes), every grown tree's numeric split nodes by the side their missing
+# rows take (counter dryad_split_default_total{dir})
+_MISSING_STATS = ("split_default_left", "split_default_right")
 
 # widest (features * bins) program the chunked fori wrapper may compile.
 # Round 2 measured Epsilon-shaped (2000 x 256) chunk programs failing
@@ -184,6 +188,12 @@ def _step_body(p, B, has_cat, mesh, platform, learn_missing, out, score, Xb,
             split = tree["feature"] >= 0
             for key, kind in zip(_CAT_STATS, (tree["is_cat"], ~tree["is_cat"])):
                 out[key] = out[key].at[t].set(jnp.sum(split & kind, dtype=jnp.int32))
+    if learn_missing and _MISSING_STATS[0] in out:
+        with jax.named_scope("dryad.missing"):
+            numeric = (tree["feature"] >= 0) & ~tree["is_cat"]
+            dleft = tree["default_left"]
+            for key, side in zip(_MISSING_STATS, (dleft, ~dleft)):
+                out[key] = out[key].at[t].set(jnp.sum(numeric & side, dtype=jnp.int32))
     with jax.named_scope("dryad.score"):
         value = tree["value"]
         if renew_alpha is not None:
@@ -789,7 +799,8 @@ def _grow_stats_of(out, lo: int, hi: int) -> dict:
     new, or nobody counts."""
     if lo >= hi or not default_registry().enabled:
         return {}
-    return {key: out[key][lo:hi] for key in _GROW_STATS + _LAYOUT_STATS + _CAT_STATS
+    return {key: out[key][lo:hi]
+            for key in _GROW_STATS + _LAYOUT_STATS + _CAT_STATS + _MISSING_STATS
             if key in out}
 
 
@@ -797,11 +808,19 @@ def _count_grow_stats(fetched: dict) -> None:
     """Fetched ``_grow_stats_of`` slices into their counters: splits the
     expansion grew, and splits the best-first selection kept of them;
     the layout's tiles the moves visited, live (holding a row) or empty;
-    the trees' split nodes, categorical or numeric."""
+    the trees' split nodes, categorical or numeric; their numeric split
+    nodes by the side missing rows take."""
     reg = default_registry()
     for key, vals in fetched.items():
         n = int(np.asarray(vals).sum())
-        if key in _CAT_STATS:
+        if key in _MISSING_STATS:
+            reg.counter(
+                "dryad_split_default_total",
+                "Numeric split nodes of the grown trees of a model that learns "
+                "where missing values go: left (the missing-left plane won) / "
+                "right (the missing-right plane won)",
+            ).labels(dir=key.rsplit("_", 1)[1]).inc(n)
+        elif key in _CAT_STATS:
             reg.counter(
                 "dryad_split_nodes_total",
                 "Split nodes of the grown trees of a model with categorical "
@@ -1087,7 +1106,8 @@ def _train_device(setup, params, data, valid, *, num_trees, init_booster,
         grow_stats=(_GROW_STATS if batched_leafwise else ())
         + (_LAYOUT_STATS if wired_layout(p, F, B, Xb.dtype.itemsize, N,
                                          n_shards, plat) else ())
-        + (_CAT_STATS if has_cat else ()))
+        + (_CAT_STATS if has_cat else ())
+        + (_MISSING_STATS if learn_missing else ()))
     if batched_leafwise and default_registry().enabled:
         default_registry().gauge(
             "dryad_leafwise_depth_cap",
@@ -1848,7 +1868,11 @@ def _train_device(setup, params, data, valid, *, num_trees, init_booster,
                     if chunk_hook is not None:
                         chunk_hook("fetch", it)
                     with span("train.fetch.eval"):
-                        vals = jax.device_get(vals_dev)  # ONE fetch, all sets
+                        # ONE fetch, all sets; the grower's statistics ride it
+                        vals, stats = jax.device_get((
+                            vals_dev, _grow_stats_of(out, stats_done, (it + 1) * K)))
+                _count_grow_stats(stats)
+                stats_done = (it + 1) * K
                 for vi, ((vname, _), (mname, higher, _)) in enumerate(
                         zip(valids, evaluators)):
                     value = float(vals[vi])

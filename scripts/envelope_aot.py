@@ -5,12 +5,15 @@ for a described v5e (no chip needed, nothing runs) and print
 
     JAX_PLATFORMS=cpu python3 scripts/envelope_aot.py 2270296,136,12,255 400000,2000,6,63
 
-Each argument is ``rows,features,max_depth,num_leaves[,shards[,cat]]`` (256
-bins, binary objective, pallas histograms; ``shards`` 2 or 4 compiles the
+Each argument is ``rows,features,max_depth,num_leaves[,shards[,cat[,bins[,missing]]]]``
+(256 bins unless ``bins`` says otherwise, binary objective, pallas
+histograms; ``shards`` 2 or 4 compiles the
 sharded iteration for a mesh of that many of the described chips, rows the
 global count, and the sizes printed are one device's; ``cat`` 1 compiles the
 program of a model with categorical features: subset scans and membership
-routing).  The rows of ``COMPILED`` in
+routing; ``missing`` 1 the program of a table with missing values: the
+split scan's two planes and the learned default direction).  The rows of
+``COMPILED`` in
 ``tests/test_rank_plan.py`` are this script's lines; the constants of
 ``config.leafwise_fast_supported`` envelop them.  A shape the chip cannot hold
 prints the compiler's RESOURCE_EXHAUSTED error instead of sizes.  A 10M-row
@@ -39,13 +42,15 @@ def main(argv) -> int:
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     one_chip = SingleDeviceSharding(topo.devices[0])
     for arg in argv:
-        rows, features, depth, leaves, shards, cat = (
-            tuple(int(v) for v in arg.split(",")) + (1, 0)[len(arg.split(",")) - 4:])[:6]
+        given = tuple(int(v) for v in arg.split(","))
+        rows, features, depth, leaves, shards, cat, bins, missing = (
+            given + (1, 0, 256, 0)[len(given) - 4:])[:8]
         p = make_params(dict(objective="binary", growth="leafwise", num_leaves=leaves,
-                             max_depth=depth, max_bins=256, min_child_weight=100,
+                             max_depth=depth, max_bins=bins, min_child_weight=100,
                              hist_precision="exact", hist_backend="pallas"))
         line = {"rows": rows, "features": features, "max_depth": depth, "num_leaves": leaves,
-                "shards": shards, "categorical": bool(cat)}
+                "shards": shards, "categorical": bool(cat), "bins": bins,
+                "missing": bool(missing)}
         t0 = time.time()
         try:
             mesh = make_mesh(topo.devices[:shards]) if shards > 1 else None
@@ -61,8 +66,9 @@ def main(argv) -> int:
                 return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=NamedSharding(mesh, spec))
 
             args = jax.tree_util.tree_map(placed, audit_iteration_args(p, padded, features))
-            memory = jax.jit(audit_iteration_fn(p, 256, bool(cat), mesh, "tpu", rows,
-                                                pad=padded - rows)).lower(
+            memory = jax.jit(audit_iteration_fn(p, bins, bool(cat), mesh, "tpu", rows,
+                                                pad=padded - rows,
+                                                learn_missing=bool(missing))).lower(
                 *args).compile().memory_analysis()
             line.update(temp_size_in_bytes=int(memory.temp_size_in_bytes),
                         argument_size_in_bytes=int(memory.argument_size_in_bytes))

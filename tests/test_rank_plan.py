@@ -19,11 +19,11 @@ LEAF255 = dict(growth="leafwise", num_leaves=255, max_depth=-1)
 HBM_BYTES = int(15.75 * (1 << 30))        # what the v5e's compiler allows one program
 
 
-def reckoned(rows, features, depth):
+def reckoned(rows, features, depth, bins=256):
     """The rule's figure for one device: the pinned expansion written out
     here, the rows' term the rule's own (the compiled shapes below hold it
     to the compiler's figures)."""
-    pinned = (1 << (depth - 1)) * 3 * features * 256 * 4
+    pinned = (1 << (depth - 1)) * 3 * features * bins * 4
     per_row = LEAFWISE_ROW_BYTES + 16 + features * (LEAFWISE_CELL_BYTES + 1)
     return LEAFWISE_PEAK_FACTOR * pinned + leafwise_rows_bytes(depth, features, 1, rows, per_row)
 
@@ -100,6 +100,40 @@ COMPILED = [
     (11_000_000, 16, 12, 255, 8_199_690_752, 275_041_280),
     (11_000_000, 28, 12, 255, 8_400_049_664, 451_042_304),
 ]
+
+
+# The same at 64 bins (the source's max_bin 63 and the missing bin), with the
+# split scan's two planes of a table with missing values (envelope_aot.py's
+# ``rows,features,max_depth,num_leaves,1,0,64,1``): bosch1m_leaf255's shape
+# at the cap the envelope gives it.
+COMPILED_64_MISSING = [
+    (1_000_000, 968, 10, 255, 8_163_383_808, 977_100_800),
+]
+
+
+@pytest.mark.parametrize("rows,features,depth,leaves,temp,args", COMPILED_64_MISSING)
+def test_the_envelope_envelops_the_64_bin_iteration_with_missing_values(rows, features, depth,
+                                                                         leaves, temp, args):
+    p = make_params(dict(growth="leafwise", num_leaves=leaves, max_depth=depth, max_bins=64))
+    assert leafwise_fast_supported(p, features, 64, rows)
+    assert reckoned(rows, features, depth, bins=64) >= temp
+    assert temp + args <= HBM_BYTES
+
+
+def test_the_bosch_shape_steps_two_levels_down_to_cap_10():
+    """1,000,000 x 968 at 64 bins and 255 leaves (bosch1m_leaf255): the
+    pinned expansion alone is 16.75 GB at cap 12, cap 11 reckons 8.37 + 7.30
+    GB of rows, cap 10 4.19 + 7.30 = 11.49, admitted.  At 256 bins the shape
+    is refused at all three and keeps the sequential grower."""
+    p = make_params(dict(objective="binary", max_bins=64, **LEAF255))
+    assert effective_depth_params(p, 968, 64, 1_000_000).max_depth == 10
+    for depth, admitted in ((12, False), (11, False), (10, True)):
+        assert leafwise_fast_supported(p.replace(max_depth=depth), 968, 64,
+                                       1_000_000) is admitted
+        assert (reckoned(1_000_000, 968, depth, bins=64)
+                <= LEAFWISE_TOTAL_BYTES_BUDGET) is admitted
+    assert round(reckoned(1_000_000, 968, 10, bins=64) / 1e9, 2) == 11.49
+    assert effective_depth_params(p, 968, 256, 1_000_000).max_depth == -1
 
 
 @pytest.mark.parametrize("rows,features,depth,leaves,temp,args", COMPILED)

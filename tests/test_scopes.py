@@ -27,6 +27,10 @@ NINE = EIGHT | {"dryad.reduce"}
 # membership look-ups of route and eval), traced only where a model has
 # categorical features: no program above carries it
 TEN = NINE | {"dryad.cat"}
+# and the missing-value work (the split scan's missing-right plane, its
+# stacked argmax and left-sum correction, the routes' look-up of the learned
+# direction), traced only where the data has missing values
+ELEVEN = TEN | {"dryad.missing"}
 BASE = dict(objective="binary", num_trees=4, num_leaves=7, max_depth=3,
             max_bins=32, seed=3, min_data_in_leaf=5, growth="depthwise")
 
@@ -110,15 +114,16 @@ def test_a_sharded_program_names_the_exchange(monkeypatch, sets, extra, expected
 
 def test_engine_sources_name_the_nine_and_no_tenth():
     """What the acceptance grep reads: every ``named_scope`` under
-    ``dryad_tpu/engine`` takes one of the nine names or ``dryad.cat``, the
-    categorical programs' own, and each is used."""
+    ``dryad_tpu/engine`` takes one of the nine names, ``dryad.cat``, the
+    categorical programs' own, or ``dryad.missing``, the programs' own
+    whose data has missing values, and each is used."""
     root = os.path.dirname(os.path.abspath(train.__file__))
     used = set()
     for name in os.listdir(root):
         if name.endswith(".py"):
             with open(os.path.join(root, name), encoding="utf-8") as f:
                 used |= set(re.findall(r'named_scope\("([^"]*)"\)', f.read()))
-    assert used == TEN
+    assert used == ELEVEN
 
 
 def _pallas_names(fn, *args, **kw):
