@@ -61,6 +61,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from dryad_tpu.booster import CAT_WORDS
 from dryad_tpu.config import Params
 from dryad_tpu.engine.grower import (
     _monotone_array,
@@ -100,6 +101,40 @@ def supports(p: Params, num_features: int, total_bins: int,
     shape a traced shard sees (see config.leafwise_fast_supported)."""
     return leafwise_fast_supported(p, num_features, total_bins, num_rows,
                                    n_shards)
+
+
+def _route_table(rec_t: jnp.ndarray, nd_catmask: jnp.ndarray) -> jnp.ndarray:
+    """Route's per-node record widened by the node's membership:
+    ``u32[HN, 2 + CAT_WORDS]``, the two words of ``rec_t``, then the node's
+    ``nd_catmask`` row in the checkpoint's bit layout (``pack_cat_bitset``'s:
+    bin b -> word b >> 5, bit b & 31; rows under 256 bins zero-padded).  The
+    rows gather it once, so the membership rides the record gather route makes
+    anyway, inside the 128-lane padding of its result.  The words are summed
+    over a flat ``(HN * CAT_WORDS, 32)`` view: summed over ``pack_cat_bitset``'s
+    ``(HN, CAT_WORDS, 32)`` one, the TPU compiler lays the chunk program's
+    ``Xb`` out row-major for the expansion loops, a 1.4 GB copy at 11M x 8."""
+    HN, Bc = nd_catmask.shape
+    width = CAT_WORDS * 32
+    bits = jnp.pad(nd_catmask[:, :width], ((0, 0), (0, max(width - Bc, 0))))
+    bits = bits.reshape(HN * CAT_WORDS, 32).astype(jnp.uint32)
+    words = (bits << jnp.arange(32, dtype=jnp.uint32)).sum(axis=1, dtype=jnp.uint32)
+    return jnp.concatenate([rec_t, words.reshape(HN, CAT_WORDS)], axis=1)
+
+
+def _member_bit(rec: jnp.ndarray, bins: jnp.ndarray) -> jnp.ndarray:
+    """Bit ``bins`` of each row's membership, read off the row's gathered
+    route record (``(N, 2 + CAT_WORDS)`` u32, a ``_route_table`` row; ``bins``
+    already clamped to the table's bins).  The word, column
+    ``2 + (bins >> 5)``, is picked by a masked reduce, not by an element
+    gather: one element a row gathered from a 2-D table ran at 12.6 ns an
+    index on a TPU v5e (PERF.md section 5).  The reduce spans the whole record,
+    whose two route words never match: over the ``(N, CAT_WORDS)`` slice alone
+    the TPU compiler lays the loop's ``Xb`` out row-major, a 1.4 GB copy at
+    11M x 8 (``scripts/envelope_aot.py``)."""
+    col = jnp.arange(rec.shape[1], dtype=jnp.int32)
+    word = jnp.bitwise_or.reduce(
+        jnp.where(col == 2 + (bins >> 5)[:, None], rec, jnp.uint32(0)), axis=1)
+    return ((word >> (bins & 31).astype(jnp.uint32)) & 1).astype(bool)
 
 
 def phase_plan(depth_cap: int):
@@ -405,6 +440,10 @@ def grow_tree_leafwise_batched(
                     rec_t = jnp.stack(
                         [w0_t, jnp.maximum(st["nd_feature"], 0).astype(jnp.uint32)],
                         axis=1)
+                    route_t = rec_t
+                    if has_cat:
+                        with jax.named_scope("dryad.cat"):
+                            route_t = _route_table(rec_t, st["nd_catmask"])
 
                     def packed_route(nodes):
                         """Per-row routing off the packed per-NODE table:
@@ -414,7 +453,7 @@ def grow_tree_leafwise_batched(
                         natural-order partition and the layout can never
                         disagree on a row (levelwise.packed_route's
                         convention)."""
-                        rr = rec_t[nodes]                        # ONE gather
+                        rr = route_t[nodes]                      # ONE gather
                         w0r = rr[:, 0]
                         rf = rr[:, 1].astype(jnp.int32)
                         bins_rf = levelwise.select_bins(Xb, rf)
@@ -425,9 +464,8 @@ def grow_tree_leafwise_batched(
                                 gl &= ((w0r >> 30) & 1).astype(bool) | (bins_rf > 0)
                         if has_cat:
                             with jax.named_scope("dryad.cat"):
-                                cat_row = st["nd_catmask"][
-                                    jnp.minimum(nodes, HN - 1),
-                                    jnp.minimum(bins_rf, Bc - 1)]
+                                cat_row = _member_bit(
+                                    rr, jnp.minimum(bins_rf, Bc - 1))
                                 gl = jnp.where(((w0r >> 29) & 1).astype(bool),
                                                cat_row, gl)
                         return ((w0r >> 31) != 0), gl

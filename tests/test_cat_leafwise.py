@@ -3,7 +3,8 @@ categorical columns (one of them past 254 values, so its rarest codes pool
 into the overflow bin) grown tree for tree against the plain best-first
 reference with subset splits (``benchmark/reference/gbdt_bestfirst_cat.py``),
 the split-node counter and the sketch's gauge, the ``dryad.cat`` scope, the
-subset scan's one sort and no gather, and the numeric programs' digests,
+subset scan's one sort and no gather, route's membership read off the
+widened node record with no gather, and the numeric programs' digests,
 which the scope must leave as committed."""
 
 import os
@@ -155,33 +156,46 @@ def test_only_a_categorical_program_carries_the_scope(has_cat):
     assert {"dryad.split_scan", "dryad.route", "dryad.layout"} <= scopes
 
 
-def _subset_scan_census(monkeypatch):
-    """Trace the categorical program and return (scan call sites, the
-    equations inside both ``dryad.split_scan`` and ``dryad.cat``): the subset
-    scan's order and membership row, the root's and the level's vmapped one."""
-    from dryad_tpu.analysis.digests import iter_sub_jaxprs
+def _categorical_program():
+    """The categorical iteration's jaxpr at the audit's small shape (N = 2048
+    rows x 8 columns, cap 4, so HN = 32 heap slots)."""
     from dryad_tpu.config import make_params
-    from dryad_tpu.engine import split
     from dryad_tpu.engine.train import audit_iteration_args, audit_iteration_fn
 
-    sites = []
-    order_of = split._cat_order
-    monkeypatch.setattr(split, "_cat_order", lambda *a: (sites.append(1), order_of(*a))[1])
     p = make_params(dict(objective="binary", num_trees=1, num_leaves=15, max_depth=4,
                          growth="leafwise", max_bins=32, hist_backend="pallas"))
     fn = audit_iteration_fn(p, 32, True, None, "tpu", 2048)
-    closed = jax.make_jaxpr(fn)(*audit_iteration_args(p, 2048, 8))
+    return jax.make_jaxpr(fn)(*audit_iteration_args(p, 2048, 8))
+
+
+def _eqns_within(closed, *scopes):
+    """Every equation, nested ones too, whose name stack holds all of ``scopes``."""
+    from dryad_tpu.analysis.digests import iter_sub_jaxprs
+
     eqns = []
 
     def walk(jaxpr, stack):
         for eqn in jaxpr.eqns:
             here = stack + "/" + str(eqn.source_info.name_stack)
-            if "dryad.split_scan" in here and "dryad.cat" in here:
+            if all(s in here for s in scopes):
                 eqns.append(eqn)
             for _, sub, _ in iter_sub_jaxprs(eqn):
                 walk(sub, here)
 
     walk(closed.jaxpr, "")
+    return eqns
+
+
+def _subset_scan_census(monkeypatch):
+    """Trace the categorical program and return (scan call sites, the
+    equations inside both ``dryad.split_scan`` and ``dryad.cat``): the subset
+    scan's order and membership row, the root's and the level's vmapped one."""
+    from dryad_tpu.engine import split
+
+    sites = []
+    order_of = split._cat_order
+    monkeypatch.setattr(split, "_cat_order", lambda *a: (sites.append(1), order_of(*a))[1])
+    eqns = _eqns_within(_categorical_program(), "dryad.split_scan", "dryad.cat")
     return len(sites), eqns
 
 
@@ -216,6 +230,81 @@ def test_the_argsort_and_gathers_are_caught(monkeypatch):
     monkeypatch.setattr(split, "_cat_order", gathered)
     sites, eqns = _subset_scan_census(monkeypatch)
     assert _sorts_and_gathers(eqns) == ([(2, 1)] * sites, 3 * sites)
+
+
+def _route_census(monkeypatch):
+    """Trace the categorical program and return (route's membership reads,
+    the gathers inside ``dryad.route`` and ``dryad.cat``, the operand shapes
+    of route's row-sized gathers outside ``dryad.cat``)."""
+    from dryad_tpu.engine import leafwise_fast
+
+    sites = []
+    bit_of = leafwise_fast._member_bit
+    monkeypatch.setattr(leafwise_fast, "_member_bit",
+                        lambda *a: (sites.append(1), bit_of(*a))[1])
+    closed = _categorical_program()
+    route = [e for e in _eqns_within(closed, "dryad.route") if e.primitive.name == "gather"]
+    in_cat = {id(e) for e in _eqns_within(closed, "dryad.route", "dryad.cat")}
+    cat = [e for e in route if id(e) in in_cat]
+    rows = [tuple(e.invars[0].aval.shape) for e in route
+            if id(e) not in in_cat and e.outvars[0].aval.shape[0] == 2048]
+    return len(sites), len(cat), rows
+
+
+def test_route_reads_membership_off_the_widened_record(monkeypatch):
+    """The static form of "the membership rides the record": route's one
+    row-sized gather reads the (HN, 2 + CAT_WORDS) node table, and the bit is
+    read by a masked reduce, so ``dryad.cat`` inside ``dryad.route`` gathers
+    nothing."""
+    from dryad_tpu.booster import CAT_WORDS
+
+    sites, cat_gathers, rows = _route_census(monkeypatch)
+    assert sites >= 1
+    assert cat_gathers == 0
+    assert rows == [(32, 2 + CAT_WORDS)] * sites
+
+
+def test_an_element_gather_in_route_is_caught(monkeypatch):
+    """Mutation direction: the bit read as an element gather, one word a row
+    out of the 2-D gathered records, reads one gather in ``dryad.cat`` a route
+    site in the same census."""
+    from dryad_tpu.engine import leafwise_fast
+
+    def gathered(rec, bins):
+        word = rec[jnp.arange(rec.shape[0]), 2 + (bins >> 5)]
+        return ((word >> (bins & 31).astype(jnp.uint32)) & 1).astype(bool)
+
+    monkeypatch.setattr(leafwise_fast, "_member_bit", gathered)
+    sites, cat_gathers, _ = _route_census(monkeypatch)
+    assert sites >= 1 and cat_gathers == sites
+
+
+@pytest.mark.parametrize("n_bins", [256, 32])
+def test_the_bit_read_equals_the_membership_table(n_bins):
+    """Route's read, the widened table gathered at each node and the bit read
+    off its words, equals ``nd_catmask[min(node, HN - 1), min(bin, Bc - 1)]``
+    for every (node, bin): nodes past the clamp, bins at and past ``Bc - 1``,
+    and at 32 bins words that are zero-padded.  The words are the checkpoint's
+    (``pack_cat_bitset``'s), bit for bit."""
+    from dryad_tpu.booster import CAT_WORDS
+    from dryad_tpu.engine import leafwise_fast
+    from dryad_tpu.engine.grower import pack_cat_bitset
+
+    HN = 64
+    rng = np.random.default_rng(n_bins)
+    mask = rng.random((HN, n_bins)) < 0.5
+    rec = rng.integers(0, 1 << 32, (HN, 2), dtype=np.uint64).astype(np.uint32)
+    table = np.asarray(leafwise_fast._route_table(jnp.asarray(rec), jnp.asarray(mask)))
+    assert table.shape == (HN, 2 + CAT_WORDS)
+    assert np.array_equal(table[:, :2], rec)
+    assert np.array_equal(table[:, 2:], np.asarray(pack_cat_bitset(jnp.asarray(mask), HN)))
+    assert not table[:, 2 + -(-n_bins // 32):].any()
+    nodes, bins = (a.ravel() for a in np.meshgrid(np.arange(HN + 3), np.arange(300),
+                                                  indexing="ij"))
+    bc = np.minimum(bins, n_bins - 1)
+    rr = jnp.asarray(table)[jnp.asarray(nodes)]
+    got = np.asarray(leafwise_fast._member_bit(rr, jnp.asarray(bc, jnp.int32)))
+    assert np.array_equal(got, mask[np.minimum(nodes, HN - 1), bc])
 
 
 @pytest.mark.parametrize("arm", ["leafwise_wired", "train_eval"])
